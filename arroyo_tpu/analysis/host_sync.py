@@ -2,8 +2,7 @@
 
 Scope: ``ops/*.py`` and ``engine/operators_*.py`` — the per-batch hot
 paths where an accidental device->host readback serializes the XLA
-dispatch pipeline (on a tunneled TPU each sync is a network round
-trip).  Flags:
+dispatch pipeline.  Flags:
 
 - ``np.asarray(x)`` / ``np.array(x)`` on a non-literal — materializes
   device output on the host

@@ -76,14 +76,6 @@ class Config:
     artifact_url: str = field(
         default_factory=lambda: _env_str("ARTIFACT_URL", "file:///tmp/arroyo_tpu/artifacts")
     )
-    # JAX persistent compilation cache (engine/aot.py): '' = the
-    # env-signature-keyed default under the /tmp scratch dir, 'off'
-    # disables, anything else is used verbatim.  ARROYO_COMPILE_CACHE
-    # accepted as a legacy alias.
-    compile_cache_dir: str = field(
-        default_factory=lambda: _env_str(
-            "COMPILE_CACHE_DIR", _env_str("ARROYO_COMPILE_CACHE", ""))
-    )
 
     # Supervision (job_controller/mod.rs:30-32 defaults)
     # checkpoint retention: prune to the last N completed epochs after
@@ -105,9 +97,6 @@ class Config:
     )
 
     # Device execution
-    device_platform: str = field(
-        default_factory=lambda: _env_str("ARROYO_TPU_PLATFORM", "")
-    )  # '' = jax default
     state_capacity: int = field(
         default_factory=lambda: _env_int("STATE_CAPACITY", 1 << 12)
     )  # initial per-subtask keyed-state slots (doubles on overflow;
@@ -162,6 +151,28 @@ def config() -> Config:
     if _config is None:
         _config = Config()
     return _config
+
+
+def require_backend() -> str:
+    """Initialise the JAX backend now and return its platform name,
+    refusing the one fallback JAX performs by itself: with
+    ``JAX_PLATFORMS`` unset, an installed accelerator plug-in that fails
+    to start (no chip, or the chip is held by another process — a chip
+    belongs to one process at a time) is logged at INFO and the CPU
+    backend quietly takes over.  Here that is an error.  Running on the
+    CPU is a choice the caller states with ``JAX_PLATFORMS=cpu``; naming
+    a platform that cannot start already raises inside JAX."""
+    import jax
+    from jax._src import xla_bridge  # records the quiet failures (jax 0.9.0)
+
+    backend = jax.default_backend()
+    failed = dict(xla_bridge._backend_errors)
+    if failed:
+        raise RuntimeError(
+            f"accelerator backend failed to initialise ({failed}); JAX "
+            f"fell back to {backend!r} and this program does not — set "
+            "JAX_PLATFORMS=cpu to run on the CPU on purpose")
+    return backend
 
 
 def reset_config() -> None:

@@ -83,6 +83,7 @@ class NexmarkConfig(BaseModel):
     batch_size: Optional[int] = None
     base_time_micros: Optional[int] = None  # pin event-time origin (bench
     # latency math needs wall(T) = wall_base + (T - base_time)/1e6 exactly)
+    seed: int = 0  # workload seed; subtask i draws from seed + i
     # planner-injected projection pushdown: physical columns the query
     # reads; None = generate everything.  Unused column families (notably
     # the string columns) are skipped entirely.
@@ -429,7 +430,7 @@ class NexmarkSource(SourceOperator):
             count = 0
 
         gen = NexmarkGenerator(self.cfg, base_time, split[0], split[1], split[2],
-                               seed=ctx.task_info.task_index)
+                               seed=self.cfg.seed + ctx.task_info.task_index)
         gen.set_rate(self.cfg.event_rate, par)
 
         batch_size = self.cfg.batch_size or config().target_batch_size

@@ -491,6 +491,13 @@ class ControllerServer:
         # (scheduling.rs:255-290; reference timeout 10min, ours shorter)
         deadline = time.monotonic() + 60
         while sum(w.slots for w in job.workers.values()) < job.slots_needed:
+            dead = self.scheduler.dead_workers(job.job_id)
+            if dead:
+                # e.g. more worker processes than chips: the extra ones
+                # cannot claim a device and exit before registering
+                raise RuntimeError(
+                    f"worker(s) died before registering for {job.job_id}: "
+                    + ", ".join(dead))
             if time.monotonic() > deadline:
                 raise TimeoutError(
                     f"workers did not register enough slots for {job.job_id}")

@@ -37,6 +37,12 @@ class Scheduler:
     def workers_for_job(self, job_id: str) -> List[str]:
         raise NotImplementedError
 
+    def dead_workers(self, job_id: str) -> List[str]:
+        """Workers of ``job_id`` that exited before the job was scheduled,
+        as printable descriptions.  Only schedulers that own OS processes
+        can tell; the others wait out the registration deadline."""
+        return []
+
     async def reap(self, job_id: str, ext_ids: List[str]) -> None:
         """Kill workers left over from a PREVIOUS controller incarnation
         (identified by their persisted external ids).  Default no-op:
@@ -80,7 +86,15 @@ class InProcessScheduler(Scheduler):
 
 
 class ProcessScheduler(Scheduler):
-    """One OS process per worker (16 slots/node default in the reference)."""
+    """One OS process per worker (16 slots/node default in the reference).
+
+    On a chip host each worker process claims the chip at start-up
+    (worker/server.py ``main``) and a chip belongs to one process at a
+    time, so ``n_workers`` beyond the number of chips cannot start: the
+    extra workers exit non-zero before registering and the job FAILS at
+    scheduling with their exit codes (``dead_workers``).  They do not
+    hang and do not run on the CPU.  Assigning chips to workers (one
+    visible device each) is future work."""
 
     def __init__(self) -> None:
         self._procs: Dict[str, List[subprocess.Popen]] = {}
@@ -108,6 +122,11 @@ class ProcessScheduler(Scheduler):
     def workers_for_job(self, job_id):
         return [f"pid-{p.pid}" for p in self._procs.get(job_id, [])
                 if p.poll() is None]
+
+    def dead_workers(self, job_id):
+        return [f"pid-{p.pid} exited rc={p.returncode}"
+                for p in self._procs.get(job_id, [])
+                if p.poll() not in (None, 0)]
 
     async def reap(self, job_id, ext_ids):
         """SIGKILL orphaned worker pids from a crashed controller — but

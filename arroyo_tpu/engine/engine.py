@@ -40,30 +40,6 @@ from .task import TaskRunner
 
 logger = logging.getLogger(__name__)
 
-_compile_cache_enabled = False
-
-
-def _enable_compile_cache() -> None:
-    """Point jax at the persistent compilation cache once per process
-    (config knob ``compile_cache_dir``; empty = the env-keyed default
-    under /tmp, 'off' disables).  Repeated bench probes, engine rebuilds
-    and worker restarts then reuse XLA executables instead of paying
-    full recompile cost."""
-    global _compile_cache_enabled
-    if _compile_cache_enabled:
-        return
-    _compile_cache_enabled = True
-    d = config().compile_cache_dir
-    if d.lower() in ("off", "0", "false", "disabled", "none"):
-        return
-    try:
-        from .aot import enable_persistent_cache
-
-        enable_persistent_cache(d or None)
-    except Exception:
-        logger.warning("persistent compile cache unavailable",
-                       exc_info=True)
-
 
 @dataclass
 class SubtaskHandle:
@@ -145,7 +121,11 @@ class Engine:
 
     def start(self) -> "RunningEngine":
         """Build the physical graph and spawn all subtask loops."""
-        _enable_compile_cache()
+        # engine rebuilds and worker restarts reuse XLA executables from
+        # the persistent compilation cache instead of recompiling
+        from .aot import enable_persistent_cache
+
+        enable_persistent_cache()
         # arroyosan runtime sanitizer: one instance per engine run (so a
         # rescale restore starts from fresh invariant state); None unless
         # ARROYO_SANITIZE armed it — the hook sites then cost nothing

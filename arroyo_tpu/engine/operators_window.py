@@ -194,10 +194,10 @@ class BinAggOperator(Operator):
 
     def _offload_transfers(self) -> bool:
         """Run device update/emit in an executor thread on accelerators:
-        host<->device transfers there can block for tens of ms (remote-
-        tunnel TPUs especially), and off the event loop sibling operators'
-        transfers overlap instead of serializing.  On the CPU backend
-        transfers are free, so the thread hop is pure overhead."""
+        host<->device transfers there block the caller, and off the
+        event loop sibling operators' transfers overlap instead of
+        serializing.  On the CPU backend transfers are free, so the
+        thread hop is pure overhead."""
         if self._offload is None:
             import jax
 

@@ -1,14 +1,19 @@
 """ctypes bindings for the C++ host runtime library (native/src/host_ops.cpp).
 
-Loads ``libarroyo_host.so`` next to this file, building it from source on
-first use when a toolchain is available.  Every binding has a numpy
-fallback with identical semantics; ``HAVE_NATIVE`` reports which path is
-active and ``ARROYO_NATIVE=0`` forces the fallback.
+Loads ``libarroyo_host-<source hash>.so`` next to this file, building it
+from source on first use when a toolchain is available.  The hash in the
+file name covers ``native/src/host_ops.cpp`` and ``native/Makefile``, so
+a binary built from other sources (an older checkout, an edited tree) is
+never loaded: it simply is not the file this checkout looks for.  Every
+binding has a numpy fallback with identical semantics; ``HAVE_NATIVE``
+reports which path is active and ``ARROYO_NATIVE=0`` forces the fallback.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -18,92 +23,75 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-_SO = os.path.join(os.path.dirname(__file__), "libarroyo_host.so")
-_SRC_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
+_DIR = os.path.dirname(os.path.abspath(__file__))
+_SRC_DIR = os.path.join(_DIR, "..", "..", "native")
+_SOURCES = ("src/host_ops.cpp", "Makefile")
 
 _lib: Optional[ctypes.CDLL] = None
 
 
-def _build() -> bool:
+def source_hash(src_dir: str = _SRC_DIR) -> Optional[str]:
+    """Digest of the files the library is built from; None when the
+    sources are not there (an installed package without ``native/``)."""
+    h = hashlib.sha256()
+    try:
+        for rel in _SOURCES:
+            with open(os.path.join(src_dir, rel), "rb") as f:
+                h.update(rel.encode() + b"\0" + f.read() + b"\0")
+    except OSError:
+        return None
+    return h.hexdigest()[:12]
+
+
+def library_path(src_dir: str = _SRC_DIR, out_dir: str = _DIR
+                 ) -> Optional[str]:
+    """The one binary this checkout will load — named after its sources."""
+    digest = source_hash(src_dir)
+    if digest is None:
+        return None
+    return os.path.join(out_dir, f"libarroyo_host-{digest}.so")
+
+
+def _build(so: str) -> bool:
     """Build the library, safe against concurrent workers: an exclusive
     lockfile serializes builds, and make writes the final .so via the
     compiler in one pass so a loader never sees a half-written file that
     a racing builder produced under the lock."""
     import fcntl
 
-    makefile = os.path.join(_SRC_DIR, "Makefile")
-    if not os.path.exists(makefile):
-        return False
-    lock_path = _SO + ".lock"
     try:
-        with open(lock_path, "w") as lock:
+        with open(os.path.join(_DIR, "build.lock"), "w") as lock:
             fcntl.flock(lock, fcntl.LOCK_EX)
-            if os.path.exists(_SO):  # another process won the race
+            if os.path.exists(so):  # another process won the race
                 return True
-            tmp = _SO + f".tmp{os.getpid()}"
+            tmp = so + f".tmp{os.getpid()}"
             subprocess.run(
                 ["make", "-C", _SRC_DIR, f"OUT={tmp}"], check=True,
                 capture_output=True, timeout=120)
-            os.replace(tmp, _SO)  # atomic publish
+            os.replace(tmp, so)  # atomic publish
+            # binaries of other source revisions are dead weight
+            for old in glob.glob(os.path.join(_DIR, "libarroyo_host*.so")):
+                if old != so:
+                    os.unlink(old)
             return True
     except (subprocess.SubprocessError, OSError) as e:
-        logger.warning("native build failed, using numpy fallbacks: %s", e)
+        detail = getattr(e, "stderr", b"") or b""
+        logger.warning("native build failed, using numpy fallbacks: %s %s",
+                       e, detail.decode(errors="replace")[-500:])
         return False
-
-
-_ABI_VERSION = 2  # must match arroyo_abi_version() in host_ops.cpp
-
-
-def _abi_ok(lib: ctypes.CDLL) -> bool:
-    try:
-        fn = lib.arroyo_abi_version
-        fn.restype = ctypes.c_int64
-        return int(fn()) == _ABI_VERSION
-    except (AttributeError, OSError):
-        return False  # pre-versioning build: signatures may have changed
 
 
 def _load() -> Optional[ctypes.CDLL]:
     if os.environ.get("ARROYO_NATIVE", "1") in ("0", "false", "no"):
         return None
-    if not os.path.exists(_SO) and not _build():
+    so = library_path()
+    if so is None or (not os.path.exists(so) and not _build(so)):
         return None
     try:
-        lib = ctypes.CDLL(_SO)
-        if not _abi_ok(lib):
-            raise OSError(f"stale ABI (want v{_ABI_VERSION})")
-    except OSError as e:  # stale/foreign-arch binary: rebuild once
-        logger.warning("reloading native lib after load failure: %s", e)
-        try:
-            os.unlink(_SO)
-        except OSError:
-            # read-only install: can't replace the corrupt library
-            return None
-        if not _build():
-            return None
-        try:
-            # dlopen caches by pathname, so re-CDLL of _SO would return
-            # the stale mapping we just detected — load the rebuilt
-            # library through a unique temp copy instead (unlinked after
-            # dlopen; the mapping survives on Linux)
-            import shutil
-            import tempfile
-
-            fd, tmp = tempfile.mkstemp(
-                suffix=".so", dir=os.path.dirname(_SO))
-            os.close(fd)
-            shutil.copy2(_SO, tmp)
-            try:
-                lib = ctypes.CDLL(tmp)
-            finally:
-                os.unlink(tmp)
-            if not _abi_ok(lib):
-                logger.warning("native lib ABI mismatch after rebuild; "
-                               "numpy fallbacks")
-                return None
-        except OSError as e2:
-            logger.warning("native lib unusable, numpy fallbacks: %s", e2)
-            return None
+        lib = ctypes.CDLL(so)
+    except OSError as e:  # foreign-arch or truncated binary
+        logger.warning("native lib unusable, numpy fallbacks: %s", e)
+        return None
 
     u64p = np.ctypeslib.ndpointer(np.uint64, flags="C_CONTIGUOUS")
     i64p = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")

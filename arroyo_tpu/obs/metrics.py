@@ -66,7 +66,7 @@ _BUCKETS = {
     BATCH_LATENCY: LATENCY_BUCKETS,
     QUEUE_WAIT: LATENCY_BUCKETS,
     # checkpoints span sub-second (tiny state) to minutes (large device
-    # tables over a remote tunnel) — the lag buckets' 1800s ceiling fits;
+    # tables read back whole) — the lag buckets' 1800s ceiling fits;
     # the latency buckets would collapse everything past 10s into +Inf
     CHECKPOINT_DURATION: LAG_BUCKETS,
     CHECKPOINT_BYTES: BYTES_BUCKETS,

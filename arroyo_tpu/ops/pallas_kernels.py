@@ -38,18 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    _enable_x64 = jax.enable_x64  # jax >= 0.5 top-level export
-except AttributeError:
-    from jax.experimental import enable_x64 as _enable_x64
-
-try:  # pallas ships with jax, but guard for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 LANES = 128  # TPU lane width
 CHUNK = 1024  # batch rows per grid step
@@ -61,13 +50,11 @@ def pallas_enabled() -> bool:
     on real TPU v5 hardware the XLA scatter update measured 1.17 ms per
     16k-cell step against the engine's 8192x16 resident state while this
     kernel measured 52-76 ms at the identical shape across three
-    sessions (BENCH_TPU_KERNELS_r04.json) — the one-hot MXU scatter
-    does not pay off at bin-ring widths, so defaulting it on would
-    silently cost the q5 hot loop ~44x."""
-    env = os.environ.get("ARROYO_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "no") and HAVE_PALLAS
-    return False
+    sessions (record since deleted: git 4daf76b, a shared remote chip,
+    not reproducible) — the one-hot MXU scatter does not pay off at
+    bin-ring widths, so defaulting it on would silently cost the q5 hot
+    loop ~44x."""
+    return os.environ.get("ARROYO_PALLAS", "0") not in ("0", "false", "no")
 
 
 def _interpret() -> bool:
@@ -168,7 +155,7 @@ def scatter_add_channels(slots: np.ndarray, bins: np.ndarray,
     run = _scatter_multi(2 * k, B, C_act, n // CHUNK, _interpret())
     # every operand is 32-bit; trace under x32 — Mosaic's TPU lowering
     # rejects the 64-bit index types that global x64 mode introduces
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = run(jnp.asarray(slots, jnp.int32),
                   jnp.asarray(bins, jnp.int32),
                   jnp.asarray(w2))  # [2k, C_act, B]
@@ -230,7 +217,7 @@ def update_bin_state(values: jnp.ndarray, counts: jnp.ndarray,
     packed[1] = bins
     packed[2:] = w2
     delta = _update_delta_call(k, B, C_act, n // CHUNK, _interpret())
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         deltas = delta(jnp.asarray(packed))
     return _apply_delta_call(k, C_act)(values, counts, deltas)
 

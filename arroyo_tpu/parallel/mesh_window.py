@@ -137,10 +137,7 @@ def _update_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int, N: int,
     """
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map  # jax >= 0.5 top-level export
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n_ch = len(ch_kinds)
@@ -268,14 +265,6 @@ def _update_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int, N: int,
         return new_keys, new_bins, new_counts, new_of
 
     mesh = _keys_mesh(nk)
-    # replication checking was renamed check_rep -> check_vma across jax
-    # releases; disable whichever this jax spells
-    import inspect
-
-    _params = inspect.signature(shard_map).parameters
-    _check_kw = ({"check_vma": False} if "check_vma" in _params
-                 else {"check_rep": False} if "check_rep" in _params
-                 else {})
     fn = shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P("keys"), P(None, "keys", None), P("keys", None),
@@ -283,7 +272,7 @@ def _update_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int, N: int,
                   P(None, "keys"), P("keys")),
         out_specs=(P("keys"), P(None, "keys", None), P("keys", None),
                    P("keys", None)),
-        **_check_kw,
+        check_vma=False,
     )
     s1 = NamedSharding(mesh, P("keys"))
     s_bins = NamedSharding(mesh, P(None, "keys", None))

@@ -39,10 +39,7 @@ from ..ops.keyed_bins import _init_value
 def _ring_step(kind: str, nk: int, Bl: int, W: int):
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map  # jax >= 0.5 top-level export
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .mesh_window import _keys_mesh
@@ -110,10 +107,7 @@ def _ring_step_2d(kind: str, nk: int, C: int, Bl: int, W: int):
     selects it instead of the [C, k, W] gather when W is large)."""
     import jax
     import jax.numpy as jnp
-    try:
-        from jax import shard_map  # jax >= 0.5 top-level export
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .mesh_window import _keys_mesh

@@ -198,15 +198,9 @@ def _route_step(nk: int, nf: int, ni: int, N: int):
     cannot drop rows.  Returns, per shard, that shard's rows from every
     source slice in source order — globally the host path's stable
     destination order."""
-    import inspect
-
     import jax
     import jax.numpy as jnp
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .mesh_window import _keys_mesh
@@ -242,15 +236,11 @@ def _route_step(nk: int, nf: int, ni: int, N: int):
         return buf_ok, buf_f, buf_i
 
     mesh = _keys_mesh(nk)
-    _params = inspect.signature(shard_map).parameters
-    _check_kw = ({"check_vma": False} if "check_vma" in _params
-                 else {"check_rep": False} if "check_rep" in _params
-                 else {})
     fn = shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P("keys"), P(None, "keys"), P(None, "keys"), P("keys")),
         out_specs=(P("keys"), P(None, "keys"), P(None, "keys")),
-        **_check_kw)
+        check_vma=False)
     shard1 = NamedSharding(mesh, P("keys"))
     stack = NamedSharding(mesh, P(None, "keys"))
     # explicit in/out shardings: inputs staged by route() already carry

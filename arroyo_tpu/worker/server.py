@@ -299,6 +299,13 @@ async def run_worker(controller_addr: str, job_id: str,
 
 def main() -> None:
     logging.basicConfig(level=logging.INFO)
+    # claim the device BEFORE registering: a worker process that cannot
+    # get a chip (one process per chip) exits non-zero here, which the
+    # controller turns into a failed job — it neither hangs mid-job nor
+    # runs the job on the CPU (config.require_backend)
+    from ..config import require_backend
+
+    logger.info("worker backend: %s", require_backend())
     asyncio.run(run_worker(
         os.environ["CONTROLLER_ADDR"], os.environ["JOB_ID"],
         int(os.environ.get("TASK_SLOTS", "16")),

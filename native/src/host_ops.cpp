@@ -20,10 +20,6 @@
 
 extern "C" {
 
-// bump when any exported signature changes so the Python loader rebuilds
-// a stale cached .so instead of calling through a mismatched ABI
-int64_t arroyo_abi_version() { return 2; }
-
 static inline uint64_t splitmix64(uint64_t z) {
     z += 0x9E3779B97F4A7C15ULL;
     z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;

@@ -89,6 +89,44 @@ def test_serialize_step_roundtrip(tmp_path):
                                np.asarray(x + 1))
 
 
-def test_enable_persistent_cache(tmp_path):
-    d = enable_persistent_cache(str(tmp_path / "cache"))
-    assert "cache" in d
+_CACHE_DIR_PROBE = (
+    "import jax; from arroyo_tpu.engine.aot import enable_persistent_cache;"
+    "d = enable_persistent_cache();"
+    "assert jax.config.jax_compilation_cache_dir == d; print(d)")
+
+
+def _cache_dir_in_subprocess(env):
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run([sys.executable, "-c", _CACHE_DIR_PROBE], env=env,
+                       cwd=repo, capture_output=True, text=True, timeout=120)
+    assert r.returncode == 0, r.stderr[-500:]
+    return r.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_dir_rule(tmp_path):
+    """One rule for where the compile cache lives: JAX_COMPILATION_CACHE_DIR
+    if set, else the fixed in-checkout directory — the same in every
+    process (a host-derived or temp path never hits on a fresh machine),
+    and nothing under /tmp."""
+    import glob
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = set(glob.glob("/tmp/*jax_cache*"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "placed")
+    assert _cache_dir_in_subprocess(env) == str(tmp_path / "placed")
+    del env["JAX_COMPILATION_CACHE_DIR"]
+    first = _cache_dir_in_subprocess(env)
+    # a second process, another cwd-independent resolution, other XLA flags
+    # (the old default hashed the flags into the path)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    second = _cache_dir_in_subprocess(env)
+    assert first == second == os.path.join(repo, ".jax_cache")
+    assert set(glob.glob("/tmp/*jax_cache*")) == before
+    # in this process conftest placed the cache in a temp dir: honoured
+    assert enable_persistent_cache() == os.environ["JAX_COMPILATION_CACHE_DIR"]

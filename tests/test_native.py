@@ -227,3 +227,29 @@ def test_projection_pushdown_struct_and_join_keep_columns():
              for n in prog2.sources()]
     assert any(p and "person_name" in p for p in projs)
     assert any(p and "auction_seller" in p for p in projs)
+
+
+def test_loader_ties_binary_to_its_sources(tmp_path):
+    """The library's file name carries a hash of host_ops.cpp + Makefile,
+    so a binary built from other sources — a stale one copied along with
+    the tree — is never the file the loader opens."""
+    import os
+    import shutil
+
+    src = tmp_path / "native"
+    shutil.copytree(os.path.join(os.path.dirname(native.__file__),
+                                 "..", "..", "native"), src)
+    here = native.library_path()
+    assert here is not None and os.path.exists(here)
+    assert native.source_hash() in os.path.basename(here)
+    # the loaded library is exactly the one named after the committed sources
+    assert native.library_path(str(src), os.path.dirname(here)) == here
+    with open(src / "src" / "host_ops.cpp", "a") as f:
+        f.write("\n// edited\n")
+    edited = native.library_path(str(src), os.path.dirname(here))
+    assert edited != here and not os.path.exists(edited)
+    with open(src / "Makefile", "a") as f:
+        f.write("\n# edited\n")
+    assert native.library_path(str(src), os.path.dirname(here)) != edited
+    # no sources (an installed package without native/): nothing to load
+    assert native.library_path(str(tmp_path / "missing")) is None

@@ -6,14 +6,11 @@ mosaic compilation), so these tests validate kernel semantics everywhere.
 
 import numpy as np
 import jax.numpy as jnp
-import pytest
 
 from arroyo_tpu.graph.logical import AggKind, AggSpec
 from arroyo_tpu.ops.keyed_bins import KeyedBinState
-from arroyo_tpu.ops.pallas_kernels import (CHUNK, HAVE_PALLAS, pad_batch,
+from arroyo_tpu.ops.pallas_kernels import (CHUNK, pad_batch,
                                            scatter_add_channels)
-
-pytestmark = pytest.mark.skipif(not HAVE_PALLAS, reason="no pallas")
 
 
 def _ref_scatter(slots, bins, w, C, B):

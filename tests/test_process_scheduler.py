@@ -504,3 +504,69 @@ def test_rescaled_parallelism_survives_controller_restart(tmp_path):
     finally:
         os.environ.pop("CHECKPOINT_INTERVAL_SECS", None)
         reset_config()
+
+
+def test_spawn_inherits_jax_platforms_unchanged(monkeypatch):
+    """A worker process gets the parent's environment as it is:
+    JAX_PLATFORMS passes through unchanged, and none is added when the
+    parent has none — a ProcessScheduler job on a chip host must not
+    silently run on the CPU."""
+    import subprocess
+
+    from arroyo_tpu.worker import spawn
+
+    seen = []
+
+    class FakePopen:
+        def __init__(self, argv, env):
+            seen.append(env)
+
+    monkeypatch.setattr(subprocess, "Popen", FakePopen)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    spawn.spawn_worker_process("j", "http://c", 4)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    spawn.spawn_worker_process("j", "http://c", 4)
+    assert seen[0]["JAX_PLATFORMS"] == "tpu,cpu"
+    assert "JAX_PLATFORMS" not in seen[1]
+    assert seen[1]["JOB_ID"] == "j" and seen[1]["TASK_SLOTS"] == "4"
+
+
+def test_worker_that_dies_before_registering_fails_the_job(monkeypatch):
+    """More worker processes than chips: the extra worker cannot claim a
+    device and exits non-zero before registering.  The job must FAIL at
+    scheduling, promptly and with the exit code — not hang out the
+    registration deadline, and not run anywhere else."""
+    import subprocess
+    import sys
+    import time
+
+    from arroyo_tpu.worker import spawn
+
+    monkeypatch.setattr(
+        spawn, "spawn_worker_process",
+        lambda *a, **k: subprocess.Popen(
+            [sys.executable, "-c", "import sys; sys.exit(3)"]))
+
+    async def scenario():
+        sched = ProcessScheduler()
+        ctrl = ControllerServer(sched)
+        await ctrl.start()
+        prog = (Stream.source("impulse", {"event_rate": 0.0,
+                                          "message_count": 10,
+                                          "batch_size": 5})
+                .sink("blackhole", {}))
+        t0 = time.monotonic()
+        job_id = await ctrl.submit_job(prog, n_workers=1)
+        try:
+            state = await ctrl.wait_for_state(job_id, JobState.FAILED,
+                                              timeout=30)
+            return (state, ctrl.jobs[job_id].fsm.failure_message,
+                    time.monotonic() - t0)
+        finally:
+            await sched.stop_workers(job_id)
+            await ctrl.stop()
+
+    state, message, took = asyncio.run(scenario())
+    assert state == JobState.FAILED
+    assert "died before registering" in message and "rc=3" in message
+    assert took < 30

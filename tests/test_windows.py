@@ -458,7 +458,7 @@ def test_min_max_beyond_float32_range():
 
 
 def test_segment_aggregate_host_branch_parity(rng, monkeypatch):
-    """The tunnel-regime numpy-reduceat branch of segment_aggregate
+    """The host-pinned numpy-reduceat branch of segment_aggregate
     (ops/segment._segment_host) must match the device kernel on every
     channel kind — sums to f64 association tolerance, min/max/count
     exactly — including null skipping and all-null segments."""
@@ -635,7 +635,7 @@ def test_i32_counts_plane_promotes_to_i64(monkeypatch):
 
 def test_count_star_skips_f64_transfer():
     """A bare COUNT(*) query ships no f64 emit channels at all — the
-    aggregate IS the counts plane (tunnel-transfer optimization); mixed
+    aggregate IS the counts plane (a smaller transfer); mixed
     aggs keep their channels and stay correct alongside it."""
     from arroyo_tpu.ops.keyed_bins import KeyedBinState
 

@@ -1,0 +1,10 @@
+"""The benchmark's own tests: ``JAX_PLATFORMS=cpu python3 -m pytest
+benchmarks/tests -q -p no:cacheprovider``.  Not part of the repo's tier-1."""
+
+import os
+import sys
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+for p in (os.path.dirname(BENCH_DIR), BENCH_DIR):
+    if p not in sys.path:
+        sys.path.insert(0, p)

@@ -1,0 +1,13 @@
+"""The yardstick's arithmetic: contract limits, ticks, trace reducer, and
+the controls (a reference that breaks exactly-once is not correct), and the
+generator copy against the program's."""
+
+import pytest
+
+from selfcheck import check
+
+
+@pytest.mark.parametrize("name", ["manifest", "ticks", "trace_reducer",
+                                  "controls", "generator"])
+def test_selfcheck(name):
+    getattr(check, name)()

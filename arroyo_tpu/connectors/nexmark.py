@@ -480,18 +480,19 @@ class NexmarkSource(SourceOperator):
 
         def gen_next():
             # executor thread: generation/decode cost lands in the
-            # `source_decode` phase directly (no nesting off-loop) —
-            # the measured half of "the host path" on ingest
-            t0 = _time.perf_counter() if prof is not None else 0.0
-            b, nums = gen.next_batch(batch_size)
-            # RNG states are captured WITH the count at generation time,
-            # so a barrier between emit and prefetch checkpoints a
-            # consistent (count, stream-position) pair
-            out = b, nums, gen.events_so_far, gen.snapshot_rng_state()
-            if prof is not None:
-                prof.add(op_id, "source_decode",
-                         _time.perf_counter() - t0)
-            return out
+            # `source_decode` phase on this thread's own stack — the
+            # measured half of "the host path" on ingest
+            frame = (prof.begin(op_id, "source_decode")
+                     if prof is not None else None)
+            try:
+                b, nums = gen.next_batch(batch_size)
+                # RNG states are captured WITH the count at generation
+                # time, so a barrier between emit and prefetch checkpoints
+                # a consistent (count, stream-position) pair
+                return b, nums, gen.events_so_far, gen.snapshot_rng_state()
+            finally:
+                if frame is not None:
+                    prof.end(frame)
 
         # emission log for the latency bench: (cummax event time, wall) per
         # batch — latency is then measured against when the watermark-

@@ -938,20 +938,17 @@ class ControllerServer:
             if not phases and not waits:
                 continue
             # host vs device split from the profiler's OWN phase table:
-            # dispatch/device_execute are the kernel-bound spans, every
-            # other phase is pure host envelope.  (kernel_seconds is the
+            # dispatch is the kernel-bound span, every other phase is
+            # pure host envelope.  (kernel_seconds is the
             # same non-blocking dispatch wall as the `dispatch` phase —
             # re-reading it as "device" would count that span twice; it
             # only serves as the fallback when no dispatch phase was
             # recorded, e.g. a legacy worker without the profiler's
             # timed_device hook.)
-            device = sum(phases.get(p, 0.0)
-                         for p in ("dispatch", "device_execute"))
+            device = phases.get("dispatch", 0.0)
+            host = sum(phases.values()) - device
             if device == 0.0:
                 device = row.get("kernel_seconds", 0.0)
-            host = sum(phases.values()) - sum(
-                phases.get(p, 0.0) for p in ("dispatch",
-                                             "device_execute"))
             ops.append({
                 "operator_id": op,
                 "phases": phases,

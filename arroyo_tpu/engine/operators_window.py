@@ -298,10 +298,30 @@ class BinAggOperator(Operator):
             else:
                 fired = self.state.fire_panes(watermark, final=final)
             if fired is not None:
-                await self._emit(fired, ctx)
+                await self._emit(fired, ctx, int(watermark))
         await ctx.broadcast(Message.wm(Watermark.event_time(watermark)))
 
-    async def _emit(self, fired, ctx: Context) -> None:
+    async def _emit(self, fired, ctx: Context,
+                    watermark: Optional[int] = None) -> None:
+        from ..obs import perf, tracing
+
+        # the host half of the fire after the readbacks: the `emit` phase
+        # and, for a watermark fire, the `window.fire.emit` span
+        # (``watermark`` ties it to its `window.fire`; a checkpoint drain
+        # has none); ``ctx.collect`` below keeps its own phases
+        tok = perf.begin_phase("emit")
+        t0 = tracing.now_us()
+        try:
+            out = self._fired_batch(fired)
+        finally:
+            perf.end_phase(tok)
+        if watermark is not None:
+            tracing.record_span(
+                "window.fire.emit", "window", t0, tracing.now_us() - t0,
+                tid=tracing.ctx_tid(ctx), args={"watermark": watermark})
+        await ctx.collect(out)
+
+    def _fired_batch(self, fired) -> Batch:
         keys, out_cols, window_end, counts = fired
         # key_idx into slot arrays for key-column recovery
         slot_idx = self.state.slot_of_sorted[
@@ -321,7 +341,7 @@ class BinAggOperator(Operator):
             out = _apply_top_n(out, *self.top_n)
         if self.projection is not None:
             out = eval_record_expr(self.projection, out)
-        await ctx.collect(out)
+        return out
 
 
 class FactorPaneOperator(BinAggOperator):

@@ -114,10 +114,12 @@ _STAGE_FOLD = {
                       ("coalesce_wait", True)),
     "queue_wait": (("queue_wait", True), ("send_wait", True),
                    ("net_flush", True)),
-    "fire": (("watermark", False),),
+    "fire": (("watermark", False), ("d2h_wait", False),
+             ("fire_flatten", False), ("emit", False)),
     "emit": (("emit_encode", False), ("frame_encode", False)),
-    "compute": (("proc", False), ("dispatch", False),
-                ("device_execute", False), ("shuffle_prep", False),
+    "compute": (("proc", False), ("dir_insert", False),
+                ("preagg", False), ("h2d", False), ("dispatch", False),
+                ("shuffle_prep", False),
                 ("frame_decode", False), ("reshard", False),
                 ("shuffle_collective", False), ("gather", False)),
 }

@@ -1,24 +1,19 @@
 """Lightweight performance accounting.
 
-Two tiers:
-
-* **Always-cheap per-operator accumulator** — every ``timed_device`` call
-  made while a task has installed a :class:`KernelAccumulator` (the
-  TaskRunner does this) adds its dispatch wall time to that operator's
-  ``arroyo_worker_kernel_seconds_total`` counter and, for spans above a
-  floor, to the flight-recorder trace ring.  Dispatch is *not* blocked
-  on, so the cost is two ``perf_counter_ns`` reads per kernel — safe in
-  production.
-* **Blocking measurement mode**, enabled by ``ARROYO_TIMING=1``: blocks
-  on the kernel result at the call site so the ``device_ns`` counter is
-  true device time.  Serializes dispatch — use for measurement runs
-  (bench.py's device_share), not production.
+**Always-cheap per-operator accumulator** — every ``timed_device`` call
+made while a task has installed a :class:`KernelAccumulator` (the
+TaskRunner does this) adds its dispatch wall time to that operator's
+``arroyo_worker_kernel_seconds_total`` counter and, for spans above a
+floor, to the flight-recorder trace ring under the kernel's own name.
+Dispatch is *not* blocked on, so the cost is two ``perf_counter_ns``
+reads per kernel — safe in production.  Device time is read from a
+device trace (``POST /debug/profile``), where every program carries the
+name :func:`kernel_name` gave it.
 """
 
 from __future__ import annotations
 
 import contextvars
-import os
 import time
 from contextvars import ContextVar
 from typing import Any, Dict, Optional
@@ -46,14 +41,14 @@ class KernelAccumulator:
         self.operator_id = task_info.operator_id
         self.counter = getattr(metrics, "kernel_time", None)
 
-    def add(self, ns: int) -> None:
+    def add(self, ns: int, kernel: str = "kernel") -> None:
         if self.counter is not None:
             self.counter.inc(ns / 1e9)
         if ns >= _TRACE_FLOOR_NS:
             from . import tracing
 
             end = tracing.now_us()
-            tracing.record_span("kernel", "kernel", end - ns / 1e3,
+            tracing.record_span(kernel, "kernel", end - ns / 1e3,
                                 ns / 1e3, tid=self.task_id)
 
 
@@ -80,18 +75,57 @@ def active_operator_id() -> Optional[str]:
     return acc.operator_id if acc is not None else None
 
 
-def run_offloaded(loop, fn, *args):
+def active_task_id() -> str:
+    """Trace track id of the current context's task ('' off-task)."""
+    acc = _ACTIVE_TASK.get()
+    return acc.task_id if acc is not None else ""
+
+
+def begin_phase(phase: str):
+    """Open profiler work phase ``phase`` for the current context's
+    operator — for state-layer code that has no operator id at hand.
+    Returns the token for :func:`end_phase`: ``None`` while the profiler
+    is off, so a site costs one call and one test."""
+    prof = _profiler.active()
+    if prof is None:
+        return None
+    return prof, prof.begin(active_operator_id() or "kernel", phase)
+
+
+def end_phase(token) -> None:
+    if token is not None:
+        token[0].end(token[1])
+
+
+async def run_offloaded(loop, fn, *args):
     """``loop.run_in_executor`` with contextvars propagated: executor
     threads don't inherit the caller's context, so kernels dispatched
     from an offloaded transfer would otherwise bypass the active task's
     accumulator and report zero kernel time exactly on the accelerator
-    backends where offload is enabled."""
+    backends where offload is enabled.  The await is an ``offload_wait``
+    wait child of the caller's profiler frame: what the executor thread
+    does is accounted on its own stack, and never charged to the
+    caller's ``proc``/``watermark`` as well."""
     ctx = contextvars.copy_context()
-    return loop.run_in_executor(None, lambda: ctx.run(fn, *args))
+    prof = _profiler.active()
+    frame = (prof.begin(active_operator_id() or "offload", "offload_wait",
+                        wait=True) if prof is not None else None)
+    try:
+        return await loop.run_in_executor(None, lambda: ctx.run(fn, *args))
+    finally:
+        if frame is not None:
+            prof.end(frame)
 
 
-def timing_enabled() -> bool:
-    return bool(os.environ.get("ARROYO_TIMING"))
+def kernel_name(name: str):
+    """Decorator under ``@jax.jit``: gives the traced function a stable
+    name, so XLA's module reads ``jit_<name>`` in a device trace and
+    :func:`timed_device` counts and spans the kernel under it."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
+    return rename
 
 
 def reset() -> None:
@@ -100,14 +134,11 @@ def reset() -> None:
 
 
 def counter(key: str) -> int:
-    """Counter read (ns-valued keys like ``device_ns``, and plain counts
-    like ``kernel_dispatches`` — the number of device-kernel dispatches
-    made through :func:`timed_device`, which bench.py turns into
-    dispatches-per-event)."""
+    """Counter read (plain counts like ``kernel_dispatches`` — the number
+    of device-kernel dispatches made through :func:`timed_device`, which
+    bench.py turns into dispatches-per-event — and the microsecond sums
+    ``wait_us.<phase>``)."""
     return _COUNTERS.get(key, 0)
-
-
-counter_ns = counter  # legacy name for the ns-valued keys
 
 
 def count(key: str, n: int = 1) -> None:
@@ -124,40 +155,33 @@ def get_note(key: str, default: Any = None) -> Any:
     return _NOTES.get(key, default)
 
 
-def timed_device(call, *args):
-    """Run a jitted kernel call.  Always: attribute dispatch wall time to
-    the active task's kernel accumulator (cheap, non-blocking).  With
-    ``ARROYO_TIMING=1``: additionally block until the result is ready and
-    account true device time to the ``device_ns`` counter.  With the
-    phase profiler armed, the span also lands in the phase table — as
-    ``dispatch`` (host-side envelope) normally, as ``device_execute``
-    when blocking — nested so the enclosing ``proc`` phase stays
-    exclusive."""
-    blocking = timing_enabled()
+def timed_device(call, *args, in_total: bool = True):
+    """Run a jitted kernel call, never blocking on its result: attribute
+    the dispatch wall time to the active task's kernel accumulator and
+    count it, in total (``kernel_dispatches``) and under the callee's name
+    (``kernel_dispatches.<name>``).  With the phase profiler armed the
+    span also lands in the phase table as ``dispatch`` — nested, so the
+    enclosing phase stays exclusive.  ``in_total=False`` is for the calls
+    that bypassed this function until the kernels were named (the evict):
+    they count under their name only, so that the unnamed total, which
+    the benchmark's ``kernel_dispatches_per_mev`` reads, keeps counting
+    what it always counted."""
     acc = _ACTIVE_TASK.get()
-    if not blocking and acc is None:
+    if acc is None:
         return call(*args)
+    name = getattr(call, "__name__", "kernel")
     prof = _profiler.active()
-    frame = None
-    if prof is not None:
-        frame = prof.begin(
-            acc.operator_id if acc is not None else "kernel",
-            "device_execute" if blocking else "dispatch")
-    _COUNTERS["kernel_dispatches"] = _COUNTERS.get(
-        "kernel_dispatches", 0) + 1
+    frame = (prof.begin(acc.operator_id, "dispatch")
+             if prof is not None else None)
+    if in_total:
+        count("kernel_dispatches")
+    count("kernel_dispatches." + name)
     t0 = time.perf_counter_ns()
     try:
         out = call(*args)
-        if blocking:
-            import jax
-
-            jax.block_until_ready(out)
     finally:
         dt = time.perf_counter_ns() - t0
         if frame is not None:
             prof.end(frame)
-    if blocking:
-        _COUNTERS["device_ns"] = _COUNTERS.get("device_ns", 0) + dt
-    if acc is not None:
-        acc.add(dt)
+    acc.add(dt, name)
     return out

@@ -16,10 +16,22 @@ phase               measured where
 ``proc``            operator ``process_batch`` host compute, EXCLUSIVE of
                     the nested phases below (per chain member for fused
                     operators)
-``dispatch``        host-side kernel dispatch wall time (``perf.timed_device``
-                    without blocking — the Python/jax envelope around XLA)
-``device_execute``  same site under ``ARROYO_TIMING=1``: dispatch blocked on
-                    the result, so the span is true device time
+``dir_insert``      key directory lookup/insert of the bin state
+                    (``KeyedBinState._lookup_or_insert``: the native
+                    ``insert`` plus ``_append_new_keys``)
+``preagg``          the bin state's host half of an update: bin admission,
+                    the per-(slot, bin) reduce, the cross-batch merge of a
+                    coalesced flush, the pad and pack of the kernel inputs
+``h2d``             ``jnp.asarray`` of a bin-state kernel's host inputs
+                    (update, fire, evict)
+``dispatch``        host-side kernel dispatch wall time (``perf.timed_device``,
+                    never blocking — the Python/jax envelope around XLA)
+``d2h_wait``        every blocking device->host readback of a pane fire
+                    (the thread sits in it until the device has finished)
+``fire_flatten``    fired cells -> flat key/pane/value columns on the host
+``emit``            ``BinAggOperator._emit`` up to ``ctx.collect``: key
+                    columns gathered, the output batch built, top-n and
+                    projection applied
 ``shuffle_prep``    Collector partition/route/select CPU before fan-out
 ``coalesce_merge``  input-side batch concat in the coalescer
 ``watermark``       timer fires + ``handle_watermark`` (window fires live
@@ -44,7 +56,12 @@ phase               measured where
 
 plus overlapping **wait** phases (reported separately, never summed into
 the work table): ``queue_wait``, ``coalesce_wait``, ``send_wait``
-(backpressure enqueue), ``net_flush`` (socket drain).
+(backpressure enqueue), ``net_flush`` (socket drain), ``offload_wait``
+(the loop-side await of ``perf.run_offloaded`` while an executor thread
+runs the bin state's update or fire).  ``send_wait`` and ``offload_wait``
+frames also add their microseconds to the ``perf`` counters
+``wait_us.send_wait`` / ``wait_us.offload_wait``, summed over operators
+(:data:`COUNTED_WAITS`).
 
 Accounting model
 ----------------
@@ -55,12 +72,22 @@ LIFO within one task.  A frame's recorded time is **exclusive**: child
 frames — including *wait* frames that span awaits — subtract their full
 inclusive span from the parent.  Work phases are only opened around
 synchronous blocks (their only interior awaits are wrapped as wait
-children), so no other task's work can ever be charged to them: summed
+children — the executor hop of ``perf.run_offloaded`` as ``offload_wait``),
+so no other task's or thread's work can ever be charged to them: summed
 work phases per thread can never exceed that thread's busy wall time.
-Executor-side work (source prefetch, offloaded transfers) overlaps the
-event loop by design, so a job's summed work phases may exceed wall
-time — the bench reports the raw ratio and flags the overlap, exactly
-like ``device_time_share`` already does.
+Executor-side work (source prefetch, offloaded update and fire) overlaps
+the event loop by design, so a job's work phases summed over ALL threads
+may exceed wall time — the bench reports the raw ratio and flags the
+overlap.
+
+While armed, every work frame is mirrored as a
+``jax.profiler.TraceAnnotation(phase, op=op_id)`` on the thread that runs
+it, so a device trace taken meanwhile (``POST /debug/profile``, the
+benchmark's ``--trace 1``) shows what each host thread was doing on the
+trace's own clock, beside ``XLA Ops``.  Wait frames are not mirrored, and
+the open work annotations of a task are closed for as long as one of its
+wait frames lasts: an annotation covers only time the thread really spent
+in that phase.
 
 Off-path discipline (same as arroyosan): every instrumentation site
 holds a local that is ``None`` unless the profiler was armed
@@ -100,11 +127,13 @@ __all__ = [
     "WAIT_PHASES",
 ]
 
-WORK_PHASES = ("source_decode", "proc", "dispatch", "device_execute",
+WORK_PHASES = ("source_decode", "proc", "dir_insert", "preagg", "h2d",
+               "dispatch", "d2h_wait", "fire_flatten", "emit",
                "shuffle_prep", "coalesce_merge", "watermark", "checkpoint",
                "emit_encode", "frame_encode", "frame_decode", "reshard",
                "shuffle_collective", "gather", "session_merge")
-WAIT_PHASES = ("queue_wait", "coalesce_wait", "send_wait", "net_flush")
+WAIT_PHASES = ("queue_wait", "coalesce_wait", "send_wait", "net_flush",
+               "offload_wait")
 
 
 def profile_enabled() -> bool:
@@ -151,6 +180,14 @@ def ensure_armed(job_id: str = "") -> Optional["Profiler"]:
     return None
 
 
+# the wait frames whose microseconds also land on a ``perf`` counter
+# ``wait_us.<phase>`` (summed over operators), where a counter reader can
+# take them between two ticks: the benchmark's ``source_blocked_s_per_mev``
+# and ``offload_wait_s_per_mev``.  The waits per operator are in
+# :meth:`Profiler.wait_snapshot`.
+COUNTED_WAITS = frozenset(("send_wait", "offload_wait"))
+
+
 # -- frame stacks ------------------------------------------------------------
 
 # Per-task stacks: a contextvar gives every asyncio task its own box (so
@@ -169,8 +206,9 @@ class _StackBox:
 _STACK: ContextVar[Optional[_StackBox]] = ContextVar(
     "arroyo_profiler_stack", default=None)
 
-# frame layout: [op_id, phase, is_wait, t0, child_inclusive_secs]
-_OP, _PHASE, _WAIT, _T0, _CHILD = range(5)
+# frame layout: [op_id, phase, is_wait, t0, child_inclusive_secs,
+#                open TraceAnnotation or None]
+_OP, _PHASE, _WAIT, _T0, _CHILD, _ANN = range(6)
 
 
 class Profiler:
@@ -183,8 +221,14 @@ class Profiler:
         self._work: Dict[Tuple[str, str], float] = {}
         self._waits: Dict[Tuple[str, str], float] = {}
         self._counts: Dict[Tuple[str, str], int] = {}
+        # exclusive work seconds by the thread that ran the frame: the
+        # check of "per thread, work never exceeds the thread's wall"
+        self._thread_work: Dict[str, float] = {}
         self._t0 = time.perf_counter()
         self.watchdog = LoopWatchdog(job_id=job_id)
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
 
     # -- hot-path API ------------------------------------------------------
 
@@ -200,12 +244,26 @@ class Profiler:
         """Open a phase frame; returns the token for :meth:`end`.  Work
         frames must not span an await except through nested wait
         children (the site discipline the accounting model rests on)."""
-        f = [op_id, phase, wait, time.perf_counter(), 0.0]
-        self._frames().append(f)
+        frames = self._frames()
+        ann = None
+        if wait:
+            # the thread leaves the enclosing work phases for the await
+            for g in frames:
+                if g[_ANN] is not None:
+                    g[_ANN].__exit__(None, None, None)
+                    g[_ANN] = None
+        else:
+            ann = self._annotation(phase, op=op_id)
+            ann.__enter__()
+        f = [op_id, phase, wait, time.perf_counter(), 0.0, ann]
+        frames.append(f)
         return f
 
     def end(self, f: list) -> None:
         now = time.perf_counter()
+        if f[_ANN] is not None:
+            f[_ANN].__exit__(None, None, None)
+            f[_ANN] = None
         frames = self._frames()
         if frames and frames[-1] is f:
             frames.pop()
@@ -223,17 +281,30 @@ class Profiler:
             excl = 0.0
         if frames:
             frames[-1][_CHILD] += dt
+        if f[_WAIT]:
+            if f[_PHASE] in COUNTED_WAITS:
+                from . import perf  # perf imports this module
+
+                perf.count("wait_us." + f[_PHASE], int(dt * 1e6))
+            if not any(g[_WAIT] for g in frames):
+                # back from the await: the work phases go on
+                for g in frames:
+                    g[_ANN] = self._annotation(g[_PHASE], op=g[_OP])
+                    g[_ANN].__enter__()
         key = (f[_OP], f[_PHASE])
         with self._lock:
             d = self._waits if f[_WAIT] else self._work
             d[key] = d.get(key, 0.0) + excl
             self._counts[key] = self._counts.get(key, 0) + 1
+            if not f[_WAIT]:
+                name = threading.current_thread().name
+                self._thread_work[name] = self._thread_work.get(
+                    name, 0.0) + excl
 
     def add(self, op_id: str, phase: str, secs: float,
             wait: bool = False, count: int = 1) -> None:
         """Direct accounting for sites that measure their own span and
-        cannot nest (executor-thread source generation, the task loop's
-        input waits)."""
+        cannot nest (the task loop's input waits)."""
         key = (op_id, phase)
         with self._lock:
             d = self._waits if wait else self._work
@@ -257,6 +328,7 @@ class Profiler:
             self._work.clear()
             self._waits.clear()
             self._counts.clear()
+            self._thread_work.clear()
             self._t0 = time.perf_counter()
         self.watchdog.reset()
 
@@ -268,12 +340,19 @@ class Profiler:
         with self._lock:
             return dict(self._waits)
 
+    def thread_work_snapshot(self) -> Dict[str, float]:
+        """{thread name: exclusive work seconds of the frames it ran}
+        (frames only: :meth:`add` knows no thread)."""
+        with self._lock:
+            return dict(self._thread_work)
+
     def snapshot(self) -> Dict[str, Any]:
         """Full structured snapshot: per-operator work/wait phase maps,
         job-level phase totals, wall since arm/reset, watchdog stats."""
         with self._lock:
             work, waits = dict(self._work), dict(self._waits)
             counts = dict(self._counts)
+            threads = dict(self._thread_work)
             wall = time.perf_counter() - self._t0
         ops: Dict[str, Dict[str, Any]] = {}
         phases: Dict[str, float] = {}
@@ -299,6 +378,7 @@ class Profiler:
             "unattributed_share": round(
                 max(1.0 - attributed / wall, 0.0), 4) if wall > 0 else 0.0,
             "operators": {op: v for op, v in sorted(ops.items())},
+            "threads": {t: round(v, 6) for t, v in sorted(threads.items())},
             "counts": {f"{op}/{ph}": n for (op, ph), n in sorted(
                 counts.items())},
             "watchdog": self.watchdog.stats(),

@@ -26,6 +26,7 @@ import numpy as np
 
 import weakref
 
+from ..obs.perf import kernel_name
 from ..types import Batch
 
 _MIN_BUCKET = 256
@@ -111,6 +112,7 @@ class CompiledExpr:
             fn = self.fn
 
             @jax.jit
+            @kernel_name("expr_compiled")
             def run(num_cols: Dict[str, jnp.ndarray]):
                 return fn(dict(num_cols))
 

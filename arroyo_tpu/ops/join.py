@@ -32,7 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..obs.perf import timed_device
+from ..obs.perf import kernel_name, timed_device
 
 # padding key: sorts after every real hash; a real key colliding with it
 # (probability ~2^-64 per row) routes the call to the host fallback
@@ -65,6 +65,7 @@ def _merged_probe() -> bool:
 @functools.lru_cache(maxsize=64)
 def _sort_kernel(n: int):
     @jax.jit
+    @kernel_name("join_sort")
     def run(keys):
         order = jnp.argsort(keys, stable=True)
         return order, keys[order]
@@ -76,6 +77,7 @@ def _sort_kernel(n: int):
 def _probe_kernel(nl: int, nr: int, merged: bool):
     if not merged:
         @jax.jit
+        @kernel_name("join_probe")
         def run(lk_sorted, rk_sorted, nl_valid, nr_valid):
             start = jnp.searchsorted(rk_sorted, lk_sorted, side="left")
             end = jnp.searchsorted(rk_sorted, lk_sorted, side="right")
@@ -89,6 +91,7 @@ def _probe_kernel(nl: int, nr: int, merged: bool):
         return run
 
     @jax.jit
+    @kernel_name("join_probe_merged")
     def run(lk_sorted, rk_sorted, nl_valid, nr_valid):
         # merged-rank probe: for every (already sorted) left key, how
         # many right keys are < / <= it falls out of its position in a
@@ -120,6 +123,7 @@ def _probe_kernel(nl: int, nr: int, merged: bool):
 @functools.lru_cache(maxsize=64)
 def _expand_kernel(nl: int, m: int):
     @jax.jit
+    @kernel_name("join_expand")
     def run(start, cum):
         # pair j belongs to the left row whose cumulative-count interval
         # contains j (cum[i-1] <= j < cum[i]), i.e.
@@ -371,6 +375,7 @@ def stage_ring(sorted_keys: np.ndarray, device: Any = None,
 @functools.lru_cache(maxsize=64)
 def _merge32_kernel(cap: int, db: int, nf: int, ni: int):
     @jax.jit
+    @kernel_name("join_merge32")
     def run(hi, lo, fstack, istack, res_pos, d_hi, d_lo, d_f, d_i,
             delta_pos):
         out_hi = jnp.full(cap, SENT32_HI, jnp.int32)
@@ -491,6 +496,7 @@ def _expand_gather_kernel(mq: int, cap: int, m: int, nf: int, ni: int):
     payload-plane gather for BOTH stacks, all in one jitted call."""
 
     @jax.jit
+    @kernel_name("join_expand_gather")
     def run(start, cum, hi, lo, q_hi, q_lo, fstack, istack):
         dt = cum.dtype
         mark = jnp.zeros(m + 1, dt).at[cum].add(1, mode="drop")
@@ -534,6 +540,7 @@ def expand_gather(ring: SplitRing, hit: ProbeHit, total: int
 @functools.lru_cache(maxsize=64)
 def _gather32_kernel(cap: int, m: int, nf: int, ni: int):
     @jax.jit
+    @kernel_name("join_gather32")
     def run(idx, fstack, istack):
         gf = (fstack[:, idx] if nf
               else jnp.zeros((0, m), jnp.float64))

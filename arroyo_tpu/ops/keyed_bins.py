@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graph.logical import AggKind, AggSpec
+from ..obs import perf, tracing
+from ..obs.perf import kernel_name, timed_device
 
 # f64 extremes (the accumulation channels are float64, see ACC_DTYPE):
 # f32 extremes here would clip MIN/MAX values beyond +/-3.4e38.  The
@@ -64,6 +66,7 @@ def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
     dup_set = frozenset(dup)
 
     @jax.jit
+    @kernel_name("bins_update")
     def run(values, counts, idx, packed):
         # TWO packed inputs (two host->device transfers: indices stay
         # i32 instead of riding the f64 pack at twice the bytes):
@@ -79,8 +82,9 @@ def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
         vals = packed[1:]
         s = jnp.where(valid, slots, C)  # trash row
         b = jnp.where(valid, bins, 0)
-        counts = counts.at[s.clip(0, C - 1), b].add(
-            jnp.where(valid & (s < C), rowcnt, 0.0).astype(counts.dtype))
+        with jax.named_scope("scatter_counts"):
+            counts = counts.at[s.clip(0, C - 1), b].add(
+                jnp.where(valid & (s < C), rowcnt, 0.0).astype(counts.dtype))
         outs = []
         r = 0
         for i, kind in enumerate(kinds):
@@ -92,16 +96,18 @@ def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
                 r += 1
             ok = valid & (s < C)
             si = s.clip(0, C - 1)
-            if kind in ("sum", "avg", "count"):
-                v = v.at[si, b].add(jnp.where(ok, x, 0.0))
-            elif kind == "min":
-                v = v.at[si, b].min(jnp.where(ok, x, POS_INF))
-            elif kind == "max":
-                v = v.at[si, b].max(jnp.where(ok, x, NEG_INF))
-            else:
-                raise ValueError(kind)
+            with jax.named_scope("scatter_accumulate"):
+                if kind in ("sum", "avg", "count"):
+                    v = v.at[si, b].add(jnp.where(ok, x, 0.0))
+                elif kind == "min":
+                    v = v.at[si, b].min(jnp.where(ok, x, POS_INF))
+                elif kind == "max":
+                    v = v.at[si, b].max(jnp.where(ok, x, NEG_INF))
+                else:
+                    raise ValueError(kind)
             outs.append(v)
-        return jnp.stack(outs), counts
+        with jax.named_scope("plane_write_back"):
+            return jnp.stack(outs), counts
 
     return run
 
@@ -136,18 +142,22 @@ def _emit_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int, k: int,
         keep = tuple(range(len(kinds)))
 
     @jax.jit
+    @kernel_name("bins_emit")
     def run(values, counts, ring, bin_ok):
         # counts per key per pane: gather [C, k, W] then sum
-        cnt_g = counts[:, ring]  # [C, k, W]
-        cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)  # [C, k]
-        if cnt16:
-            cnt = cnt.astype(jnp.uint16)
+        with jax.named_scope("window_reduce_counts"):
+            cnt_g = counts[:, ring]  # [C, k, W]
+            cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)
+            if cnt16:
+                cnt = cnt.astype(jnp.uint16)
 
         outs = []
         for i in keep:
             # (avg division happens on host from the validity-count
             # channel — NOT from cnt, which counts null rows too)
-            outs.append(_pane_reduce(kinds[i], values[i][:, ring], bin_ok))
+            with jax.named_scope("window_reduce"):
+                outs.append(_pane_reduce(kinds[i], values[i][:, ring],
+                                         bin_ok))
         return (jnp.stack(outs) if outs else jnp.zeros((0, C, k))), cnt
 
     return run
@@ -162,16 +172,20 @@ def _argmax_nnz_kernel(C: int, B: int, W: int, k: int, minmax: str):
     the downstream WindowArgmax stage."""
 
     @jax.jit
+    @kernel_name("bins_argmax_nnz")
     def run(counts, ring, bin_ok):
-        cnt_g = counts[:, ring]  # [C, k, W]
-        cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)  # [C, k]
-        if minmax == "max":
-            ext = jnp.max(cnt, axis=0)  # counts are >= 0: empty cells lose
-        else:
-            big = jnp.iinfo(cnt.dtype).max
-            ext = jnp.min(jnp.where(cnt > 0, cnt, big), axis=0)
-        sel = (cnt == ext[None, :]) & (cnt > 0)
-        return cnt, sel, jnp.sum(sel)
+        with jax.named_scope("window_reduce"):
+            cnt_g = counts[:, ring]  # [C, k, W]
+            cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)
+        with jax.named_scope("pane_extremum"):
+            if minmax == "max":
+                ext = jnp.max(cnt, axis=0)  # counts >= 0: empty cells lose
+            else:
+                big = jnp.iinfo(cnt.dtype).max
+                ext = jnp.min(jnp.where(cnt > 0, cnt, big), axis=0)
+        with jax.named_scope("candidate_select"):
+            sel = (cnt == ext[None, :]) & (cnt > 0)
+            return cnt, sel, jnp.sum(sel)
 
     return run
 
@@ -181,6 +195,7 @@ def _argmax_gather_kernel(C: int, B: int, W: int, k: int, npad: int):
     """Phase 2: gather ONLY the candidate cells' (key, pane, count)."""
 
     @jax.jit
+    @kernel_name("bins_argmax_gather")
     def run(cnt, sel):
         flat = sel.reshape(-1)
         idx = jnp.nonzero(flat, size=npad, fill_value=C * k)[0]
@@ -201,6 +216,7 @@ def _emit_count_kernel(C: int, B: int, W: int, k: int):
     grid — the scalar sizes phase 2's static-shape compaction)."""
 
     @jax.jit
+    @kernel_name("bins_emit_count")
     def run(counts, ring, bin_ok):
         cnt_g = counts[:, ring]  # [C, k, W]
         cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)  # [C, k]
@@ -219,6 +235,7 @@ def _emit_compact_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int,
     host-side np.nonzero scan."""
 
     @jax.jit
+    @kernel_name("bins_emit_compact")
     def run(values, cnt, ring, bin_ok):
         flat = cnt.reshape(-1)  # [C * k]
         idx = jnp.nonzero(flat > 0, size=npad, fill_value=C * k)[0]
@@ -245,6 +262,7 @@ def _linearize_kernel(kinds: Tuple[str, ...], C: int, B: int, L: int):
     aggregation identity.  Feeds the ring-pane emission path."""
 
     @jax.jit
+    @kernel_name("bins_linearize")
     def run(values, counts, ring_idx, ok):
         outs = []
         for i, kind in enumerate(kinds):
@@ -261,6 +279,7 @@ def _linearize_kernel(kinds: Tuple[str, ...], C: int, B: int, L: int):
 @functools.lru_cache(maxsize=256)
 def _evict_kernel(kinds: Tuple[str, ...], C: int, B: int):
     @jax.jit
+    @kernel_name("bins_evict")
     def run(values, counts, ring_slots, slot_valid):
         # zero expired ring columns
         mask = jnp.zeros((B,), dtype=bool).at[
@@ -306,6 +325,62 @@ def _prefetch_host(*arrays) -> None:
                 start()
             except Exception:  # pragma: no cover - non-committed arrays
                 pass
+
+
+def _in_phase(phase: str):
+    """Decorator: the call is profiler work phase ``phase`` of the active
+    operator (one test per call while the profiler is off)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def phased(*args, **kwargs):
+            tok = perf.begin_phase(phase)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                perf.end_phase(tok)
+
+        return phased
+
+    return wrap
+
+
+@_in_phase("h2d")
+def _h2d(*host_arrays):
+    """``jnp.asarray`` of a kernel's host inputs."""
+    return [jnp.asarray(a) for a in host_arrays]
+
+
+def _readback(state, dev) -> np.ndarray:
+    """One blocking device->host readback of a fire or drain: the
+    ``d2h_wait`` phase, counted, and kept on ``state._d2h`` for the fire's
+    ``window.fire.d2h`` span."""
+    tok = perf.begin_phase("d2h_wait")
+    t0 = tracing.now_us()
+    try:
+        out = np.asarray(dev)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
+    finally:
+        state._d2h.append((t0, tracing.now_us() - t0))
+        perf.end_phase(tok)
+    perf.count("d2h_syncs")
+    perf.count("d2h_bytes", out.nbytes)
+    return out
+
+
+def _fire_done(state, watermark: Optional[int]) -> None:
+    """Close the accounts of a pass that read panes back.  A watermark
+    fire counts ``window_fires`` and records its readbacks as ONE
+    ``window.fire.d2h`` span (from the first readback's start, as long as
+    all of them together) carrying the watermark that ``window.fire`` and
+    ``window.fire.emit`` carry; a checkpoint drain (``watermark`` None)
+    has no ``window.fire`` around it and counts ``pane_drains``."""
+    d2h, state._d2h = state._d2h, []
+    if watermark is None:
+        perf.count("pane_drains")
+        return
+    perf.count("window_fires")
+    tracing.record_span(
+        "window.fire.d2h", "window", d2h[0][0], sum(dur for _, dur in d2h),
+        tid=perf.active_task_id(), args={"watermark": watermark})
 
 
 # -- shared channel + directory semantics (single-device AND mesh state) -----
@@ -479,6 +554,7 @@ def _append_new_keys(state, new_keys: np.ndarray, ensure_capacity) -> None:
     if not len(new_keys):
         return
     n_new = len(new_keys)
+    perf.count("keys_inserted", n_new)
     ensure_capacity(state.next_slot + n_new, new_keys)
     new_slots = np.arange(state.next_slot, state.next_slot + n_new)
     state.slot_to_key[new_slots] = new_keys
@@ -586,9 +662,13 @@ class KeyedBinState:
         # unfactored member would have built from the same rows
         self._merge_cols: Optional[Dict[int, str]] = None
         self._rows_col: Optional[str] = None
+        # (start, duration) in tracing microseconds of each blocking
+        # readback of the fire under way
+        self._d2h: List[Tuple[float, float]] = []
 
     # -- key directory -----------------------------------------------------
 
+    @_in_phase("dir_insert")
     def _lookup_or_insert(self, kh: np.ndarray) -> np.ndarray:
         """Vectorized key hash -> slot id, inserting unknown keys."""
         def ensure(total, _new_keys):
@@ -598,6 +678,7 @@ class KeyedBinState:
         return directory_insert(self, kh, ensure)
 
     def _grow(self, needed: int) -> None:
+        perf.count("state_grows")
         newC = self.C
         while newC < needed:
             newC <<= 1
@@ -632,6 +713,7 @@ class KeyedBinState:
         self._merge_cols = dict(channel_cols)
         self._rows_col = rows_col
 
+    @_in_phase("preagg")  # its directory lookup, h2d and dispatch nest
     def update(self, key_hash: np.ndarray, timestamps: np.ndarray,
                agg_inputs: Dict[str, np.ndarray]) -> None:
         n = len(key_hash)
@@ -641,8 +723,6 @@ class KeyedBinState:
         # pane-update state per event is ~K unfactored (every ring sees
         # every event) vs ~1 + O(panes) factored (derived rings see only
         # fired pane cells) — the correlated_windows bench reads these
-        from ..obs import perf
-
         perf.count("pane_update_rows", n)
         if self._merge_cols is not None:
             self._update_merged(key_hash, timestamps, agg_inputs)
@@ -790,6 +870,7 @@ class KeyedBinState:
         vals_c = red[:-1]
         self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c, lo, hi)
 
+    @_in_phase("preagg")
     def flush_updates(self) -> None:
         """Apply every buffered pre-aggregated cell run to the device
         planes in ONE scatter dispatch.  Called by every plane reader
@@ -813,17 +894,17 @@ class KeyedBinState:
 
     def _dispatch_cells(self, slots_c: np.ndarray, bins_c: np.ndarray,
                         rowcnt: np.ndarray, vals_c: np.ndarray) -> None:
-        from ..obs import perf
-
+        m = len(slots_c)
         perf.count("pane_update_dispatches")
+        perf.count("pane_update_cells", m)
         # additive aggregates route through the Pallas MXU scatter (one-hot
         # matmul) instead of XLA's serial scatter; min/max stay on XLA
         if self._use_pallas():
             self._update_pallas(slots_c, bins_c, rowcnt, vals_c)
             return
 
-        m = len(slots_c)
         npad = _bucket(m, floor=256)
+        perf.count("pane_update_pad_cells", npad - m)
         idx = np.zeros((2, npad), dtype=np.int32)
         idx[0, :m] = slots_c
         idx[1, :m] = bins_c
@@ -831,13 +912,10 @@ class KeyedBinState:
         packed[0, :m] = rowcnt
         packed[1:, :m] = vals_c
 
-        from ..obs.perf import timed_device
-
         kernel = _update_kernel(self._ch_kinds, self.C, self.B, npad,
                                 self._dup_ch)
         self.values, self.counts = timed_device(
-            kernel, self.values, self.counts, jnp.asarray(idx),
-            jnp.asarray(packed))
+            kernel, self.values, self.counts, *_h2d(idx, packed))
 
     def _channel_input(self, j: int, agg_inputs: Dict[str, np.ndarray],
                        n: int) -> np.ndarray:
@@ -878,6 +956,7 @@ class KeyedBinState:
             vals_c = full
         weights = np.concatenate([rowcnt[None], vals_c], axis=0)
         s, b, w = pad_batch(slots_c.astype(np.int32), bins_c, weights)
+        perf.count("pane_update_pad_cells", len(s) - len(slots_c))
         c_act = active_capacity(self.next_slot, self.C)
         self.values, self.counts = update_bin_state(
             self.values, self.counts, s, b, w, c_act, self.B)
@@ -887,6 +966,7 @@ class KeyedBinState:
         # buffered cell runs carry ring indices mod the OLD B — they must
         # land before the ring re-layout redefines the modulus
         self.flush_updates()
+        perf.count("state_grows")
         newB = self.B
         while newB < needed:
             newB <<= 1
@@ -960,15 +1040,12 @@ class KeyedBinState:
         channel block) for cells at their pane's count extremum — a
         ~1000x smaller readback (ties-per-pane instead of every
         (key, pane) cell)."""
-        from ..obs.perf import timed_device
-
-        ring_j = jnp.asarray(ring)
-        ok_j = jnp.asarray(bin_ok)
+        ring_j, ok_j = _h2d(ring, bin_ok)
         nk = _argmax_nnz_kernel(self.C, self.B, self.W, kpad,
                                 self._argmax_local)
         cnt_dev, sel_dev, nnz_dev = timed_device(
             nk, self.counts, ring_j, ok_j)
-        nnz = int(nnz_dev)  # the only blocking scalar readback
+        nnz = int(_readback(self, nnz_dev))  # waits for the whole scan
         if nnz == 0:
             return (np.zeros(0, np.int64), np.zeros(0, np.int64),
                     np.zeros(0, np.int64),
@@ -977,10 +1054,10 @@ class KeyedBinState:
         gk = _argmax_gather_kernel(self.C, self.B, self.W, kpad, npad)
         idx2_d, cnt_d = timed_device(gk, cnt_dev, sel_dev)
         _prefetch_host(idx2_d, cnt_d)
-        idx2 = np.asarray(idx2_d)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        idx2 = _readback(self, idx2_d)
         return (idx2[0, :nnz].astype(np.int64),
                 idx2[1, :nnz].astype(np.int64),
-                np.asarray(cnt_d)[:nnz],  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+                _readback(self, cnt_d)[:nnz],
                 np.zeros((len(self._xfer_ch), nnz)))
 
     def _use_compact_emit(self, c_slice: int, k: int) -> bool:
@@ -1015,13 +1092,10 @@ class KeyedBinState:
         """(key_idx, pane_idx, counts, channel values [n_xfer, m]) for the
         live cells only, compacted on device (row-major order — identical
         to the dense path's np.nonzero order)."""
-        from ..obs.perf import timed_device
-
-        ring_j = jnp.asarray(ring)
-        ok_j = jnp.asarray(bin_ok)
+        ring_j, ok_j = _h2d(ring, bin_ok)
         ck = _emit_count_kernel(self.C, self.B, self.W, kpad)
         cnt_dev, nnz_dev = timed_device(ck, self.counts, ring_j, ok_j)
-        nnz = int(nnz_dev)  # the only blocking readback: one scalar
+        nnz = int(_readback(self, nnz_dev))  # one scalar sizes phase 2
         if nnz == 0:
             return (np.zeros(0, np.int64), np.zeros(0, np.int64),
                     np.zeros(0, np.int64),
@@ -1032,10 +1106,10 @@ class KeyedBinState:
         idx2_d, cnt_d, ch_d = timed_device(gk, self.values, cnt_dev,
                                            ring_j, ok_j)
         _prefetch_host(idx2_d, cnt_d, ch_d)
-        idx2 = np.asarray(idx2_d)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        idx2 = _readback(self, idx2_d)
         return (idx2[0, :nnz].astype(np.int64),
                 idx2[1, :nnz].astype(np.int64),
-                np.asarray(cnt_d)[:nnz], np.asarray(ch_d)[:, :nnz])  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+                _readback(self, cnt_d)[:nnz], _readback(self, ch_d)[:, :nnz])
 
     def _ring_shards(self) -> int:
         nk = 1
@@ -1047,7 +1121,6 @@ class KeyedBinState:
         """Pane aggregates for the contiguous ``pane_ends`` range via the
         bin-sharded ring kernel (parallel/ring_panes.py): linearize the
         span once, then one trailing-W sweep per channel."""
-        from ..obs.perf import timed_device
         from ..parallel.ring_panes import _ring_step_2d
 
         nk = self._ring_shards()
@@ -1061,7 +1134,7 @@ class KeyedBinState:
         ring_idx = (abs_bins % self.B).astype(np.int32)
         lin = _linearize_kernel(self._ch_kinds, self.C, self.B, L)
         g, cg = timed_device(lin, self.values, self.counts,
-                             jnp.asarray(ring_idx), jnp.asarray(ok))
+                             *_h2d(ring_idx, ok))
         # dispatch every channel sweep, then materialize: the transfers
         # overlap instead of each paying its own sync.
         # Channel set matches _emit_kernel's ``keep`` (COUNT(*) channels
@@ -1076,11 +1149,11 @@ class KeyedBinState:
         cdev = timed_device(fn, jax.device_put(cg.astype(jnp.float64),
                                                sharding))[:, -k:]
         _prefetch_host(*devs, cdev)
-        outs = [np.asarray(d) for d in devs]  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        outs = [_readback(self, d) for d in devs]
         # match the plane dtype: a promoted i64 plane can hold pane sums
         # beyond i32 (the sweep itself is exact in f64 to 2^53)
         cnt_np = (np.int64 if self.counts.dtype == jnp.int64 else np.int32)
-        cnts = np.asarray(cdev).astype(cnt_np)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        cnts = _readback(self, cdev).astype(cnt_np)
         return (np.stack(outs) if outs else
                 np.zeros((0, self.C, k))), cnts
 
@@ -1125,8 +1198,6 @@ class KeyedBinState:
         lo = self.min_bin if self.min_bin is not None else 0
         bin_ok[:k] = (abs_bins >= lo) & (abs_bins <= self.max_bin)
 
-        from ..obs.perf import timed_device
-
         # transfer only the occupied key rows, not all C slots.  2048-row
         # granularity: finer than pow2 buckets (pow2 wastes up to 50% of
         # the transfer) while bounding the compile-variant count;
@@ -1163,14 +1234,16 @@ class KeyedBinState:
                 ev = np.zeros(epad, dtype=bool)
                 ev[:len(expired)] = True
                 ek = _evict_kernel(self._ch_kinds, self.C, self.B)
-                self.values, self.counts = ek(self.values, self.counts,
-                                              jnp.asarray(ring), jnp.asarray(ev))
+                self.values, self.counts = timed_device(
+                    ek, self.values, self.counts, *_h2d(ring, ev),
+                    in_total=False)
             self.min_bin = new_min
             # evicted bins leave the u16 proof, keeping it live on
             # long-running streams (the bound would otherwise only grow)
             self._bin_bound = {b: v for b, v in self._bin_bound.items()
                                if b >= new_min}
 
+        _fire_done(self, int(watermark))
         # flatten (key, pane) pairs with data
         if compact is not None:
             key_idx, pane_idx, cnt_sel, ch_sel = compact
@@ -1200,20 +1273,17 @@ class KeyedBinState:
         fire in an 8-pane kernel grid would ship 37% dead bytes), then
         overlap the round-trips.  ONE home for fire_panes and
         drain_deltas so a transfer/slicing fix cannot diverge."""
-        from ..obs.perf import timed_device
-
         c_slice = self._c_slice()
         kernel = _emit_kernel(self._ch_kinds, self.C, self.B, W, kpad,
                               self._xfer_ch, cnt16)
         outs, cnts = timed_device(kernel, self.values, self.counts,
-                                  jnp.asarray(ring), jnp.asarray(bin_ok))
+                                  *_h2d(ring, bin_ok))
         outs_d = outs[:, :c_slice, :k]  # [n_xfer, c_slice, k]
         cnts_d = cnts[:c_slice, :k]  # [c_slice, k]
         _prefetch_host(outs_d, cnts_d)
-        outs = np.asarray(outs_d)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
-        cnts = np.asarray(cnts_d)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
-        return outs, cnts
+        return _readback(self, outs_d), _readback(self, cnts_d)
 
+    @_in_phase("fire_flatten")
     def _flatten_dense(self, outs: np.ndarray, cnts: np.ndarray, k: int
                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                   np.ndarray]:
@@ -1226,6 +1296,7 @@ class KeyedBinState:
         ch_sel = outs[:, :C_used, :k][:, key_idx, pane_idx]
         return key_idx, pane_idx, cnt_sel, ch_sel
 
+    @_in_phase("fire_flatten")
     def _out_cols(self, cnt_sel: np.ndarray, ch_sel: np.ndarray
                   ) -> Dict[str, np.ndarray]:
         """Visible aggregate columns from flattened fired cells (shared
@@ -1285,6 +1356,7 @@ class KeyedBinState:
         bin_ok[:k, 0] = (pane_ends >= lo) & (pane_ends <= self.max_bin)
 
         outs, cnts = self._read_dense(ring, bin_ok, kpad, k, 1, False)
+        _fire_done(self, None)
 
         # reset the drained bins to identity; bookkeeping stays put
         drained = pane_ends[bin_ok[:k, 0]]
@@ -1295,9 +1367,9 @@ class KeyedBinState:
             ev = np.zeros(epad, dtype=bool)
             ev[:len(drained)] = True
             ek = _evict_kernel(self._ch_kinds, self.C, self.B)
-            self.values, self.counts = ek(self.values, self.counts,
-                                          jnp.asarray(rslots),
-                                          jnp.asarray(ev))
+            self.values, self.counts = timed_device(
+                ek, self.values, self.counts, *_h2d(rslots, ev),
+                in_total=False)
             # drained cells are 0 again: their bounds restart from zero
             for b in drained.tolist():
                 self._bin_bound.pop(int(b), None)

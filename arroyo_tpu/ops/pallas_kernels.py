@@ -40,6 +40,8 @@ import numpy as np
 
 from jax.experimental import pallas as pl
 
+from ..obs.perf import kernel_name
+
 LANES = 128  # TPU lane width
 CHUNK = 1024  # batch rows per grid step
 TILE_C = 512  # key slots per grid tile
@@ -115,6 +117,7 @@ def _scatter_multi(k2: int, B: int, C_act: int, n_chunks: int,
     n = n_chunks * CHUNK
 
     @jax.jit
+    @kernel_name("pallas_scatter_multi")
     def run(slots, bins, weights):
         # packed[i, g*B + b] = (bin_i == b) * w_g,i ; every entry is
         # bf16-representable because the hi/lo split happened on host

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..graph.logical import AggKind, AggSpec
+from ..obs.perf import kernel_name
 from .expr import bucket_size
 
 # f64 extremes: the aggregation channels are float64 (numeric-fidelity
@@ -32,6 +33,7 @@ def _segment_agg_kernel(n_padded: int, n_segments: int, agg_kinds: Tuple[str, ..
     per-segment aggregates [k, n_segments] + counts [n_segments]."""
 
     @jax.jit
+    @kernel_name("segment_agg")
     def run(values: jnp.ndarray, segment_ids: jnp.ndarray, valid: jnp.ndarray):
         # invalid rows go to a trash segment
         sid = jnp.where(valid, segment_ids, n_segments)

@@ -35,6 +35,7 @@ from typing import Tuple
 
 import numpy as np
 
+from ..obs.perf import kernel_name
 from .expr import bucket_size
 
 _I64_MIN = np.iinfo(np.int64).min
@@ -83,6 +84,7 @@ def _union_kernel(npad: int):
     steps = max(npad - 1, 1).bit_length()
 
     @jax.jit
+    @kernel_name("session_union")
     def run(kh: "jnp.ndarray", st: "jnp.ndarray", en: "jnp.ndarray",
             valid: "jnp.ndarray"):
         newkey = jnp.ones(npad, dtype=bool)

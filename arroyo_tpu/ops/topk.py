@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.perf import kernel_name
 from .expr import bucket_size
 
 _PAD_SEG = np.int32(2**31 - 1)  # padding rows sort after all segments
@@ -24,6 +25,7 @@ _PAD_SEG = np.int32(2**31 - 1)  # padding rows sort after all segments
 @functools.lru_cache(maxsize=128)
 def _topk_kernel(n_pad: int, k: int):
     @jax.jit
+    @kernel_name("topk_topk")
     def run(seg, neg_val):
         # seg: i32[n_pad] (padding = _PAD_SEG); neg_val: f64[n_pad]
         idx = jnp.arange(n_pad, dtype=jnp.int32)

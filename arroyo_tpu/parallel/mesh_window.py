@@ -43,12 +43,16 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..graph.logical import AggKind, AggSpec
+from ..obs.perf import kernel_name
 from ..ops.keyed_bins import (
     NEG_INF,
     POS_INF,
     KeyedBinState,
     _bucket,
+    _fire_done,
     _init_value,
+    _prefetch_host,
+    _readback,
     build_channels,
     channel_inits,
     channel_input,
@@ -297,6 +301,7 @@ def _fire_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int, W: int):
     # feeds panes up to B-1+W-1, which must be emittable on final flush
     PANES = B + W - 1
 
+    @kernel_name("mesh_fire_step")
     def run(keys, bins, counts, lims):
         first_rel, wm_rel = lims[0], lims[1]
         pane = jnp.arange(PANES, dtype=jnp.int32)
@@ -345,6 +350,7 @@ def _reset_span_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int):
 
     inits = tuple(float(_init_value(AggKind(k))) for k in ch_kinds)
 
+    @kernel_name("mesh_reset_span_step")
     def run(bins, counts, lims):
         idx = jnp.arange(B, dtype=jnp.int32)
         m = (idx >= lims[0]) & (idx <= lims[1])
@@ -373,6 +379,7 @@ def _roll_step(ch_kinds: Tuple[str, ...], nk: int, C: int, B: int):
 
     inits = tuple(float(_init_value(AggKind(k))) for k in ch_kinds)
 
+    @kernel_name("mesh_roll_step")
     def run(bins, counts, shift):
         idx = jnp.arange(B, dtype=jnp.int32) + shift
         ok = idx < B
@@ -452,6 +459,9 @@ class MeshKeyedBinState:
         self.min_bin: Optional[int] = None
         self.max_bin: Optional[int] = None
         self.last_fired_pane: Optional[int] = None
+        # (start, duration) of the readbacks of the fire under way
+        # (keyed_bins._readback / _fire_done)
+        self._d2h: List[Tuple[float, float]] = []
         self.late_rows = 0
         # mirror of KeyedBinState.total_rows: bounds any cell/pane count
         # sum, driving i32 -> i64 plane promotion before a wrap is possible
@@ -778,7 +788,6 @@ class MeshKeyedBinState:
         half of :meth:`fire_panes` and :meth:`drain_deltas` (transfer
         only the fired range; prefetch so the readbacks overlap into
         ~one round-trip)."""
-        import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -796,17 +805,13 @@ class MeshKeyedBinState:
         outs, cnts, mask = timed_device(
             fire, d_keys, d_bins, d_counts,
             jnp.asarray([first_rel, wm_rel], jnp.int32))
-        from ..ops.keyed_bins import _prefetch_host
-
         k = wm_rel - first_rel + 1
         outs_d = outs[:, :, first_rel:first_rel + k]
         cnts_d = cnts[:, first_rel:first_rel + k]
         mask_d = mask[:, first_rel:first_rel + k]
         _prefetch_host(outs_d, cnts_d, mask_d, self.d_keys)
-        return (np.asarray(jax.device_get(outs_d)),
-                np.asarray(jax.device_get(cnts_d)),
-                np.asarray(jax.device_get(mask_d)),
-                np.asarray(jax.device_get(self.d_keys)))
+        return tuple(_readback(self, d)
+                     for d in (outs_d, cnts_d, mask_d, self.d_keys))
 
     def _flatten_fired(self, outs, cnts, mask, keys_h, base: int,
                        first_rel: int):
@@ -868,6 +873,7 @@ class MeshKeyedBinState:
             if self.min_bin is not None:
                 self.min_bin = max(self.min_bin, self.base_bin)
 
+        _fire_done(self, int(watermark))
         return self._flatten_fired(outs, cnts, mask, keys_h, base,
                                    first_rel)
 
@@ -899,6 +905,7 @@ class MeshKeyedBinState:
             self.d_bins, self.d_counts,
             jnp.asarray([first_rel, wm_rel], jnp.int32))
 
+        _fire_done(self, None)
         return self._flatten_fired(outs, cnts, mask, keys_h, base,
                                    first_rel)
 

@@ -479,7 +479,6 @@ def run_query(name: str, sql_template: str) -> dict:
         # module docstring; the reference's backend can't run here)
         result["vs_baseline"] = round(
             eps / ctl["control_events_per_sec"], 3)
-    result.update(device_share(name, sql_template))
     result.update(phase_profile(name, sql_template))
     result.update(sanitize_overhead(name, sql_template))
     return result
@@ -522,47 +521,6 @@ def sanitize_overhead(name: str, sql_template: str) -> dict:
         (dt_on - dt_off) / dt_off * 100.0, 2)}
 
 
-def device_share(name: str, sql_template: str) -> dict:
-    """Host/device wall-time split: re-run a slice of the stream with
-    per-kernel blocking timers (ARROYO_TIMING serializes dispatch, so this
-    runs separately from the throughput measurement)."""
-    from arroyo_tpu.connectors.memory import clear_sink
-    from arroyo_tpu.engine.engine import LocalRunner
-    from arroyo_tpu.obs import perf
-    from arroyo_tpu.sql import plan_sql
-
-    n = min(NUM_EVENTS, 500_000)
-    prog = plan_sql(sql_template.format(n=n, b=BATCH),
-                    parallelism=bench_parallelism())
-    # warm run of the SAME program first (the jit cache is keyed by the
-    # program's expression fns, so the timed run never counts compiles)
-    clear_sink("results")
-    LocalRunner(prog).run()
-    os.environ["ARROYO_TIMING"] = "1"
-    try:
-        perf.reset()
-        clear_sink("results")
-        t0 = time.perf_counter()
-        LocalRunner(prog).run()
-        dt = time.perf_counter() - t0
-    finally:
-        os.environ.pop("ARROYO_TIMING", None)
-    dev = perf.counter_ns("device_ns") / 1e9
-    # device_ns sums per-operator timed_device spans; concurrent
-    # operators (q8's two parallel aggregates) can overlap, so the share
-    # may exceed 1 — report the raw ratio and mark overlap instead of
-    # fabricating a negative host share.
-    # host_time_share_DERIVED: the old wall-minus-device residual, kept
-    # for continuity with BENCH_r0* history — the MEASURED
-    # host_time_share now comes from phase_profile()'s phase sum
-    share = round(dev / dt, 3)
-    out = {"device_time_share": share,
-           "host_time_share_derived": round(max(1 - dev / dt, 0.0), 3)}
-    if share > 1:
-        out["device_time_overlapped"] = True
-    return out
-
-
 def phase_profile(name: str, sql_template: str) -> dict:
     """Measured per-phase host-time table (obs/profiler.py): re-run a
     slice of the stream with the phase profiler armed and record where
@@ -574,7 +532,7 @@ def phase_profile(name: str, sql_template: str) -> dict:
     engine evolves).  ``host_time_share`` is now this measured phase
     sum over wall time (clamped to 1; executor-offloaded source
     generation overlaps the event loop, so the raw ``attributed_share``
-    may exceed 1 and is reported alongside, like device_time_share).
+    may exceed 1 and is reported alongside).
     Profiler overhead is measured as armed-vs-off wall time on the same
     slice.  BENCH_PHASES=0 skips."""
     if os.environ.get("BENCH_PHASES", "1") in ("0", "false", "no"):
@@ -633,8 +591,7 @@ def phase_profile(name: str, sql_template: str) -> dict:
         out["ingest_rows_per_s"] = round(n / decode_secs, 1)
     if attributed > dt_on:
         out["phases_overlapped"] = True  # executor-side source decode
-        # runs concurrently with the loop — same caveat as
-        # device_time_overlapped
+        # runs concurrently with the loop
     return out
 
 

@@ -340,7 +340,7 @@ def test_checkpoint_metrics_and_spans(tmp_path):
 def test_kernel_time_attributed_per_operator():
     """timed_device dispatch time lands in the active task's
     arroyo_worker_kernel_seconds_total counter (the always-cheap
-    per-operator accumulator generalizing ARROYO_TIMING)."""
+    per-operator accumulator)."""
     from arroyo_tpu.obs import perf
 
     ti = TaskInfo("kacc-job", "op-k", "kernels", 0, 1)
@@ -356,6 +356,127 @@ def test_kernel_time_attributed_per_operator():
         "arroyo_worker_kernel_seconds").items()
         if "kacc-job" in k and "_total" in k}
     assert any(v > 0 for v in vals.values()), vals
+
+
+# every jitted kernel factory of ops/keyed_bins.py, with arguments for a
+# tiny state, and the stable name its kernel carries into XLA's module
+# line (jit_<name>), the trace ring and the named dispatch counters
+BIN_KERNELS = {
+    "_update_kernel": ((("count",), 64, 8, 256), "bins_update"),
+    "_emit_kernel": ((("count",), 64, 8, 5, 1), "bins_emit"),
+    "_argmax_nnz_kernel": ((64, 8, 5, 1, "max"), "bins_argmax_nnz"),
+    "_argmax_gather_kernel": ((64, 8, 5, 1, 8), "bins_argmax_gather"),
+    "_emit_count_kernel": ((64, 8, 5, 1), "bins_emit_count"),
+    "_emit_compact_kernel": ((("count",), 64, 8, 5, 1, (), 256),
+                             "bins_emit_compact"),
+    "_linearize_kernel": ((("count",), 64, 8, 8), "bins_linearize"),
+    "_evict_kernel": ((("count",), 64, 8), "bins_evict"),
+}
+
+
+@pytest.mark.parametrize("factory", sorted(BIN_KERNELS))
+def test_bin_kernels_carry_stable_names(factory):
+    import ast
+    import inspect
+
+    from arroyo_tpu.ops import keyed_bins
+
+    args, name = BIN_KERNELS[factory]
+    kernel = getattr(keyed_bins, factory)(*args)
+    assert kernel.__name__ == name and kernel.__qualname__ == name
+    names = [n for _, n in BIN_KERNELS.values()]
+    assert len(set(names)) == len(names)
+    # the table above misses no factory: every @jax.jit of the module
+    jitted = [
+        f.name for f in ast.parse(inspect.getsource(keyed_bins)).body
+        if isinstance(f, ast.FunctionDef) and any(
+            isinstance(g, ast.FunctionDef) and g.decorator_list
+            and ast.unparse(g.decorator_list[0]) == "jax.jit"
+            for g in ast.walk(f))]
+    assert sorted(jitted) == sorted(BIN_KERNELS)
+
+
+def test_named_dispatch_counters_and_kernel_spans():
+    """A dispatch through timed_device bumps kernel_dispatches.<name>
+    beside the total, and its trace-ring span is named for the kernel."""
+    import numpy as np
+
+    from arroyo_tpu.graph.logical import AggKind, AggSpec
+    from arroyo_tpu.obs import perf, tracing
+    from arroyo_tpu.ops.keyed_bins import KeyedBinState
+
+    ti = TaskInfo("knames-job", "op-n", "kernels", 0, 1)
+    token = perf.set_active_task(perf.KernelAccumulator(ti, None))
+    perf.reset()
+    tracing.reset()
+    try:
+        st = KeyedBinState((AggSpec(AggKind.COUNT, None, "n"),),
+                           1_000_000, 2_000_000, capacity=64)
+        kh = np.arange(1, 41, dtype=np.uint64)
+        st.update(kh, np.arange(40, dtype=np.int64) * 100_000, {})
+        fired = st.fire_panes(3_000_000)
+    finally:
+        perf.reset_active_task(token)
+    assert fired is not None
+    assert perf.counter("kernel_dispatches.bins_update") == 1
+    assert perf.counter("kernel_dispatches.bins_emit") == 1
+    assert perf.counter("kernel_dispatches.bins_evict") == 1
+    # the evict was a direct call before the kernels were named: it counts
+    # under its name, and the unnamed total keeps its old meaning
+    assert perf.counter("kernel_dispatches") == 2
+    assert perf.counter("pane_update_cells") == 40
+    assert perf.counter("pane_update_pad_cells") == 256 - 40
+    assert perf.counter("d2h_syncs") == 2 and perf.counter("d2h_bytes") > 0
+    names = {s[0] for s in tracing.spans("kernel")}
+    assert names and names <= {"bins_update", "bins_emit", "bins_evict"}
+
+
+def _fire_accounts(state, drain):
+    """Counters and spans one pass over ``state`` leaves: 30 keys in the
+    first second, then a watermark fire or a checkpoint drain."""
+    import numpy as np
+
+    from arroyo_tpu.obs import perf, tracing
+
+    kh = np.arange(1, 31, dtype=np.uint64)
+    state.update(kh, np.arange(30, dtype=np.int64) * 10_000,
+                 {"v": np.ones(30)})
+    perf.reset()
+    tracing.reset()
+    fired = state.drain_deltas() if drain else state.fire_panes(2_000_000)
+    assert fired is not None and state._d2h == []
+    return ({k: perf.counter(k) for k in (
+        "window_fires", "pane_drains", "d2h_syncs", "d2h_bytes")},
+        [s for s in tracing.spans("window") if s[0] == "window.fire.d2h"])
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["single", "mesh"])
+@pytest.mark.parametrize("drain", [False, True], ids=["fire", "drain"])
+def test_fire_and_drain_accounts_on_both_states(mesh, drain):
+    """Both bin states keep the same accounts of a pass that reads panes
+    back: a watermark fire counts ``window_fires`` and leaves one
+    ``window.fire.d2h`` span with its watermark, a checkpoint drain counts
+    ``pane_drains`` and no span; the readbacks are counted either way."""
+    from arroyo_tpu.graph.logical import AggKind, AggSpec
+    from arroyo_tpu.ops.keyed_bins import KeyedBinState
+    from arroyo_tpu.parallel.mesh_window import MeshKeyedBinState
+
+    aggs = (AggSpec(AggKind.SUM, "v", "total"),)
+    if mesh:
+        state = MeshKeyedBinState(aggs, 1_000_000, 1_000_000, capacity=64,
+                                  n_shards=2)
+    else:
+        state = KeyedBinState(aggs, 1_000_000, 1_000_000, capacity=64)
+    counts, spans = _fire_accounts(state, drain)
+    assert counts["window_fires"] == (0 if drain else 1)
+    assert counts["pane_drains"] == (1 if drain else 0)
+    assert counts["d2h_syncs"] == (4 if mesh else 2)
+    assert counts["d2h_bytes"] > 0
+    if drain:
+        assert spans == []
+    else:
+        (span,) = spans
+        assert span[3] > 0 and span[6] == {"watermark": 2_000_000}
 
 
 def test_controller_job_rollup_aggregates_heartbeat_snapshots():

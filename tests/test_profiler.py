@@ -340,3 +340,189 @@ def test_debug_profile_capture_is_bounded(run_async, monkeypatch):
     import tempfile
 
     run_async(scenario(tempfile.mkdtemp(prefix="prof-cap-")))
+
+
+# -- spans beneath the calls: the update and the fire across the executor hop --
+
+Q5_SQL = """
+CREATE TABLE nexmark WITH (
+  connector = 'nexmark', event_rate = '20000', num_events = '240000',
+  rate_limited = 'false', batch_size = '8192',
+  base_time_micros = '1700000000000000', seed = '7'
+);
+WITH bids as (SELECT bid.auction as auction, bid.datetime as datetime
+    FROM nexmark where bid is not null)
+SELECT AuctionBids.auction as auction, AuctionBids.num as num
+FROM (
+  SELECT B1.auction, HOP(INTERVAL '2' SECOND, INTERVAL '10' SECOND)
+         as window, count(*) AS num
+  FROM bids B1 GROUP BY 1, 2
+) AS AuctionBids
+JOIN (
+  SELECT max(num) AS maxn, window
+  FROM (
+    SELECT count(*) AS num,
+           HOP(INTERVAL '2' SECOND, INTERVAL '10' SECOND) AS window
+    FROM bids B2 GROUP BY B2.auction, 2
+  ) AS CountBids
+  GROUP BY 2
+) AS MaxBids
+ON AuctionBids.num = MaxBids.maxn and AuctionBids.window = MaxBids.window
+"""
+ADMIT_SLEEP_S = 0.01
+
+
+@pytest.fixture(scope="module")
+def q5_offloaded():
+    """One q5-shaped job (the benchmark's query at a tiny size) on the
+    single-device bin state, its update and fire forced through the
+    executor hop as on an accelerator, with a sleep planted in the
+    executor's half of the update; read by the tests below."""
+    from arroyo_tpu import config
+    from arroyo_tpu.connectors.memory import clear_sink, sink_output
+    from arroyo_tpu.engine.engine import LocalRunner
+    from arroyo_tpu.engine.operators_window import BinAggOperator
+    from arroyo_tpu.obs import perf, tracing
+    from arroyo_tpu.ops.keyed_bins import KeyedBinState
+    from arroyo_tpu.sql import plan_sql
+
+    admit = KeyedBinState._admit_bins
+
+    def slow_admit(self, timestamps):
+        time.sleep(ADMIT_SLEEP_S)
+        return admit(self, timestamps)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("ARROYO_MESH", "off")
+        mp.setenv("STATE_CAPACITY", "256")  # the keys outgrow it: _grow
+        config.reset_config()
+        mp.setattr(BinAggOperator, "_offload_transfers", lambda self: True)
+        mp.setattr(KeyedBinState, "_admit_bins", slow_admit)
+        prog = plan_sql(Q5_SQL)
+        prof = profiler.arm("q5-offloaded")
+        prof.reset()
+        perf.reset()
+        tracing.reset()
+        clear_sink("results")
+        t0 = time.perf_counter()
+        LocalRunner(prog).run()
+        wall = time.perf_counter() - t0
+        run = {"wall": wall, "work": prof.work_snapshot(),
+               "waits": prof.wait_snapshot(),
+               "threads": prof.thread_work_snapshot(),
+               "spans": tracing.spans(),
+               "counter": dict(perf._COUNTERS),
+               "rows": sum(len(b) for b in sink_output("results"))}
+        profiler.disarm()
+    config.reset_config()
+    assert run["rows"] > 0
+    return run
+
+
+def _by_phase(table):
+    out = {}
+    for (_op, phase), secs in table.items():
+        out[phase] = out.get(phase, 0.0) + secs
+    return out
+
+
+def test_offload_wait_keeps_proc_exclusive(q5_offloaded):
+    """The await of ``run_offloaded`` is a wait child: ``proc`` is not
+    charged what the executor thread does (its ``preagg`` holds the
+    planted sleeps), and no thread's work phases exceed its wall."""
+    work, waits = _by_phase(q5_offloaded["work"]), _by_phase(
+        q5_offloaded["waits"])
+    batches = 240000 // 8192
+    assert work["preagg"] >= 0.9 * batches * ADMIT_SLEEP_S, work
+    assert waits["offload_wait"] >= work["preagg"], (waits, work)
+    agg_proc = sum(secs for (op, ph), secs in q5_offloaded["work"].items()
+                   if ph == "proc"
+                   and (op, "preagg") in q5_offloaded["work"])
+    assert 0 < agg_proc < 0.5 * work["preagg"], (agg_proc, work)
+    assert work["watermark"] < waits["offload_wait"], (work, waits)
+    threads = q5_offloaded["threads"]
+    assert "MainThread" in threads and len(threads) >= 2, threads
+    for name, secs in threads.items():
+        assert secs <= q5_offloaded["wall"], (name, secs, threads)
+
+
+@pytest.mark.parametrize("phase", ["dir_insert", "preagg", "h2d", "dispatch",
+                                   "d2h_wait", "fire_flatten", "emit"])
+def test_q5_records_work_phase(q5_offloaded, phase):
+    assert _by_phase(q5_offloaded["work"]).get(phase, 0.0) > 0.0
+    assert phase in profiler.WORK_PHASES
+
+
+@pytest.mark.parametrize("counter", [
+    "pane_update_cells", "pane_update_pad_cells", "keys_inserted",
+    "state_grows", "window_fires", "d2h_syncs", "d2h_bytes",
+    "wait_us.send_wait", "wait_us.offload_wait",
+    "kernel_dispatches.bins_update", "kernel_dispatches.bins_argmax_nnz",
+    "kernel_dispatches.bins_argmax_gather", "kernel_dispatches.bins_evict"])
+def test_q5_counts(q5_offloaded, counter):
+    c = q5_offloaded["counter"]
+    assert c.get(counter, 0) > 0, sorted(c)
+    assert c["pane_update_cells"] <= c["pane_update_rows"]
+    # q5 fires through _emit_argmax: the nnz scalar, then two readbacks
+    assert c["d2h_syncs"] == 3 * c["window_fires"]
+    named = sum(v for k, v in c.items()
+                if k.startswith("kernel_dispatches."))
+    assert named == c["kernel_dispatches"] + c[
+        "kernel_dispatches.bins_evict"]
+    # one source per wait: only the waits a counter reader takes are
+    # mirrored; the others stay in the profiler's per-operator table
+    assert "wait_us.queue_wait" not in c and "pane_drains" not in c
+    assert _by_phase(q5_offloaded["waits"])["queue_wait"] > 0
+
+
+def test_fire_child_spans_lie_inside_their_fire(q5_offloaded):
+    """``window.fire.d2h`` and ``window.fire.emit`` share their
+    ``window.fire``'s watermark and lie inside it; dispatch spans carry
+    the kernel's name."""
+    by_name = {}
+    for name, _cat, start, dur, _pid, _tid, args in q5_offloaded["spans"]:
+        by_name.setdefault(name, []).append((start, dur, args))
+    fires = {a["watermark"]: (s, d) for s, d, a in by_name["window.fire"]}
+    for child in ("window.fire.d2h", "window.fire.emit"):
+        assert len(by_name[child]) == q5_offloaded["counter"]["window_fires"]
+        for start, dur, args in by_name[child]:
+            f_start, f_dur = fires[args["watermark"]]
+            assert dur > 0
+            assert f_start <= start and start + dur <= f_start + f_dur + 1
+    kernels = {n for n, cat, *_ in q5_offloaded["spans"] if cat == "kernel"}
+    assert "kernel" not in kernels, kernels
+    assert any(k.startswith("bins_") for k in kernels), kernels
+
+
+def test_work_frames_are_mirrored_as_trace_annotations(monkeypatch):
+    """Every work frame enters a TraceAnnotation(phase, op=...) and leaves
+    it; a wait frame is not mirrored and closes the open work annotations
+    for as long as it lasts."""
+    log = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            self.name = name
+            log.append(("new", name, kw))
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    prof = profiler.arm("mirror")
+    monkeypatch.setattr(prof, "_annotation", Annotation)
+    outer = prof.begin("op", "proc")
+    inner = prof.begin("op", "dir_insert")
+    prof.end(inner)
+    wait = prof.begin("op", "offload_wait", wait=True)
+    prof.end(wait)
+    prof.end(outer)
+    assert log == [
+        ("new", "proc", {"op": "op"}), ("enter", "proc"),
+        ("new", "dir_insert", {"op": "op"}), ("enter", "dir_insert"),
+        ("exit", "dir_insert"),
+        ("exit", "proc"),  # the wait begins: the thread leaves `proc`
+        ("new", "proc", {"op": "op"}), ("enter", "proc"),  # and is back
+        ("exit", "proc")], log

@@ -120,8 +120,10 @@ def stop_trace():
 
 async def drive(cell, seed, seconds, trace_dir=None):
     """Returns a dict: ``batches`` (the sink's), ``tick_map``, ``window``
-    (ticks.Window), what the Observer read at the origin and close ticks,
-    ``state_bytes`` per tick, and in a traced run ``host_samples``."""
+    (ticks.Window), what the Observer read at the origin and close ticks and
+    once the stop has drained the queues,
+    ``state_bytes`` per tick, and in a traced run ``host_samples`` and the
+    counters at the two marks of the traced stretch."""
     from arroyo_tpu.connectors.memory import (clear_sink, sink_arrivals,
                                               sink_output)
     from arroyo_tpu.engine.engine import Engine
@@ -158,6 +160,12 @@ async def drive(cell, seed, seconds, trace_dir=None):
         fresh, seen = ends[-1] if n > seen else None, n
         return fresh
 
+    def mark_stop():
+        nonlocal marked
+        mark_trace_stop()
+        log["counters_trace_stop"] = watch.counters()
+        marked = True
+
     try:
         while not joined.done():
             fresh = sweep()
@@ -173,11 +181,11 @@ async def drive(cell, seed, seconds, trace_dir=None):
                         sampler = HostSampler(threading.get_ident())
                         sampler.start()
                         start_trace(trace_dir)
+                        log["counters_trace_start"] = watch.counters()
                         tracing_on = True
                 elif phase == "window":
                     if tracing_on and not marked and fresh >= trace_stop_end:
-                        mark_trace_stop()
-                        marked = True
+                        mark_stop()
                     if ticks.close_of(ticks.ticks(ends, ats), origin_end,
                                       seconds) is not None:
                         phase = "closing"
@@ -186,7 +194,7 @@ async def drive(cell, seed, seconds, trace_dir=None):
                         if sampler is not None:
                             sampler.stop()
                         if tracing_on and not marked:
-                            mark_trace_stop()  # a window under the stretch
+                            mark_stop()  # a window under the stretch
                         await running.stop(StopMode.GRACEFUL)
                         stop_sent_at = time.monotonic()
                         if tracing_on:
@@ -203,6 +211,8 @@ async def drive(cell, seed, seconds, trace_dir=None):
             await asyncio.sleep(POLL_S)
         await joined  # raises what a task of the job raised
         sweep()
+        log["counters_drained"] = watch.counters()
+        log["state_bytes_drained"] = watch.state_bytes()
     finally:
         if sampler is not None:
             sampler.stop()
@@ -222,4 +232,5 @@ async def drive(cell, seed, seconds, trace_dir=None):
     log["window"] = ticks.measure(log["tick_map"], origin_end, seconds,
                                   stream["event_rate"])
     log["drained_s"] = time.monotonic() - stop_sent_at
+    log["started_at"], log["first_arrival_at"] = started, ats[0]
     return log

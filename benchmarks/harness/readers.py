@@ -6,8 +6,10 @@ import statistics
 
 
 def counter_names(spec):
-    """The program counters a metric's reader wants snapshotted at both
+    """The program counters a metric's reader wants snapshotted at the
     ticks ('@...' names are the harness's own quantities)."""
+    if spec["reader"] == "module_roofline":
+        return list(spec["work"])
     if spec["reader"] != "counter_ratio":
         return []
     return [n for n in spec["num"] + spec["den"] if not n.startswith("@")]
@@ -53,7 +55,7 @@ def span_per_period(spec, obs):
     sums = []
     for _, at, _, secs in periods:
         lo = at - secs
-        sums.append(sum(dur for end, dur in obs["spans"][spec["span"]]
+        sums.append(sum(dur for end, dur in obs["spans"].get(spec["span"], ())
                         if lo < end <= at) * 1e3)
     if not any(sums):
         return None
@@ -78,6 +80,27 @@ def trace_field(spec, obs):
     return t[spec["field"]]
 
 
+def module_roofline(spec, obs):
+    """A kernel's share of one peak of the chip, in percent: the least
+    seconds its work could take (``work``: program counters, each with the
+    bytes or operations one unit of it needs whatever implements it, over
+    the ``peak`` of ``peaks.json``) over the device seconds of the XLA module
+    ``module``.  Work and seconds are both of the traced stretch: the
+    counters' deltas between the two trace marks."""
+    t, peaks = obs["trace"], obs["peaks"]
+    start, stop = (obs.get(k) for k in ("counters_trace_start",
+                                        "counters_trace_stop"))
+    if (None in (t, peaks, start, stop)
+            or spec["module"] not in t["modules"]):
+        return None
+    seconds = t["modules"][spec["module"]][0]
+    work = sum(per_unit * (stop[n] - start[n])
+               for n, per_unit in spec["work"].items())
+    if work <= 0 or seconds <= 0:
+        return None
+    return 100.0 * work / (peaks[spec["peak"]] * t["devices"]) / seconds
+
+
 def compile_listener(spec, obs):
     """XLA compile seconds (or requests) between the ticks."""
     count, secs, _ = obs["compiles"]
@@ -86,7 +109,7 @@ def compile_listener(spec, obs):
 
 READERS = {f.__name__: f for f in (counter_ratio, phase_per_mev,
                                    span_per_period, memory_stat, trace_field,
-                                   compile_listener)}
+                                   module_roofline, compile_listener)}
 
 
 def read(spec, obs):

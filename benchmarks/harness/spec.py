@@ -1,8 +1,12 @@
 """Everything a cell is made of, found by the names in ``BENCHMARK.json``:
 ``configs/<configuration>.json``, ``traffic/<mix>.json``,
-``metrics/<metric>.json``, ``queries/*.sql`` and ``references/<name>.py``.
-A later PR adds a cell, a mix, a configuration or a metric over an existing
-reader by adding files and entries; no file that is there needs an edit."""
+``metrics/<metric>.json`` and, named by the configuration's file,
+``queries/*.sql`` and ``references/<name>.py``.  A configuration also brings
+``selfcheck/rehearsal/<configuration>.json``, the sizes at which the
+yardstick's own tools (``rehearse.py``, ``selfcheck``, the tests) run its
+cells on the CPU.  A later PR adds a cell, a mix, a configuration or a metric
+over an existing reader by adding files and entries; no file that is there
+needs an edit (``tests/test_second_config.py`` holds the harness to it)."""
 
 import importlib
 import json
@@ -74,9 +78,21 @@ def manifest():
     return _json(ROOT, "BENCHMARK.json")
 
 
-def load_cell(name, overrides=None):
-    """``overrides`` (rehearsals and tests only) is merged over the
-    configuration and, under its ``traffic`` key, over the mix."""
+def rehearsal_sizes(config_name):
+    """The configuration's ``selfcheck/rehearsal/<name>.json``: sizes merged
+    over the configuration and, under their ``traffic`` key, over the mix."""
+    path = os.path.join(BENCH_DIR, "selfcheck", "rehearsal",
+                        config_name + ".json")
+    if not os.path.exists(path):
+        raise FileNotFoundError(
+            f"configuration {config_name!r} brings no rehearsal sizes: "
+            f"{path} is missing")
+    return _json(path)["sizes"]
+
+
+def load_cell(name, rehearsal=False):
+    """``rehearsal`` (the yardstick's own tools and tests only) shrinks the
+    cell to its configuration's rehearsal sizes; never a chip result."""
     bench = manifest()
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -84,7 +100,7 @@ def load_cell(name, overrides=None):
                        f"{sorted(cells)}")
     w = cells[name]
     files = {c["name"]: c["file"] for c in bench["configs"]}
-    overrides = dict(overrides or {})
+    overrides = dict(rehearsal_sizes(w["config"]) if rehearsal else {})
     traffic = _merged(_json(BENCH_DIR, "traffic", w["traffic"] + ".json"),
                       overrides.pop("traffic", {}))
     config = _merged(_json(ROOT, files[w["config"]]), overrides)

@@ -1,8 +1,9 @@
 """Rehearsal on the CPU, at a tiny size: the self-checks of the tick
-arithmetic and the trace reducer, the contract's limits on
-``BENCHMARK.json``, the controls, and one run of every cell through the
-harness with the sizes of ``selfcheck/rehearsal.json``.  It debugs the
-benchmark's own files; nothing it prints is a chip result.
+arithmetic, the trace reducer and the roofline reader, the contract's limits
+on ``BENCHMARK.json``, the controls, and one run of every cell through the
+harness at the sizes of its configuration's
+``selfcheck/rehearsal/<configuration>.json``.  It debugs the benchmark's own
+files; nothing it prints is a chip result.
 
     JAX_PLATFORMS=cpu python3 benchmarks/rehearse.py [--seconds 3] [--trace 1]
 
@@ -38,20 +39,18 @@ def main(argv):
     check.manifest()
     check.ticks()
     check.trace_reducer()
+    check.roofline()
     check.controls()
     check.generator()
-    with open(os.path.join(BENCH_DIR, "selfcheck", "rehearsal.json")) as f:
-        sizes = json.load(f)
     for w in spec.manifest()["workloads"]:
         if args.workload and w["name"] not in args.workload:
             continue
+        cell = spec.load_cell(w["name"], rehearsal=not args.chip)
         if args.chip:
-            cell = spec.load_cell(w["name"])
             device = run.device_report(cell.chips)
             if device is None:
                 return run.EXIT_NO_CHIP
         else:
-            cell = spec.load_cell(w["name"], sizes[w["config"]])
             device = {"platform": "rehearsal-not-a-chip", "kind": "cpu",
                       "count": 1}
         result = run.run_cell(cell, args.seed, args.seconds,

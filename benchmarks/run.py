@@ -41,13 +41,17 @@ def log(msg):
     print(msg, file=sys.stderr, flush=True)
 
 
+def peaks_table():
+    with open(os.path.join(BENCH_DIR, "harness", "peaks.json")) as f:
+        return json.load(f)
+
+
 def device_report(chips):
     """The devices as JAX reports them; None unless they are exactly the
     TPU chips the cell asks for, of a kind the peaks table knows."""
     import jax
 
-    with open(os.path.join(BENCH_DIR, "harness", "peaks.json")) as f:
-        peaks = json.load(f)
+    peaks = peaks_table()
     devs = jax.devices()
     dev = {"platform": devs[0].platform, "kind": devs[0].device_kind,
            "count": len(devs)}
@@ -62,6 +66,7 @@ def device_report(chips):
 
 M_TRIM_THRESHOLD, M_MMAP_THRESHOLD = -1, -3  # glibc's <malloc.h>
 MMAP_THRESHOLD_BYTES = 32 << 20  # DEFAULT_MMAP_THRESHOLD_MAX on 64 bits
+TRIM_THRESHOLD_BYTES = 1 << 30  # over any heap top a run frees: no trim
 
 
 def pin_allocator():
@@ -71,14 +76,18 @@ def pin_allocator():
     directory's whole-array temporaries come from the heap or from fresh
     pages: a run that loaded every kernel from the cache stops at 3 x C
     bytes, one that compiled anything at 6 x C, and they differ by half in
-    ``events_per_s`` (PERF.md section 6).  Both thresholds are set here to
+    ``events_per_s`` (PERF.md section 6).  The mmap threshold is set here to
     the end of glibc's own adjustment, where it stays for good, so that
-    every run of every cell measures the same allocator."""
+    every run of every cell measures the same allocator.  The trim threshold
+    is set over anything a run frees: at glibc's 64 MiB the heap's top was
+    given back and faulted in again in every batch once three directory-long
+    arrays passed it, from the 13th to the 19th period of a run and at 0.5 to
+    1.1 s a period, and the check read that as a spread of 6 to 9 %."""
     import ctypes
 
     libc = ctypes.CDLL(None)
     for knob, value in ((M_MMAP_THRESHOLD, MMAP_THRESHOLD_BYTES),
-                        (M_TRIM_THRESHOLD, 2 * MMAP_THRESHOLD_BYTES)):
+                        (M_TRIM_THRESHOLD, TRIM_THRESHOLD_BYTES)):
         if libc.mallopt(knob, value) != 1:
             raise OSError(f"mallopt({knob}, {value}) was refused: the runs "
                           "of this benchmark would fall into two modes")
@@ -123,6 +132,23 @@ def _period_table(window, run, clock):
         prev_at, prev_end = at, end
 
 
+def _module_table(trace, run):
+    """The traced stretch by XLA module, and the counters of work that the
+    program made between the same two marks."""
+    log(f"traced stretch: busy_s={trace['busy_s']:.6f} "
+        f"window_s={trace['window_s']:.6f} modules_s="
+        f"{sum(s for s, _ in trace['modules'].values()):.6f}")
+    log("module  device_s  dispatches  s_per_dispatch")
+    for name, (secs, n) in sorted(trace["modules"].items(),
+                                  key=lambda kv: -kv[1][0]):
+        log(f"{name}  {secs:.6f}  {n:g}  {secs / n:.6f}")
+    start, stop = (run.get(k) for k in ("counters_trace_start",
+                                        "counters_trace_stop"))
+    if start is not None and stop is not None:
+        log("counters between the trace marks: "
+            + " ".join(f"{k}={stop[k] - start[k]}" for k in sorted(stop)))
+
+
 def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
     """Drive the cell once and return the result object.  ``device`` is
     ``device_report``'s; the tests hand in a made-up one to run on the CPU."""
@@ -145,6 +171,7 @@ def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
         f"device={device} compile_cache_dir={cache_dir} "
         f"state_capacity={cell.config['state_capacity']}")
 
+    t_drive = time.monotonic()
     run = asyncio.run(drive(cell, seed, seconds, trace_dir))
     window = run["window"]
     memory = memory_stats()
@@ -156,6 +183,11 @@ def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
     log(f"setup_s={setup_s:.3f} compiles_in_setup={in_setup[0]} "
         f"compile_s_in_setup={in_setup[1]:.3f} cache_requests="
         f"{clock.requests} cache_hits={clock.hits}")
+    log(f"setup by stage: start_and_imports_s={t_drive - t_process:.3f} "
+        f"plan_and_engine_s={run['started_at'] - t_drive:.3f} "
+        f"to_first_sink_batch_s="
+        f"{run['first_arrival_at'] - run['started_at']:.3f} "
+        f"to_origin_tick_s={window.origin_at - run['first_arrival_at']:.3f}")
     log(f"window: events={window.events} seconds={window.seconds:.4f} "
         f"overshoot_s={window.seconds - seconds:.4f} "
         f"fires_in_window={len(window.periods)} "
@@ -165,7 +197,11 @@ def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
     _period_table(window, run, clock)
     first, last = (run["state_bytes"].get(e, {})
                    for e in (window.origin_end, window.close_end))
-    log(f"state_bytes at origin tick {first} at close tick {last}")
+    log(f"state_bytes at origin tick {first} at close tick {last} "
+        f"after the drain {run['state_bytes_drained']}")
+    log("counters at the close tick and after the drain: " + " ".join(
+        f"{k}={run['counters_close'][k]}/{v}"
+        for k, v in sorted(run["counters_drained"].items())))
 
     # the reference runs only now: the peak is read and the state is freed
     got = compare.sink_rows(run.pop("batches"), cell.config["result_columns"],
@@ -195,13 +231,16 @@ def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
                     f"correct={compare.verdict(broken)}")
 
     if trace:
-        obs = dict(run, memory=memory, compiles=in_window, trace=None)
+        obs = dict(run, memory=memory, compiles=in_window, trace=None,
+                   peaks=peaks_table().get(device["kind"]))
         files = glob.glob(os.path.join(
             trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
         if files:
             obs["trace"] = trace_reduce.reduce_file(files[0],
                                                     run["host_samples"])
         shutil.rmtree(trace_dir, ignore_errors=True)
+        if obs["trace"] is not None:
+            _module_table(obs["trace"], run)
         metrics = {}
         for entry, reader in cell.per_layer:
             value = readers.read(reader, obs)
@@ -224,6 +263,7 @@ def run_cell(cell, seed, seconds, trace, t_process, device, control=False):
                       window_s=obs["trace"]["window_s"])
         result["breakdown"] = {k: obs["trace"][k]
                                for k in ("device_ops", "idle_gaps")}
+        result["modules"] = obs["trace"]["modules"]
     result["compared"] = {k: {"value": numbers[k], "limit": limit}
                           for k, limit in compare.LIMITS.items()}
     for line in compare.lines(numbers):
@@ -239,9 +279,13 @@ def main(argv):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args(argv)
     cell = spec.load_cell(args.workload)
+    t_main = time.monotonic()
     device = device_report(cell.chips)
     if device is None:
         return EXIT_NO_CHIP
+    log(f"start by stage: interpreter_and_harness_imports_s="
+        f"{t_main - T_PROCESS:.3f} jax_import_and_devices_s="
+        f"{time.monotonic() - t_main:.3f}")
     result = run_cell(cell, args.seed, args.seconds, bool(args.trace),
                       T_PROCESS, device)
     print(json.dumps(result), flush=True)

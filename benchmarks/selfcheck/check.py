@@ -1,14 +1,15 @@
 """Self-checks of the yardstick's own arithmetic, run by ``rehearse.py`` and
 by the tests beside it (never by the repo's tier-1 tests): the contract's
 limits on ``BENCHMARK.json``, the tick arithmetic on a synthetic arrival log,
-the trace reducer on a small trace recorded on the chip, the controls, and
-the yardstick's generator copy against the program's source."""
+the trace reducer and the roofline reader on a small trace recorded on the
+chip, the controls, and the yardstick's generator copy against the program's
+source."""
 
 import json
 import os
 import re
 
-from harness import compare, spec, ticks as tk, trace_reduce
+from harness import compare, readers, spec, ticks as tk, trace_reduce
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -112,6 +113,35 @@ def trace_reducer():
     assert len(got["device_ops"]) >= 1
     for key in ("busy_s", "window_s"):
         assert abs(got[key] - want["reduced"][key]) < 1e-9, (key, got[key])
+    # by module: the modules' seconds are their operations' (three ops, so
+    # the top ten hold them all), and every operation is named by its module
+    by_module = sum(s for s, _ in got["modules"].values())
+    by_op = sum(s for _, s in got["device_ops"])
+    assert abs(by_module - by_op) <= 0.01 * by_op, (by_module, by_op)
+    assert all(n >= 1 for _, n in got["modules"].values()), got["modules"]
+    for name, _ in got["device_ops"]:
+        assert name.split("/")[0] in got["modules"], name
+
+
+def roofline():
+    """``module_roofline`` over the sample trace: work that needs half of the
+    module's device seconds at the peak reads 50 %; a module that never ran,
+    a device without peaks, or counters never read at the marks, nothing."""
+    trace = trace_reduce.reduce_file(os.path.join(HERE, "sample.xplane.pb"))
+    seconds = trace["modules"]["jit__lambda"][0]
+    metric = {"reader": "module_roofline", "module": "jit__lambda",
+              "peak": "bytes_per_s", "work": {"cells": 8, "rows": 2}}
+    assert readers.counter_names(metric) == ["cells", "rows"]
+    obs = {"trace": trace, "peaks": {"bytes_per_s": 10 / seconds},
+           "counters_trace_start": {"cells": 7, "rows": 1},
+           "counters_trace_stop": {"cells": 7.5, "rows": 1.5}}
+    assert abs(readers.read(metric, obs) - 50.0) < 1e-9
+    for broken in (dict(obs, peaks=None), dict(obs, trace=None),
+                   dict(obs, counters_trace_stop=obs["counters_trace_start"]),
+                   {k: v for k, v in obs.items()
+                    if k != "counters_trace_stop"}):
+        assert readers.read(metric, broken) is None, broken
+    assert readers.read(dict(metric, module="jit_never_ran"), obs) is None
 
 
 def generator():
@@ -144,9 +174,8 @@ def controls():
     """A reference that breaks exactly-once as the configuration's
     ``controls`` say (a batch delivered twice; half a batch left out) has to
     come out as not correct; the reference itself as correct."""
-    sizes = _load("rehearsal.json")
     for w in spec.manifest()["workloads"]:
-        cell = spec.load_cell(w["name"], sizes[w["config"]])
+        cell = spec.load_cell(w["name"], rehearsal=True)
         base = cell.config["stream"]["base_time_micros"]
         for seed in (0, 1, 2_147_483_999):
             t_end = base + 40_000_000

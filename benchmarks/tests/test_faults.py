@@ -2,8 +2,6 @@
 look for a chip), at the rehearsal's size on the CPU: ``correct`` has to come
 out false for every fault a cell can have, and true for the sound program."""
 
-import json
-import os
 import time
 
 import numpy as np
@@ -16,7 +14,7 @@ FAULTS = ["sound", "answer_altered", "half_of_each_batch_left_out",
           "state_left_unchanged"]
 
 
-def _plant(fault, monkeypatch):
+def _plant(fault, monkeypatch, cell):
     if fault == "answer_altered":
         from arroyo_tpu.connectors.memory import MemorySink
 
@@ -26,7 +24,7 @@ def _plant(fault, monkeypatch):
         async def altered(self, batch, ctx, side=0):
             seen.append(len(batch))
             if len(seen) == 3 and len(batch):  # one row of the third batch
-                col = next(c for c in batch.columns if c.startswith("n"))
+                col = cell.config["result_columns"][-1]
                 batch.columns[col] = np.array(batch.columns[col])
                 batch.columns[col][0] += 1
             await sound(self, batch, ctx, side)
@@ -61,14 +59,11 @@ def _plant(fault, monkeypatch):
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-@pytest.mark.parametrize("workload", ["nexmark_q5.catchup"])
+@pytest.mark.parametrize("workload", [w["name"] for w in
+                                      spec.manifest()["workloads"]])
 def test_fault_is_not_correct(workload, fault, monkeypatch):
-    with open(os.path.join(spec.BENCH_DIR, "selfcheck",
-                           "rehearsal.json")) as f:
-        sizes = json.load(f)
-    config = workload.split(".")[0]
-    cell = spec.load_cell(workload, sizes[config])
-    _plant(fault, monkeypatch)
+    cell = spec.load_cell(workload, rehearsal=True)
+    _plant(fault, monkeypatch, cell)
     device = {"platform": "test-not-a-chip", "kind": "cpu", "count": 1}
     result = run.run_cell(cell, 2_147_483_900, 1.0, False, time.monotonic(),
                           device)

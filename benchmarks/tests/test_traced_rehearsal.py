@@ -3,8 +3,6 @@ per-layer metric that reads the program's own spans, phases and counters has
 to come out with a value (the device's and the compiler's have nothing that
 must be there without a chip)."""
 
-import json
-import os
 import time
 
 import pytest
@@ -16,29 +14,40 @@ from selfcheck import check
 PROGRAM_READERS = {"counter_ratio", "phase_per_mev", "span_per_period"}
 
 
+CELLS = [w["name"] for w in spec.manifest()["workloads"]]
+
+
 @pytest.fixture(scope="module")
 def traced():
-    with open(os.path.join(spec.BENCH_DIR, "selfcheck",
-                           "rehearsal.json")) as f:
-        sizes = json.load(f)
-    cell = spec.load_cell("nexmark_q5.catchup", sizes["nexmark_q5"])
-    device = {"platform": "test-not-a-chip", "kind": "cpu", "count": 1}
-    return run.run_cell(cell, 2_147_483_901, 2.0, True, time.monotonic(),
-                        device)
+    """One traced run per cell, made when its first metric asks for it."""
+    runs = {}
+
+    def of(workload):
+        if workload not in runs:
+            cell = spec.load_cell(workload, rehearsal=True)
+            device = {"platform": "test-not-a-chip", "kind": "cpu",
+                      "count": 1}
+            runs[workload] = run.run_cell(cell, 2_147_483_901, 2.0, True,
+                                          time.monotonic(), device)
+        return runs[workload]
+
+    return of
 
 
 def test_manifest_holds_with_the_longer_list():
     check.manifest()
 
 
-@pytest.mark.parametrize("metric", [
-    entry["name"]
-    for entry, reader in spec.load_cell("nexmark_q5.catchup").per_layer
+@pytest.mark.parametrize("workload,metric", [
+    (workload, entry["name"])
+    for workload in CELLS
+    for entry, reader in spec.load_cell(workload).per_layer
     if reader["reader"] in PROGRAM_READERS])
-def test_traced_run_reports_metric(traced, metric):
-    assert traced["correct"], traced["compared"]
-    got = traced["metrics"].get(metric)
-    assert got is not None, sorted(traced["metrics"])
+def test_traced_run_reports_metric(traced, workload, metric):
+    result = traced(workload)
+    assert result["correct"], result["compared"]
+    got = result["metrics"].get(metric)
+    assert got is not None, sorted(result["metrics"])
     assert got["value"] >= 0
     # no growth is the cell's design; the executor hop is an accelerator's
     if metric not in ("state_grows_in_window", "offload_wait_s_per_mev"):

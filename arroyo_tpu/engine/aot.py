@@ -18,7 +18,13 @@ happens per batch-size bucket at runtime — so the AOT stage's jobs become:
    restarts reuse compilations instead of re-tracing (the analog of the
    compiler service's warm build_dir + artifact re-use via the program
    graph hash, compiler.rs:57-90).
-3. **Export jittable steps** (`serialize_step`/`deserialize_step`): a
+3. **Warm the fire** (`KeyedBinState.warm_fire`, which a window operator
+   calls when it starts): the device kernels of a window's fire whose
+   shapes follow from the plan and the configured state capacity alone
+   (the scan and the compacted pick at each of its row buckets) are
+   compiled then, not inside whichever fire first needs them: a fire's
+   compile is a stall of seconds to a minute in the middle of a stream.
+4. **Export jittable steps** (`serialize_step`/`deserialize_step`): a
    traced step (e.g. the mesh window update) serializes to portable
    StableHLO bytes via ``jax.export`` and can be stored to the artifact
    store and re-loaded without the Python closure — the closest analog of

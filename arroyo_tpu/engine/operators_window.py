@@ -246,6 +246,8 @@ class BinAggOperator(Operator):
             TableDescriptor("a", TableType.DEVICE, "bin aggregates",
                             retention_micros=self.width),
             DeviceTable(snap, restore))
+        if hasattr(self.state, "warm_fire"):  # not the mesh state's
+            self.state.warm_fire()
 
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
         assert batch.key_hash is not None, f"{self.name} requires keyed input"
@@ -1106,7 +1108,21 @@ class WindowJoinOperator(Operator):
 
     async def handle_timer(self, time: int, key: Any, payload: Any,
                            ctx: Context) -> None:
+        from ..obs import tracing
+
         end = key[1]
+        # flight-recorder tap, the join's like of `window.fire`: probe,
+        # gather, assembly and the hand-over downstream
+        with tracing.span("join.fire", "window", tid=tracing.ctx_tid(ctx),
+                          args={"window_end": int(end)}):
+            await self._fire(end, ctx)
+        evict_to = end - self.width + self.slide
+        self.left.evict_before(evict_to)
+        self.right.evict_before(evict_to)
+
+    async def _fire(self, end: int, ctx: Context) -> None:
+        from ..obs import tracing
+
         start = end - self.width
         how = self.join_type
         if self._partitioned:
@@ -1136,9 +1152,23 @@ class WindowJoinOperator(Operator):
                 r_un = (self.right.gather(ru)
                         if how in (JoinType.RIGHT, JoinType.FULL)
                         else None)
+                tid, args = tracing.ctx_tid(ctx), {"window_end": int(end)}
+                # the device gathers' blocking readbacks as ONE span, as
+                # `window.fire.d2h` is: from the first one's start, as
+                # long as all of them together
+                d2h = sorted(self.left.take_readbacks()
+                             + self.right.take_readbacks())
+                if d2h:
+                    tracing.record_span(
+                        "join.fire.d2h", "window", d2h[0][0],
+                        sum(dur for _, dur in d2h), tid=tid, args=args)
+                t0 = tracing.now_us()
                 out = _assemble_join_output(
                     l_rows, r_rows, l_un, r_un, end, how, key_cols,
                     tmpl=(self._tmpl[0], self._tmpl[1]))
+                tracing.record_span("join.fire.emit", "window", t0,
+                                    tracing.now_us() - t0, tid=tid,
+                                    args=args)
                 if len(out):
                     out.lat_stamp = _lat_consume(self._lat_pending)
                     self._lat_pending = None
@@ -1162,9 +1192,6 @@ class WindowJoinOperator(Operator):
                     out.lat_stamp = _lat_consume(self._lat_pending)
                     self._lat_pending = None
                     await ctx.collect(out)
-        evict_to = end - self.width + self.slide
-        self.left.evict_before(evict_to)
-        self.right.evict_before(evict_to)
 
 
 class WindowArgmaxOperator(Operator):

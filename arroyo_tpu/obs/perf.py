@@ -14,6 +14,7 @@ name :func:`kernel_name` gave it.
 from __future__ import annotations
 
 import contextvars
+import functools
 import time
 from contextvars import ContextVar
 from typing import Any, Dict, Optional
@@ -95,6 +96,23 @@ def begin_phase(phase: str):
 def end_phase(token) -> None:
     if token is not None:
         token[0].end(token[1])
+
+
+def in_phase(phase: str):
+    """Decorator: the call is profiler work phase ``phase`` of the active
+    operator (one test per call while the profiler is off)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def phased(*args, **kwargs):
+            tok = begin_phase(phase)
+            try:
+                return fn(*args, **kwargs)
+            finally:
+                end_phase(tok)
+
+        return phased
+
+    return wrap
 
 
 async def run_offloaded(loop, fn, *args):

@@ -52,6 +52,16 @@ phase               measured where
                     or host fancy-index, whichever path ran; the
                     device/host row split rides the
                     ``join_*_gather_rows`` counters)
+``join_append``     ``PartitionedJoinBuffer.append``: routing a keyed batch
+                    to its partitions, the sort of each delta and the
+                    positional merge into the host sorted run (exclusive
+                    of ``join_merge``)
+``join_merge``      the device half of an append: packing a delta and the
+                    ``join_merge32`` scatter-merge dispatch of one hot
+                    partition's ring (staging a new ring included)
+``join_probe``      a window join's fire up to the matched positions:
+                    ``range_join``'s mask-compress and merge-probe of
+                    every partition's two sorted runs
 ==================  =========================================================
 
 plus overlapping **wait** phases (reported separately, never summed into
@@ -131,7 +141,8 @@ WORK_PHASES = ("source_decode", "proc", "dir_insert", "preagg", "h2d",
                "dispatch", "d2h_wait", "fire_flatten", "emit",
                "shuffle_prep", "coalesce_merge", "watermark", "checkpoint",
                "emit_encode", "frame_encode", "frame_decode", "reshard",
-               "shuffle_collective", "gather", "session_merge")
+               "shuffle_collective", "gather", "session_merge",
+               "join_append", "join_merge", "join_probe")
 WAIT_PHASES = ("queue_wait", "coalesce_wait", "send_wait", "net_flush",
                "offload_wait")
 

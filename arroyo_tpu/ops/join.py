@@ -32,6 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..obs import perf, tracing
 from ..obs.perf import kernel_name, timed_device
 
 # padding key: sorts after every real hash; a real key colliding with it
@@ -338,13 +339,22 @@ def _pack_stacks(plan, nf, ni, width, n, cols, ts):
     return fv, iv
 
 
+def ring_cap(n: int) -> int:
+    """Rows a ring that holds ``n`` is allocated for: the one bucket
+    function every ring shape goes through."""
+    return _bucket(max(n, 1))
+
+
 def stage_ring(sorted_keys: np.ndarray, device: Any = None,
                sorted_ts: Optional[np.ndarray] = None,
                sorted_cols: Optional["dict[str, np.ndarray]"] = None
                ) -> Optional[SplitRing]:
-    """Upload a sorted key run (plus payload columns when given, all in
+    """Put a sorted key run (plus payload columns when given, all in
     the same sorted-run order) into a fresh power-of-two sentinel-padded
-    device ring.  ``device`` pins the ring to one mesh device
+    device ring: empty planes of ``ring_cap(n)`` rows, then the run
+    merged in as one delta by the same ``join_merge32`` dispatch that
+    every later append takes, so a ring's first window compiles what its
+    later windows run.  ``device`` pins the ring to one mesh device
     (state/join_state.py spreads hot partitions over the ``("keys",)``
     mesh via ``parallel.shuffle.partition_device`` so q7/q8-style joins
     stop funneling every ring through chip 0); None keeps the default
@@ -354,22 +364,22 @@ def stage_ring(sorted_keys: np.ndarray, device: Any = None,
     if not ring_stageable(sorted_keys):
         return None
     n = len(sorted_keys)
-    cap = _bucket(max(n, 1))
-    hi = np.full(cap, SENT32_HI, np.int32)
-    lo = np.full(cap, SENT32_LO, np.int32)
-    hi[:n] = split_hi32(sorted_keys)
-    lo[:n] = split_lo32(sorted_keys)
+    cap = ring_cap(n)
     plan = (payload_plan({c: v.dtype for c, v in sorted_cols.items()})
             if sorted_cols is not None else None)
     fstack = istack = None
     nf = ni = 0
     if plan is not None:
         nf, ni = _plan_dims(plan)
-        fv, iv = _pack_stacks(plan, nf, ni, cap, n, sorted_cols, sorted_ts)
-        fstack = jax.device_put(fv, device)
-        istack = jax.device_put(iv, device)
-    return SplitRing(jax.device_put(hi, device), jax.device_put(lo, device),
-                     cap, fstack, istack, plan, nf, ni, device)
+        fstack = jax.device_put(np.zeros((nf, cap), np.float64), device)
+        istack = jax.device_put(np.zeros((ni, cap), np.int64), device)
+    empty = SplitRing(
+        jax.device_put(np.full(cap, SENT32_HI, np.int32), device),
+        jax.device_put(np.full(cap, SENT32_LO, np.int32), device),
+        cap, fstack, istack, plan, nf, ni, device)
+    return merge_ring(empty, np.zeros(0, np.int64), sorted_keys,
+                      np.arange(n, dtype=np.int64), delta_ts=sorted_ts,
+                      delta_cols=sorted_cols if plan is not None else None)
 
 
 @functools.lru_cache(maxsize=64)
@@ -550,12 +560,15 @@ def _gather32_kernel(cap: int, m: int, nf: int, ni: int):
     return run
 
 
-def gather_ring(ring: SplitRing, spos: np.ndarray
+def gather_ring(ring: SplitRing, spos: np.ndarray,
+                readbacks: Optional[list] = None
                 ) -> Tuple[np.ndarray, np.ndarray]:
     """Fire-path payload gather: materialize payload stacks for the
     given sorted-run positions (already exact — window fires match on
     the host mirror's full keys) in one dispatch.  Returns
-    (f_rows, i_rows) sliced to ``len(spos)``."""
+    (f_rows, i_rows) sliced to ``len(spos)``; the blocking readback's
+    (start, duration) in tracing microseconds is appended to
+    ``readbacks`` for the caller's ``join.fire.d2h`` span."""
     n = len(spos)
     mb = _bucket(max(n, 1))
     idx = np.zeros(mb, np.int64)
@@ -564,8 +577,12 @@ def gather_ring(ring: SplitRing, spos: np.ndarray
         _gather32_kernel(ring.cap, mb, ring.nf, ring.ni), idx,
         ring.fstack if ring.nf else np.zeros((0, ring.cap), np.float64),
         ring.istack)
-    return (np.asarray(gf_d)[:, :n],  # arroyolint: disable=host-sync -- intentional join-emission readback: gathered payload rows become the output batch
-            np.asarray(gi_d)[:, :n])  # arroyolint: disable=host-sync -- intentional join-emission readback: gathered payload rows become the output batch
+    t0 = tracing.now_us()
+    gf = np.asarray(gf_d)  # arroyolint: disable=host-sync -- intentional join-emission readback: gathered payload rows become the output batch
+    gi = np.asarray(gi_d)  # arroyolint: disable=host-sync -- intentional join-emission readback: gathered payload rows become the output batch
+    if readbacks is not None:
+        readbacks.append((t0, tracing.now_us() - t0))
+    return gf[:, :n], gi[:, :n]
 
 
 def unpack_payload(ring: SplitRing, gf: np.ndarray, gi: np.ndarray
@@ -589,8 +606,6 @@ def join_pairs(lk: np.ndarray, rk: np.ndarray
     arrays: ``lo``/``ro`` sort each side, ``lidx``/``ridx`` index pairs
     into the sorted orders, ``counts`` is per-sorted-left-row match
     count (for outer-join unmatched masks)."""
-    from ..obs import perf
-
     perf.count("join_state_resorts")  # full re-sort of both sides (the
     # legacy path the partitioned sorted runs exist to avoid)
     nl, nr = len(lk), len(rk)

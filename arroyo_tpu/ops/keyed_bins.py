@@ -33,7 +33,7 @@ import numpy as np
 
 from ..graph.logical import AggKind, AggSpec
 from ..obs import perf, tracing
-from ..obs.perf import kernel_name, timed_device
+from ..obs.perf import in_phase as _in_phase, kernel_name, timed_device
 
 # f64 extremes (the accumulation channels are float64, see ACC_DTYPE):
 # f32 extremes here would clip MIN/MAX values beyond +/-3.4e38.  The
@@ -127,17 +127,14 @@ def _pane_reduce(kind: str, g, bin_ok):
 
 @functools.lru_cache(maxsize=256)
 def _emit_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int, k: int,
-                 keep: Optional[Tuple[int, ...]] = None,
-                 cnt16: bool = False):
+                 keep: Optional[Tuple[int, ...]] = None):
     """Compute per-key aggregates for k panes.  ``ring[k, W]`` (int32) and
     ``bin_ok[k, W]`` are computed on host from the absolute (int64) bin
     indices — keeping 64-bit bin arithmetic out of jit, where x64-disabled
     JAX would truncate it.  ``keep`` selects the channels that ride the
     device->host transfer (COUNT(*) channels are dropped — their pane
     output is exactly the counts plane, which transfers as integers
-    anyway).  ``cnt16`` downcasts the count grid to u16 for the transfer —
-    the caller proves pane sums fit (host-tracked bound), halving the
-    dominant readback."""
+    anyway)."""
     if keep is None:
         keep = tuple(range(len(kinds)))
 
@@ -148,8 +145,6 @@ def _emit_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int, k: int,
         with jax.named_scope("window_reduce_counts"):
             cnt_g = counts[:, ring]  # [C, k, W]
             cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)
-            if cnt16:
-                cnt = cnt.astype(jnp.uint16)
 
         outs = []
         for i in keep:
@@ -205,6 +200,23 @@ def _first_set_flags(flat, npad: int):
                             side="left")
 
 
+def _live_cells(flat):
+    """Indices of the set flags of the 1-D bool ``flat``, ascending, and
+    ``len(flat)`` in every place past their count:
+    ``jnp.nonzero(flat, size=len(flat), fill_value=len(flat))[0]`` element
+    for element, by ONE sort of int32 keys (a set flag's key is its index,
+    a clear one's ``len(flat)``).  Where a fire picks a handful
+    ``_first_set_flags`` is the cheaper; where it picks every live key its
+    ``npad`` binary searches are log2(len) dependent gathers each: over
+    2^21 flags the chip took 163 ms for 262,144 picks and 3.2 ms for this
+    sort, whatever the count (PERF.md section 6, PR 29, names the run).
+    The sort is the part that compiles slowly (15 s on the chip), so it
+    sits in the scan's kernel, compiled once, and the pick at each
+    readback size takes its first rows."""
+    n = flat.shape[0]
+    return jnp.sort(jnp.where(flat, jnp.arange(n, dtype=jnp.int32), n))
+
+
 @functools.lru_cache(maxsize=256)
 def _argmax_gather_kernel(C: int, B: int, W: int, k: int, npad: int):
     """Phase 2: gather ONLY the candidate cells' (key, pane, count).  The
@@ -232,16 +244,20 @@ def _argmax_gather_kernel(C: int, B: int, W: int, k: int, npad: int):
 
 @functools.lru_cache(maxsize=256)
 def _emit_count_kernel(C: int, B: int, W: int, k: int):
-    """Phase 1 of compacted emission: pane counts stay device-resident;
-    only the live-cell total crosses (4 bytes instead of the [C, k]
-    grid — the scalar sizes phase 2's static-shape compaction)."""
+    """Phase 1 of compacted emission: pane counts and the live cells'
+    positions (``_live_cells``, row-major) stay device-resident; only the
+    live-cell total crosses (4 bytes instead of the [C, k] grid — the
+    scalar sizes phase 2's static-shape compaction)."""
+    # the positions and their fill value C * k are int32
+    assert C * k < 2 ** 31, (C, k)
 
     @jax.jit
     @kernel_name("bins_emit_count")
     def run(counts, ring, bin_ok):
         cnt_g = counts[:, ring]  # [C, k, W]
         cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)  # [C, k]
-        return cnt, jnp.sum(cnt > 0)
+        flat = cnt.reshape(-1) > 0
+        return cnt, _live_cells(flat), jnp.sum(flat)
 
     return run
 
@@ -253,13 +269,16 @@ def _emit_compact_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int,
     is C*k cells of which a fire typically touches a few percent (keys
     active inside one window span vs every key ever seen) — compacting on
     device shrinks the readback by that ratio and replaces the
-    host-side np.nonzero scan."""
+    host-side np.nonzero scan.  The cells are the first ``npad`` of the
+    live positions that phase 1 left on the device (row-major order, as
+    ``nonzero`` gives them; ``C * k`` past the live count)."""
 
     @jax.jit
     @kernel_name("bins_emit_compact")
-    def run(values, cnt, ring, bin_ok):
+    def run(values, cnt, live, ring, bin_ok):
         flat = cnt.reshape(-1)  # [C * k]
-        idx = jnp.nonzero(flat > 0, size=npad, fill_value=C * k)[0]
+        idx = (live[:npad] if npad <= C * k else jnp.concatenate(
+            [live, jnp.full(npad - C * k, C * k, jnp.int32)]))
         ok = idx < C * k
         safe = jnp.where(ok, idx, 0)
         key_idx = (safe // k).astype(jnp.int32)
@@ -322,6 +341,13 @@ def _bucket(n: int, floor: int = 8) -> int:
     return b
 
 
+# Every shape a fire hands to ``jit`` is a function of the state's capacity
+# (C, B), of the window (W) or of a power-of-two row bucket, never of
+# ``next_slot``, of a density or of a row count as the data gives it: a
+# job whose keys never return would otherwise compile in every fire.
+_EMIT_ROWS_FLOOR = 1024  # smallest compacted readback, in rows
+
+
 def restored_count_state(raw_counts: np.ndarray, promote_at: int
                          ) -> Tuple[int, np.dtype]:
     """(total restored rows, counts-plane dtype) for a snapshot restore —
@@ -346,23 +372,6 @@ def _prefetch_host(*arrays) -> None:
                 start()
             except Exception:  # pragma: no cover - non-committed arrays
                 pass
-
-
-def _in_phase(phase: str):
-    """Decorator: the call is profiler work phase ``phase`` of the active
-    operator (one test per call while the profiler is off)."""
-    def wrap(fn):
-        @functools.wraps(fn)
-        def phased(*args, **kwargs):
-            tok = perf.begin_phase(phase)
-            try:
-                return fn(*args, **kwargs)
-            finally:
-                perf.end_phase(tok)
-
-        return phased
-
-    return wrap
 
 
 @_in_phase("h2d")
@@ -651,20 +660,10 @@ class KeyedBinState:
         # (and the COUNT(*) outputs read from it) cannot wrap — once it
         # could, update() promotes the plane to i64 (one recompile)
         self.total_rows = 0
-        # observed live-cell fraction of the last fire's pane grid (None
-        # until a fire happens); drives the compact-emission prediction
-        self._fire_density: Optional[float] = None
         # set via set_argmax_local: emission keeps only local per-pane
         # argmax candidates (planner-proven sole consumer settles the
         # global answer); only COUNT(*) values qualify (see planner)
         self._argmax_local: Optional[str] = None  # 'max' | 'min'
-        # per-ABSOLUTE-bin upper bound on any (key, bin) cell count (each
-        # touched bin accrues the batch's largest pre-aggregated cell;
-        # evicted bins drop out).  The max sliding-window sum over W bins
-        # bounds any pane sum, proving when the emit count grid can be
-        # read back as u16 instead of i32 — per-bin (vs one monotone
-        # scalar) keeps the proof live on long-running streams
-        self._bin_bound: Dict[int, int] = {}
         # update coalescing (ARROYO_UPDATE_COALESCE): per-batch
         # pre-aggregated cell runs buffer HERE and flush to the device in
         # one merged scatter when a reader needs the planes (pane fire,
@@ -751,7 +750,7 @@ class KeyedBinState:
         admitted = self._admit_bins(timestamps)
         if admitted is None:
             return
-        bins_mod, live, n_live, lo, hi = admitted
+        bins_mod, live, n_live, _lo, _hi = admitted
         self._note_mass(int(n_live))
 
         slots = self._lookup_or_insert(key_hash)
@@ -782,7 +781,7 @@ class KeyedBinState:
                     slots[idx], bins_mod[idx], vals[:, idx]
             slots_c, bins_c, rowcnt, vals_c = preaggregate(
                 slots, bins_mod, xfer_kinds, vals)
-        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c, lo, hi)
+        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c)
 
     def _admit_bins(self, timestamps: np.ndarray
                     ) -> Optional[Tuple[np.ndarray, np.ndarray, int,
@@ -827,19 +826,12 @@ class KeyedBinState:
             self.counts = self.counts.astype(jnp.int64)
 
     def _enqueue_cells(self, slots_c: np.ndarray, bins_c: np.ndarray,
-                       rowcnt: np.ndarray, vals_c: np.ndarray,
-                       lo: int, hi: int) -> None:
-        """Shared update tail: u16-proof bin bounds, then either buffer
-        the pre-aggregated cell run (update coalescing — one merged
-        scatter carries many batches; the planes are only read at pane
-        fires / snapshots, and every reader flushes) or dispatch now."""
+                       rowcnt: np.ndarray, vals_c: np.ndarray) -> None:
+        """Shared update tail: either buffer the pre-aggregated cell run
+        (update coalescing — one merged scatter carries many batches; the
+        planes are only read at pane fires / snapshots, and every reader
+        flushes) or dispatch now."""
         m = len(slots_c)
-        if m:
-            # coarse but sound: every bin this batch touched could have
-            # grown by at most the batch's largest cell mass
-            bmax = int(np.ceil(rowcnt.max()))
-            for b in range(lo, hi + 1):
-                self._bin_bound[b] = self._bin_bound.get(b, 0) + bmax
         if update_coalescing_enabled():
             self._pending.append((slots_c, bins_c, rowcnt, vals_c))
             self._pending_cells += m
@@ -862,7 +854,7 @@ class KeyedBinState:
         admitted = self._admit_bins(timestamps)
         if admitted is None:
             return
-        bins_mod, live, _n_live, lo, hi = admitted
+        bins_mod, live, _n_live, _lo, _hi = admitted
         w = coerce_float(agg_inputs[self._rows_col], ACC_DTYPE)
         w = np.where(np.isnan(w), 0.0, w)
         self._note_mass(int(np.ceil(w[live].sum())))
@@ -889,7 +881,7 @@ class KeyedBinState:
             slots, bins_mod, ext_kinds, np.concatenate([vals, w[None]]))
         rowcnt = red[-1]
         vals_c = red[:-1]
-        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c, lo, hi)
+        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c)
 
     @_in_phase("preagg")
     def flush_updates(self) -> None:
@@ -924,7 +916,7 @@ class KeyedBinState:
             self._update_pallas(slots_c, bins_c, rowcnt, vals_c)
             return
 
-        npad = _bucket(m, floor=256)
+        npad = _bucket(m, floor=self._update_rows_floor())
         perf.count("pane_update_pad_cells", npad - m)
         idx = np.zeros((2, npad), dtype=np.int32)
         idx[0, :m] = slots_c
@@ -937,6 +929,17 @@ class KeyedBinState:
                                 self._dup_ch)
         self.values, self.counts = timed_device(
             kernel, self.values, self.counts, *_h2d(idx, packed))
+
+    def _update_rows_floor(self) -> int:
+        """Least cells an update dispatch is padded to.  A coalesced flush
+        carries what gathered up to the flush bound, or, before a fire,
+        whatever is left under it: padded to the bound's bucket (capped by
+        the capacity, which small states stay under), the flushes of a
+        stream take two or three shapes, which ``warm_fire`` knows, and
+        not one per size of remainder."""
+        if not update_coalescing_enabled():
+            return 256
+        return min(_bucket(_flush_cell_bound()), max(self.C, 256))
 
     def _channel_input(self, j: int, agg_inputs: Dict[str, np.ndarray],
                        n: int) -> np.ndarray:
@@ -1025,23 +1028,6 @@ class KeyedBinState:
         w_min = int(os.environ.get("ARROYO_RING_MIN_W", 64))
         return self.W >= w_min and len(jax.devices()) > 1
 
-    def _pane_bound(self, first_pane: int, last_pane: int) -> int:
-        """Largest provable pane sum over the firing range: max sliding
-        W-sum of the per-bin cell bounds.  Sound by construction — every
-        pane's true count is at most the sum of its bins' bounds."""
-        W = self.W
-        span = last_pane - first_pane + 1
-        if span + W > 100_000:  # degenerate range: don't scan, stay i32
-            return 1 << 40
-        lo_b = first_pane - W + 1
-        n = last_pane - lo_b + 1
-        arr = np.fromiter((self._bin_bound.get(b, 0)
-                           for b in range(lo_b, last_pane + 1)),
-                          dtype=np.int64, count=n)
-        c = np.concatenate([[0], np.cumsum(arr)])
-        sums = c[W:] - c[:-W]  # sums[i] covers bins [first_pane+i-W+1, ..]
-        return int(sums.max()) if len(sums) else 0
-
     def set_argmax_local(self, agg_out: str, minmax: str) -> None:
         """Enable candidate-only emission for the given COUNT(*) agg
         (the value IS the counts plane — enforced here, not just by the
@@ -1053,6 +1039,55 @@ class KeyedBinState:
             f"aggregate of this state")
         assert minmax in ("max", "min"), minmax
         self._argmax_local = minmax
+
+    def _argmax_candidates(self) -> bool:
+        return self._argmax_local is not None and not self._xfer_ch
+
+    def warm_fire(self) -> int:
+        """Compile (or load from the persistent cache) the kernels of a
+        one-pane fire before the first event, from the plan and the
+        capacity alone: the flush of the buffered updates, the scan, and
+        the pick at every row bucket from ``_EMIT_ROWS_FLOOR`` up to C, so
+        that no fire meets a kernel it has not run, however many cells it
+        finds live.  Each is called once on the empty planes (no bin is in
+        range, so it reads nothing live).  Returns how many kernels it ran.  A later ``_grow``
+        changes C and compiles what it then needs, as the update does."""
+        ran = 0
+        if not self._use_pallas():
+            # the flush before a fire: at the floor and, for a flush at
+            # the bound, one bucket above (rowcount 0 marks padding: the
+            # planes come back as they went in)
+            for npad in (self._update_rows_floor(),
+                         2 * self._update_rows_floor()):
+                kernel = _update_kernel(self._ch_kinds, self.C, self.B, npad,
+                                        self._dup_ch)
+                self.values, self.counts = kernel(
+                    self.values, self.counts, *_h2d(
+                        np.zeros((2, npad), np.int32),
+                        np.zeros((len(self._xfer_ch) + 1, npad), ACC_DTYPE)))
+                ran += 1
+        if self._use_ring():
+            return ran  # the ring sweep's shapes follow the open span
+        ring_j, ok_j = _h2d(np.zeros((1, self.W), np.int32),
+                            np.zeros((1, self.W), bool))
+        if self._argmax_candidates():
+            nk = _argmax_nnz_kernel(self.C, self.B, self.W, 1,
+                                    self._argmax_local)
+            cnt_dev, sel_dev, _ = nk(self.counts, ring_j, ok_j)
+            _argmax_gather_kernel(self.C, self.B, self.W, 1, 8)(
+                cnt_dev, sel_dev)
+            return ran + 2
+        cnt_dev, live_dev, _ = _emit_count_kernel(
+            self.C, self.B, self.W, 1)(self.counts, ring_j, ok_j)
+        ran, npad = ran + 1, _EMIT_ROWS_FLOOR
+        while True:
+            _emit_compact_kernel(self._ch_kinds, self.C, self.B, self.W, 1,
+                                 self._xfer_ch, npad)(
+                self.values, cnt_dev, live_dev, ring_j, ok_j)
+            ran += 1
+            if npad >= self.C:
+                return ran
+            npad <<= 1
 
     def _emit_argmax(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int
                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
@@ -1067,6 +1102,7 @@ class KeyedBinState:
         cnt_dev, sel_dev, nnz_dev = timed_device(
             nk, self.counts, ring_j, ok_j)
         nnz = int(_readback(self, nnz_dev))  # waits for the whole scan
+        perf.count("pane_emit_cells", nnz)
         if nnz == 0:
             return (np.zeros(0, np.int64), np.zeros(0, np.int64),
                     np.zeros(0, np.int64),
@@ -1081,32 +1117,6 @@ class KeyedBinState:
                 _readback(self, cnt_d)[:nnz],
                 np.zeros((len(self._xfer_ch), nnz)))
 
-    def _use_compact_emit(self, c_slice: int, k: int) -> bool:
-        """Two-phase compacted emission: worth one extra (4-byte) scalar
-        round-trip only when fires are SPARSE (keys active inside one
-        window span vs every key ever seen).  ``auto`` predicts from the
-        last observed fire density — nexmark q5 measures density 1.0
-        (every auction bids in every window), where compaction is
-        strictly worse; long-window/churning-key shapes measure a few
-        percent, where it wins by that ratio."""
-        import os
-
-        mode = os.environ.get("ARROYO_EMIT_COMPACT", "auto")
-        if mode == "off":
-            return False
-        if mode == "on":
-            return True
-        if self._fire_density is None:
-            return False  # no evidence yet: dense is the safe default
-        itemsize = self.counts.dtype.itemsize
-        row_bytes = 8 + itemsize + 8 * len(self._xfer_ch)  # idx2+cnt+chans
-        compact_bytes = self._fire_density * self.next_slot * k * row_bytes
-        dense_bytes = (8 * len(self._xfer_ch) + itemsize) * c_slice * k
-        # margin stands in for the extra scalar round-trip + gather pass
-        margin = int(os.environ.get("ARROYO_EMIT_COMPACT_MARGIN",
-                                    256 * 1024))
-        return compact_bytes + margin < dense_bytes
-
     def _emit_compact(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int
                       ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
                                  np.ndarray]:
@@ -1115,22 +1125,26 @@ class KeyedBinState:
         to the dense path's np.nonzero order)."""
         ring_j, ok_j = _h2d(ring, bin_ok)
         ck = _emit_count_kernel(self.C, self.B, self.W, kpad)
-        cnt_dev, nnz_dev = timed_device(ck, self.counts, ring_j, ok_j)
+        cnt_dev, live_dev, nnz_dev = timed_device(ck, self.counts, ring_j,
+                                                   ok_j)
         nnz = int(_readback(self, nnz_dev))  # one scalar sizes phase 2
+        perf.count("pane_emit_cells", nnz)
         if nnz == 0:
             return (np.zeros(0, np.int64), np.zeros(0, np.int64),
                     np.zeros(0, np.int64),
                     np.zeros((len(self._xfer_ch), 0)))
-        npad = _bucket(nnz, floor=256)
         gk = _emit_compact_kernel(self._ch_kinds, self.C, self.B, self.W,
-                                  kpad, self._xfer_ch, npad)
+                                  kpad, self._xfer_ch,
+                                  _bucket(nnz, _EMIT_ROWS_FLOOR))
         idx2_d, cnt_d, ch_d = timed_device(gk, self.values, cnt_dev,
-                                           ring_j, ok_j)
+                                           live_dev, ring_j, ok_j)
         _prefetch_host(idx2_d, cnt_d, ch_d)
         idx2 = _readback(self, idx2_d)
         return (idx2[0, :nnz].astype(np.int64),
                 idx2[1, :nnz].astype(np.int64),
-                _readback(self, cnt_d)[:nnz], _readback(self, ch_d)[:, :nnz])
+                _readback(self, cnt_d)[:nnz],
+                (_readback(self, ch_d)[:, :nnz] if self._xfer_ch
+                 else np.zeros((0, nnz))))
 
     def _ring_shards(self) -> int:
         nk = 1
@@ -1219,29 +1233,22 @@ class KeyedBinState:
         lo = self.min_bin if self.min_bin is not None else 0
         bin_ok[:k] = (abs_bins >= lo) & (abs_bins <= self.max_bin)
 
-        # transfer only the occupied key rows, not all C slots.  2048-row
-        # granularity: finer than pow2 buckets (pow2 wastes up to 50% of
-        # the transfer) while bounding the compile-variant count;
-        # the persistent compile cache amortizes each variant to one compile
-        c_slice = self._c_slice()
+        # what the scan has to read whatever implements it: the bins of
+        # the occupied slots' firing panes
+        perf.count("pane_scan_cells", self.next_slot * k * self.W)
         compact = None
-        use_ring = self._use_ring()
-        if use_ring:
+        if self._use_ring():
             outs, cnts = self._emit_ring(pane_ends, k)
-        elif self._argmax_local is not None and not self._xfer_ch:
+        elif self._argmax_candidates():
             # candidate-only emission: every output column derives from
             # the counts plane (bare COUNT(*) aggs), so nothing else
             # needs to ride the transfer; with f64 channels present the
-            # normal paths run and the downstream argmax stage filters
+            # compacted path runs and the downstream argmax stage filters
             compact = self._emit_argmax(ring, bin_ok, kpad)
-        elif self._use_compact_emit(c_slice, k):
-            compact = self._emit_compact(ring, bin_ok, kpad)
         else:
-            # pane sums provably fit u16 -> halve the dominant transfer
-            cnt16 = (self.counts.dtype == jnp.int32
-                     and self._pane_bound(first_pane, last_pane) < 65_000)
-            outs, cnts = self._read_dense(ring, bin_ok, kpad, k, self.W,
-                                          cnt16)
+            # live cells only, compacted on the device: a readback sized
+            # by a bucket of the live count, at any density
+            compact = self._emit_compact(ring, bin_ok, kpad)
 
         self.last_fired_pane = last_pane
         # evict bins that no future pane needs: abs bins <= last_pane - W + 1
@@ -1259,10 +1266,6 @@ class KeyedBinState:
                     ek, self.values, self.counts, *_h2d(ring, ev),
                     in_total=False)
             self.min_bin = new_min
-            # evicted bins leave the u16 proof, keeping it live on
-            # long-running streams (the bound would otherwise only grow)
-            self._bin_bound = {b: v for b, v in self._bin_bound.items()
-                               if b >= new_min}
 
         _fire_done(self, int(watermark))
         # flatten (key, pane) pairs with data
@@ -1271,7 +1274,6 @@ class KeyedBinState:
         else:
             key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
                 outs, cnts, k)
-        self._fire_density = len(key_idx) / max(self.next_slot * k, 1)
         if len(key_idx) == 0:
             return None
         keys = self.slot_to_key[key_idx]
@@ -1279,28 +1281,25 @@ class KeyedBinState:
         return keys, self._out_cols(cnt_sel, ch_sel), window_end, cnt_sel
 
     def _c_slice(self) -> int:
-        """Occupied-key transfer granularity (2048-row steps above the
-        pow2 floor: finer than pow2 buckets — which waste up to 50% of
-        the transfer — while bounding compile variants)."""
-        if self.next_slot <= 2048:
-            return min(_bucket(max(self.next_slot, 1), floor=256), self.C)
-        return min(-(-self.next_slot // 2048) * 2048, self.C)
+        """Key rows a dense read transfers: the power-of-two bucket of the
+        occupied slots, so a state that only gains keys slices at
+        log2(C) shapes in its life and not at one per fire."""
+        return min(_bucket(max(self.next_slot, 1), floor=256), self.C)
 
-    def _read_dense(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int,
-                    k: int, W: int, cnt16: bool
+    def _read_dense(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int
                     ) -> Tuple[np.ndarray, np.ndarray]:
-        """Dense emit-kernel read: dispatch, device-slice to occupied
-        keys AND real panes (k, not the pow2-padded kpad — a 5-pane
-        fire in an 8-pane kernel grid would ship 37% dead bytes), then
-        overlap the round-trips.  ONE home for fire_panes and
-        drain_deltas so a transfer/slicing fix cannot diverge."""
+        """Dense emit-kernel read of a checkpoint drain (W == 1; a fire
+        compacts on the device instead): dispatch, device-slice to the
+        occupied keys' bucket, then overlap the round-trips.  The panes
+        stay padded to ``kpad``: a slice to ``k`` would be one more shape
+        that follows the data."""
         c_slice = self._c_slice()
-        kernel = _emit_kernel(self._ch_kinds, self.C, self.B, W, kpad,
-                              self._xfer_ch, cnt16)
+        kernel = _emit_kernel(self._ch_kinds, self.C, self.B, 1, kpad,
+                              self._xfer_ch)
         outs, cnts = timed_device(kernel, self.values, self.counts,
                                   *_h2d(ring, bin_ok))
-        outs_d = outs[:, :c_slice, :k]  # [n_xfer, c_slice, k]
-        cnts_d = cnts[:c_slice, :k]  # [c_slice, k]
+        outs_d = outs[:, :c_slice]  # [n_xfer, c_slice, kpad]
+        cnts_d = cnts[:c_slice]  # [c_slice, kpad]
         _prefetch_host(outs_d, cnts_d)
         return _readback(self, outs_d), _readback(self, cnts_d)
 
@@ -1376,7 +1375,7 @@ class KeyedBinState:
         lo = self.min_bin if self.min_bin is not None else 0
         bin_ok[:k, 0] = (pane_ends >= lo) & (pane_ends <= self.max_bin)
 
-        outs, cnts = self._read_dense(ring, bin_ok, kpad, k, 1, False)
+        outs, cnts = self._read_dense(ring, bin_ok, kpad)
         _fire_done(self, None)
 
         # reset the drained bins to identity; bookkeeping stays put
@@ -1391,9 +1390,6 @@ class KeyedBinState:
             self.values, self.counts = timed_device(
                 ek, self.values, self.counts, *_h2d(rslots, ev),
                 in_total=False)
-            # drained cells are 0 again: their bounds restart from zero
-            for b in drained.tolist():
-                self._bin_bound.pop(int(b), None)
 
         key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
             outs, cnts, k)
@@ -1474,16 +1470,6 @@ class KeyedBinState:
         self.total_rows, cnt_dtype = restored_count_state(
             raw_counts, self._i32_promote)
         bin_counts = raw_counts.astype(cnt_dtype)
-        # the u16-downcast proof must survive restore: seed each restored
-        # bin's bound from its largest restored cell so cnt16 never
-        # "proves" a vacuous empty bound over non-empty state (review r4:
-        # pane counts wrapped modulo 65536 after any checkpoint restore)
-        self._bin_bound = {}
-        if raw_counts.size and lo >= 0:
-            col_max = raw_counts.max(axis=0)
-            for j, bnd in enumerate(col_max.tolist()):
-                if bnd > 0:
-                    self._bin_bound[lo + j] = int(bnd)
         span = bin_vals.shape[-1]
         self.B = _bucket(max(span, 2 * self.W + 4), floor=8)
         values = np.zeros((len(self._ch_kinds), self.C, self.B), ACC_DTYPE)

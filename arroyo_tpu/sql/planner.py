@@ -1542,9 +1542,12 @@ class Planner:
             import jax.numpy as jnp
 
             v, m = c.fn(env)
-            arr = np.asarray(v) if isinstance(v, np.ndarray) else v
-            if isinstance(arr, np.ndarray) and arr.dtype == object:
-                return v, m
+            if isinstance(v, np.ndarray):
+                # host rows (the key maps run as UDFs over a fire's exact
+                # row count): numpy, or every fire would trace and compile
+                # a convert at a shape nobody has seen
+                return (v if v.dtype == object
+                        else v.astype(np.float32)), m
             return jnp.asarray(v).astype(jnp.float32), m
 
         return Compiled(fn, c.needs_host, c.sql, c.used_cols)
@@ -1555,6 +1558,8 @@ class Planner:
             import jax.numpy as jnp
 
             v, m = c.fn(env)
+            if isinstance(v, np.ndarray) and v.dtype.kind in "iub":
+                return v.astype(np.int64), m  # host rows: see _normalize_key
             return jnp.asarray(v).astype(jnp.int64), m
 
         return Compiled(fn, c.needs_host, c.sql, c.used_cols)

@@ -51,7 +51,9 @@ directions.
 Knobs (see docs/operations.md):
   ARROYO_JOIN_STATE=partitioned|legacy   state layout (default partitioned)
   ARROYO_JOIN_PARTITIONS=16              partitions per side (power of two)
-  ARROYO_JOIN_HOT_PARTITIONS=4           device-resident partition budget
+  ARROYO_JOIN_HOT_PARTITIONS=<auto>      device-resident partition budget:
+                                         unset, as many partitions as fit
+                                         ``_RING_BUDGET_BYTES`` of rings
   ARROYO_JOIN_HOT_MIN_ROWS=4096          EWMA rows to qualify as hot
   ARROYO_JOIN_PAYLOAD_DEVICE=auto|off    payload planes on hot rings
 """
@@ -123,8 +125,27 @@ def join_partitions() -> int:
     return b
 
 
-def _hot_budget() -> int:
-    return int(os.environ.get("ARROYO_JOIN_HOT_PARTITIONS", 4))
+# device bytes one side's resident rings may hold when nobody sets a count
+_RING_BUDGET_BYTES = 256 << 20
+
+
+def _hot_budget(parts: Sequence["_Partition"], payload: bool) -> int:
+    """How many partitions of one side may hold device rings.  A set
+    ``ARROYO_JOIN_HOT_PARTITIONS`` is the count; otherwise the budget
+    follows what the rings hold: as many as fit ``_RING_BUDGET_BYTES`` at
+    the largest partition's ring (split-hash key planes, plus the payload
+    stacks when they ride along).  A numeric side of a few columns then
+    keeps every partition's gathered rows on the device; wide or huge
+    sides fall back to the hottest few."""
+    forced = os.environ.get("ARROYO_JOIN_HOT_PARTITIONS")
+    if forced is not None:
+        return int(forced)
+    from ..ops.join import ring_cap
+
+    rows = ring_cap(max((part.n for part in parts), default=0))
+    slots = 1 + max((len(part.cols) for part in parts), default=0)
+    row_bytes = 8 + (8 * slots if payload else 0)
+    return min(len(parts), _RING_BUDGET_BYTES // (rows * row_bytes))
 
 
 def _hot_min_rows() -> float:
@@ -143,7 +164,7 @@ class _Partition:
 
     __slots__ = ("cols", "keys", "ts", "n", "cap", "order", "skeys",
                  "sts", "valid_from", "dead", "_evicts_since_scan",
-                 "touches", "dev", "dev_device", "payload_on")
+                 "touches", "dev", "dev_device", "payload_on", "max_ts")
 
     def __init__(self) -> None:
         self.cols: Dict[str, np.ndarray] = {}
@@ -157,6 +178,7 @@ class _Partition:
         self.skeys = np.empty(0, dtype=np.uint64)
         self.sts = np.empty(0, dtype=np.int64)
         self.valid_from = _NEG_INF
+        self.max_ts = _NEG_INF  # newest resident row (all dead below it)
         self.dead = 0  # estimated rows below valid_from
         self._evicts_since_scan = 0
         self.touches = 0.0  # EWMA of rows handled per operation
@@ -206,6 +228,7 @@ class _Partition:
         self._ensure_cap(n + m)
         self.keys[n:n + m] = keys
         self.ts[n:n + m] = ts
+        self.max_ts = max(self.max_ts, int(ts.max()))
         for c, v in cols.items():
             if c not in self.cols:
                 col = np.empty(self.cap, dtype=v.dtype)
@@ -263,6 +286,7 @@ class _Partition:
 
     # -- device residency --------------------------------------------------
 
+    @perf.in_phase("join_merge")
     def _device_merge(self, dkeys: np.ndarray, dpos: np.ndarray,
                       keep: np.ndarray, dts: np.ndarray,
                       dcols: Optional[Dict[str, np.ndarray]]) -> None:
@@ -301,6 +325,7 @@ class _Partition:
         self.dev = merged
         perf.count("join_state_device_merges")
 
+    @perf.in_phase("join_merge")
     def promote(self, device: Any = None,
                 payload: Optional[bool] = None) -> None:
         """Stage this partition's sorted keys — plus, when the buffer's
@@ -346,6 +371,17 @@ class _Partition:
         if t <= self.valid_from or self.n == 0:
             return
         self.valid_from = t
+        if self.max_ts < t:
+            # every resident row is dead (a tumbling window's fire): drop
+            # them without a scan or a copy.  Storage and the device ring
+            # keep their capacity, so the next window's rows land at the
+            # same shapes and nothing is staged or compiled again
+            self.n = self.dead = self._evicts_since_scan = 0
+            self.order = self.order[:0]
+            self.skeys = self.skeys[:0]
+            self.sts = self.sts[:0]
+            self.max_ts = _NEG_INF
+            return
         self._evicts_since_scan += 1
         if self.n >= 1024 and self._evicts_since_scan >= 8:
             self._evicts_since_scan = 0
@@ -506,6 +542,13 @@ class PartitionedJoinBuffer(BatchBuffer):
         # emission layout (and the edge's sharding spec) never flips
         # mid-stream (shardcheck's sticky-route contract)
         self._payload_sticky_host = False
+        # (start, duration) in tracing microseconds of the device gathers'
+        # blocking readbacks since a fire last took them
+        self._d2h: List[Tuple[float, float]] = []
+
+    def take_readbacks(self) -> List[Tuple[float, float]]:
+        out, self._d2h = self._d2h, []
+        return out
 
     # -- routing -----------------------------------------------------------
 
@@ -522,10 +565,12 @@ class PartitionedJoinBuffer(BatchBuffer):
 
         return payload_device_enabled() and not self._payload_sticky_host
 
+    @perf.in_phase("join_append")
     def append(self, batch: Batch) -> None:
         if not len(batch):
             return
         assert batch.key_hash is not None, "join state requires keyed rows"
+        perf.count("join_rows_appended", len(batch))
         if batch.key_cols:
             self.key_cols = batch.key_cols
         self._schema = {c: v.dtype for c, v in batch.columns.items()}
@@ -569,7 +614,8 @@ class PartitionedJoinBuffer(BatchBuffer):
         that stops seeing rows must cool below the demotion floor, or
         its score would freeze and resident rings could exceed the
         budget forever after a skew shift."""
-        budget = _hot_budget()
+        payload = self._payload_active()
+        budget = _hot_budget(self.parts, payload)
         floor = _hot_min_rows()
         for part in self.parts:
             part.touches *= 0.98
@@ -585,7 +631,6 @@ class PartitionedJoinBuffer(BatchBuffer):
         grace = set(ranked[: budget + 2])
         from ..parallel.shuffle import partition_device
 
-        payload = self._payload_active()
         for p, part in enumerate(self.parts):
             if p in hot and part.dev is None:
                 # sharded device placement over the same ("keys",) mesh
@@ -738,7 +783,7 @@ class PartitionedJoinBuffer(BatchBuffer):
                 if ring is not None and ring.plan is not None:
                     from ..ops import join as dj
 
-                    gf, gi = dj.gather_ring(ring, spos)
+                    gf, gi = dj.gather_ring(ring, spos, self._d2h)
                     pts, pcols = dj.unpack_payload(ring, gf, gi)
                     ts[sel] = pts
                     dev_rows += len(spos)
@@ -833,6 +878,7 @@ class PartitionedJoinBuffer(BatchBuffer):
         _qidx, gpos = self.probe_positions(ks, pre_sorted=True)
         return self.gather(gpos)
 
+    @perf.in_phase("join_probe")
     def range_join(self, other: "PartitionedJoinBuffer", start: int,
                    end: int) -> Tuple[np.ndarray, np.ndarray,
                                       np.ndarray, np.ndarray]:
@@ -847,6 +893,7 @@ class PartitionedJoinBuffer(BatchBuffer):
         for p in range(self.P):
             lk, lpos = self.parts[p].range_view(start, end)
             rk, rpos = other.parts[p].range_view(start, end)
+            perf.count("join_rows_probed", len(lk) + len(rk))
             enc_l = p * (1 << 48) + lpos
             enc_r = p * (1 << 48) + rpos
             if len(lk) == 0 or len(rk) == 0:

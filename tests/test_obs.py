@@ -419,16 +419,23 @@ def test_named_dispatch_counters_and_kernel_spans():
         perf.reset_active_task(token)
     assert fired is not None
     assert perf.counter("kernel_dispatches.bins_update") == 1
-    assert perf.counter("kernel_dispatches.bins_emit") == 1
+    # a fire compacts on the device: the scan, then the pick
+    assert perf.counter("kernel_dispatches.bins_emit_count") == 1
+    assert perf.counter("kernel_dispatches.bins_emit_compact") == 1
     assert perf.counter("kernel_dispatches.bins_evict") == 1
     # the evict was a direct call before the kernels were named: it counts
     # under its name, and the unnamed total keeps its old meaning
-    assert perf.counter("kernel_dispatches") == 2
+    assert perf.counter("kernel_dispatches") == 3
     assert perf.counter("pane_update_cells") == 40
     assert perf.counter("pane_update_pad_cells") == 256 - 40
-    assert perf.counter("d2h_syncs") == 2 and perf.counter("d2h_bytes") > 0
+    # the live count, the cells' (key, pane) and their counts; a bare
+    # COUNT(*) has no channel block to read
+    assert perf.counter("d2h_syncs") == 3 and perf.counter("d2h_bytes") > 0
+    assert perf.counter("pane_emit_cells") == len(fired[0]) > 0
+    assert perf.counter("pane_scan_cells") == 40 * 3 * 2  # slots x k x W
     names = {s[0] for s in tracing.spans("kernel")}
-    assert names and names <= {"bins_update", "bins_emit", "bins_evict"}
+    assert names and names <= {"bins_update", "bins_emit_count",
+                               "bins_emit_compact", "bins_evict"}
 
 
 def _fire_accounts(state, drain):
@@ -470,7 +477,10 @@ def test_fire_and_drain_accounts_on_both_states(mesh, drain):
     counts, spans = _fire_accounts(state, drain)
     assert counts["window_fires"] == (0 if drain else 1)
     assert counts["pane_drains"] == (1 if drain else 0)
-    assert counts["d2h_syncs"] == (4 if mesh else 2)
+    # mesh: as before; single: a drain reads the dense grid (values,
+    # counts), a fire the live count and the compacted cells' (key, pane),
+    # counts and channel block
+    assert counts["d2h_syncs"] == (2 if drain and not mesh else 4)
     assert counts["d2h_bytes"] > 0
     if drain:
         assert spans == []

@@ -24,7 +24,6 @@ recompiles are O(log keys).
 from __future__ import annotations
 
 import functools
-import os
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -36,19 +35,16 @@ from ..obs import perf, tracing
 from ..obs.perf import in_phase as _in_phase, kernel_name, timed_device
 
 # f64 extremes (the accumulation channels are float64, see ACC_DTYPE):
-# f32 extremes here would clip MIN/MAX values beyond +/-3.4e38.  The
-# Pallas path never sees these — it handles additive channels only.
+# f32 extremes here would clip MIN/MAX values beyond +/-3.4e38.
 NEG_INF = float(jnp.finfo(jnp.float64).min)
 POS_INF = float(jnp.finfo(jnp.float64).max)
 
 # Numeric-fidelity policy (VERDICT r2 #5; the reference aggregates in exact
-# i64/f64, aggregating_window.rs): all XLA-path accumulation channels are
+# i64/f64, aggregating_window.rs): all accumulation channels are
 # float64 — int64 SUM/COUNT stay exact to 2^53, MIN/MAX preserve full int64
-# comparisons below that, AVG divides exactly-summed numerators.  The Pallas
-# MXU path keeps its bf16 hi/lo compensated scatter per batch but lands the
-# deltas in this f64 state, so only within-batch rounding (~2^-16 relative)
-# remains.  MIN/MAX null identities are f64 extremes (NEG_INF/POS_INF
-# above) so values beyond +/-3.4e38 never clip.
+# comparisons below that, AVG divides exactly-summed numerators.  MIN/MAX
+# null identities are f64 extremes (NEG_INF/POS_INF above) so values
+# beyond +/-3.4e38 never clip.
 ACC_DTYPE = np.float64
 
 
@@ -507,18 +503,11 @@ def preaggregate(kh: np.ndarray, bins: np.ndarray,
     return kh_s[starts], bin_s[starts], rowcnt, out
 
 
-def update_coalescing_enabled() -> bool:
-    """``ARROYO_UPDATE_COALESCE=0`` dispatches every batch's scatter
-    immediately (the pre-deferral behavior, bit-for-bit).  Read per
-    call so tests can toggle without rebuilding state."""
-    return os.environ.get("ARROYO_UPDATE_COALESCE", "1") not in (
-        "0", "off", "false")
-
-
-def _flush_cell_bound() -> int:
-    """Pending-cell count above which buffered updates flush even
-    without a reader (bounds host memory and scatter size)."""
-    return int(os.environ.get("ARROYO_UPDATE_FLUSH_CELLS", 65536))
+# Pending-cell count at which buffered updates flush even without a
+# reader: it bounds the host memory of the pending runs and the size of one
+# scatter.  The shapes ``warm_fire`` compiles follow from it
+# (``_update_rows_floor``), so it is a constant and not a setting.
+UPDATE_FLUSH_CELLS = 65536
 
 
 def _merge_cells(slots: np.ndarray, bins: np.ndarray, rowcnt: np.ndarray,
@@ -664,11 +653,10 @@ class KeyedBinState:
         # argmax candidates (planner-proven sole consumer settles the
         # global answer); only COUNT(*) values qualify (see planner)
         self._argmax_local: Optional[str] = None  # 'max' | 'min'
-        # update coalescing (ARROYO_UPDATE_COALESCE): per-batch
-        # pre-aggregated cell runs buffer HERE and flush to the device in
-        # one merged scatter when a reader needs the planes (pane fire,
-        # snapshot, ring relayout) or the buffer crosses
-        # ARROYO_UPDATE_FLUSH_CELLS — one dispatch + one h2d transfer
+        # update coalescing: per-batch pre-aggregated cell runs buffer
+        # HERE and flush to the device in one merged scatter when a reader
+        # needs the planes (pane fire, snapshot, ring relayout) or the
+        # buffer reaches UPDATE_FLUSH_CELLS — one dispatch + one h2d transfer
         # amortizes across many batches (the dominant per-batch device
         # cost once the ingest spine killed the expression dispatches)
         self._pending: List[Tuple[np.ndarray, np.ndarray, np.ndarray,
@@ -827,18 +815,14 @@ class KeyedBinState:
 
     def _enqueue_cells(self, slots_c: np.ndarray, bins_c: np.ndarray,
                        rowcnt: np.ndarray, vals_c: np.ndarray) -> None:
-        """Shared update tail: either buffer the pre-aggregated cell run
-        (update coalescing — one merged scatter carries many batches; the
-        planes are only read at pane fires / snapshots, and every reader
-        flushes) or dispatch now."""
-        m = len(slots_c)
-        if update_coalescing_enabled():
-            self._pending.append((slots_c, bins_c, rowcnt, vals_c))
-            self._pending_cells += m
-            if self._pending_cells >= _flush_cell_bound():
-                self.flush_updates()
-            return
-        self._dispatch_cells(slots_c, bins_c, rowcnt, vals_c)
+        """Shared update tail: buffer the pre-aggregated cell run (one
+        merged scatter carries many batches; the planes are only read at
+        pane fires / snapshots, and every reader flushes) and flush at
+        the bound."""
+        self._pending.append((slots_c, bins_c, rowcnt, vals_c))
+        self._pending_cells += len(slots_c)
+        if self._pending_cells >= UPDATE_FLUSH_CELLS:
+            self.flush_updates()
 
     def _update_merged(self, key_hash: np.ndarray, timestamps: np.ndarray,
                        agg_inputs: Dict[str, np.ndarray]) -> None:
@@ -910,80 +894,45 @@ class KeyedBinState:
         m = len(slots_c)
         perf.count("pane_update_dispatches")
         perf.count("pane_update_cells", m)
-        # additive aggregates route through the Pallas MXU scatter (one-hot
-        # matmul) instead of XLA's serial scatter; min/max stay on XLA
-        if self._use_pallas():
-            self._update_pallas(slots_c, bins_c, rowcnt, vals_c)
-            return
-
         npad = _bucket(m, floor=self._update_rows_floor())
         perf.count("pane_update_pad_cells", npad - m)
+        kernel, idx, packed = self._pack_cells(
+            slots_c, bins_c, rowcnt, vals_c, npad)
+        self.values, self.counts = timed_device(
+            kernel, self.values, self.counts, *_h2d(idx, packed))
+
+    def _pack_cells(self, slots_c: np.ndarray, bins_c: np.ndarray,
+                    rowcnt: np.ndarray, vals_c: np.ndarray, npad: int):
+        """The update kernel at ``npad`` cells and its two input blocks
+        for one (possibly empty) cell run: i32[2, npad] of slots and bins,
+        f64[n_xfer + 1, npad] of rowcount and transferred channels;
+        rowcount 0 marks padding.  The one place a dispatch's shapes are
+        made, for the stream's flushes and for ``warm_fire`` alike."""
+        m = len(slots_c)
         idx = np.zeros((2, npad), dtype=np.int32)
         idx[0, :m] = slots_c
         idx[1, :m] = bins_c
         packed = np.zeros((len(self._xfer_ch) + 1, npad), dtype=ACC_DTYPE)
         packed[0, :m] = rowcnt
         packed[1:, :m] = vals_c
-
         kernel = _update_kernel(self._ch_kinds, self.C, self.B, npad,
                                 self._dup_ch)
-        self.values, self.counts = timed_device(
-            kernel, self.values, self.counts, *_h2d(idx, packed))
+        return kernel, idx, packed
 
     def _update_rows_floor(self) -> int:
-        """Least cells an update dispatch is padded to.  A coalesced flush
-        carries what gathered up to the flush bound, or, before a fire,
-        whatever is left under it: padded to the bound's bucket (capped by
-        the capacity, which small states stay under), the flushes of a
-        stream take two or three shapes, which ``warm_fire`` knows, and
-        not one per size of remainder."""
-        if not update_coalescing_enabled():
-            return 256
-        return min(_bucket(_flush_cell_bound()), max(self.C, 256))
+        """Least cells an update dispatch is padded to.  A flush carries
+        what gathered up to the flush bound, or, before a fire, whatever
+        is left under it: padded to the bound's bucket (capped by the
+        capacity, which small states stay under), the flushes of a stream
+        take the two shapes ``warm_fire`` knows (a third only where one
+        batch brings more cells than the bound), and not one per size of
+        remainder."""
+        return min(_bucket(UPDATE_FLUSH_CELLS), max(self.C, 256))
 
     def _channel_input(self, j: int, agg_inputs: Dict[str, np.ndarray],
                        n: int) -> np.ndarray:
         return channel_input(self.aggs, self._ch_kinds, self._valid_of, j,
                              agg_inputs, n)
-
-    def _use_pallas(self) -> bool:
-        from .pallas_kernels import LANES, pallas_enabled
-
-        if not pallas_enabled():
-            return False
-        if not all(k in ("sum", "avg", "count") for k in self._ch_kinds):
-            return False
-        if self.counts.dtype != jnp.int32:
-            return False  # promoted i64 plane: the Pallas kernel is f32-pair
-        # packed width P = 2 channels (hi/lo) x (channels + count) x B lanes;
-        # the kernel holds [CHUNK, P] + [TILE_C, P] f32 blocks in VMEM, so
-        # wide rings (long window / short slide) must fall back to XLA
-        P = 2 * (len(self._ch_kinds) + 1) * self.B
-        return ((P + LANES - 1) // LANES) * LANES <= 1024
-
-    def _update_pallas(self, slots_c: np.ndarray, bins_c: np.ndarray,
-                       rowcnt: np.ndarray, vals_c: np.ndarray) -> None:
-        from .pallas_kernels import (active_capacity, pad_batch,
-                                     update_bin_state)
-
-        # pre-aggregated cells: counts channel carries the per-cell row
-        # count (the kernel sums weight channels, so this is exact).
-        # vals_c holds transferred channels only — COUNT(*) rows are the
-        # rowcount itself
-        if self._dup_ch:
-            full = np.empty((len(self._ch_kinds), len(rowcnt)),
-                            dtype=ACC_DTYPE)
-            for r, j in enumerate(self._xfer_ch):
-                full[j] = vals_c[r]
-            for j in self._dup_ch:
-                full[j] = rowcnt
-            vals_c = full
-        weights = np.concatenate([rowcnt[None], vals_c], axis=0)
-        s, b, w = pad_batch(slots_c.astype(np.int32), bins_c, weights)
-        perf.count("pane_update_pad_cells", len(s) - len(slots_c))
-        c_act = active_capacity(self.next_slot, self.C)
-        self.values, self.counts = update_bin_state(
-            self.values, self.counts, s, b, w, c_act, self.B)
 
     def _grow_ring(self, needed: int) -> None:
         """Rare: data spans more bins than the ring; re-layout host-side."""
@@ -1053,19 +1002,18 @@ class KeyedBinState:
         range, so it reads nothing live).  Returns how many kernels it ran.  A later ``_grow``
         changes C and compiles what it then needs, as the update does."""
         ran = 0
-        if not self._use_pallas():
-            # the flush before a fire: at the floor and, for a flush at
-            # the bound, one bucket above (rowcount 0 marks padding: the
-            # planes come back as they went in)
-            for npad in (self._update_rows_floor(),
-                         2 * self._update_rows_floor()):
-                kernel = _update_kernel(self._ch_kinds, self.C, self.B, npad,
-                                        self._dup_ch)
-                self.values, self.counts = kernel(
-                    self.values, self.counts, *_h2d(
-                        np.zeros((2, npad), np.int32),
-                        np.zeros((len(self._xfer_ch) + 1, npad), ACC_DTYPE)))
-                ran += 1
+        # the flush before a fire: at the floor and, for a flush at the
+        # bound, one bucket above (an empty run is all padding: the planes
+        # come back as they went in)
+        empty = np.zeros(0, np.int32)
+        no_vals = np.zeros((len(self._xfer_ch), 0), ACC_DTYPE)
+        floor = self._update_rows_floor()
+        for npad in (floor, 2 * floor):
+            kernel, idx, packed = self._pack_cells(
+                empty, empty, empty, no_vals, npad)
+            self.values, self.counts = kernel(
+                self.values, self.counts, *_h2d(idx, packed))
+            ran += 1
         if self._use_ring():
             return ran  # the ring sweep's shapes follow the open span
         ring_j, ok_j = _h2d(np.zeros((1, self.W), np.int32),

@@ -602,9 +602,9 @@ def test_shuffle_chains_only_at_parallelism_1():
             assert prog2.edge(u, v).typ.value == "forward"
 
 
-def test_update_coalescing_parity_with_snapshot_roundtrip(monkeypatch):
+def test_update_coalescing_parity_with_snapshot_roundtrip():
     """Deferred window-state scatters are invisible to emission and
-    checkpointing: same fired panes as the immediate-dispatch path, and
+    checkpointing: same fired panes as a flush after every update, and
     a snapshot taken mid-buffer flushes first (a restore of it resumes
     bit-identically)."""
     from arroyo_tpu.ops.keyed_bins import KeyedBinState
@@ -618,6 +618,8 @@ def test_update_coalescing_parity_with_snapshot_roundtrip(monkeypatch):
             t = rng2.integers(i * SEC, (i + 1) * SEC, 300).astype(np.int64)
             v = rng2.integers(1, 9, 300).astype(np.float64)
             state.update(kh, t, {"v": v})
+            if not deferred:  # the oracle: every batch lands at once
+                state.flush_updates()
 
     def fire(state):
         out = state.fire_panes(10 * SEC)
@@ -628,8 +630,7 @@ def test_update_coalescing_parity_with_snapshot_roundtrip(monkeypatch):
                           cols["n"].tolist(), cols["s"].tolist()))
 
     results = {}
-    for flag in ("0", "1"):
-        monkeypatch.setenv("ARROYO_UPDATE_COALESCE", flag)
+    for deferred in (False, True):
         rng2 = np.random.default_rng(11)
         st = KeyedBinState(aggs, SEC, 2 * SEC, capacity=64)
         feed(st, 6)
@@ -638,6 +639,6 @@ def test_update_coalescing_parity_with_snapshot_roundtrip(monkeypatch):
         st2 = KeyedBinState(aggs, SEC, 2 * SEC, capacity=64)
         st2.restore(snap)
         feed(st2, 2)
-        results[flag] = fire(st2)
-    assert results["1"] == results["0"]
-    assert results["1"] is not None
+        results[deferred] = fire(st2)
+    assert results[True] == results[False]
+    assert results[True] is not None

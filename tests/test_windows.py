@@ -942,3 +942,111 @@ def test_window_argmax_raw_restore_late_rows():
         assert len(ctx2.out) == 1  # nothing new, window never existed
 
     asyncio.run(drive())
+
+
+def _planes_from_rows(st, batches):
+    """The planes a state must hold after ``batches`` of (key hashes,
+    timestamps, column v), by numpy scatter over the rows: the counts
+    plane, then one plane per channel of ``st._ch_kinds`` (a hidden
+    validity channel counts rows, as no v is null here)."""
+    counts = np.zeros((st.C, st.B), np.int64)
+    planes = [np.full((st.C, st.B), {"min": np.inf, "max": -np.inf}.get(
+        kind, 0.0)) for kind in st._ch_kinds]
+    for kh, ts, v in batches:
+        at = (st.slot_of_sorted[np.searchsorted(st.key_sorted, kh)],
+              (ts // st.slide) % st.B)
+        np.add.at(counts, at, 1)
+        for j, kind in enumerate(st._ch_kinds):
+            counted = j >= len(st.aggs) or st.aggs[j].kind == AggKind.COUNT
+            if kind == "min":
+                np.minimum.at(planes[j], at, v)
+            elif kind == "max":
+                np.maximum.at(planes[j], at, v)
+            else:
+                np.add.at(planes[j], at, 1.0 if counted else v)
+    return counts, planes
+
+
+@pytest.mark.parametrize("agg_kinds,capacity,n_keys,batch_rows,shapes", [
+    # additive channels, a few hundred cells a batch, one flush at the end
+    ((AggKind.COUNT, AggKind.SUM), 64, 40, (700,) * 4, {256}),
+    # one batch of more cells than the flush bound flushes by itself at
+    # twice the floor; what the next leaves goes at the floor: both of
+    # the shapes warm_fire compiles
+    ((AggKind.COUNT, AggKind.SUM), 1 << 16, 30_000, (150_000, 500),
+     {131_072, 65_536}),
+    # min and max beside a COUNT(*) that rides no transfer
+    ((AggKind.COUNT, AggKind.MIN, AggKind.MAX), 64, 40, (700,) * 4, {256}),
+], ids=["additive", "over_flush_bound", "minmax_with_count_star"])
+def test_update_kernel_equals_numpy_scatter(monkeypatch, agg_kinds, capacity,
+                                            n_keys, batch_rows, shapes):
+    """What ``update`` -> ``flush_updates`` leaves in the planes is what
+    ``np.add.at`` / ``np.minimum.at`` / ``np.maximum.at`` leave over the
+    same rows, at the padded shapes the one rule gives."""
+    from arroyo_tpu.ops import keyed_bins
+
+    seen = set()
+    kernel_of = keyed_bins._update_kernel
+
+    def recording(kinds, C, B, n, dup=()):
+        seen.add(n)
+        return kernel_of(kinds, C, B, n, dup)
+
+    monkeypatch.setattr(keyed_bins, "_update_kernel", recording)
+    aggs = tuple(AggSpec(kind=k, column=None if k == AggKind.COUNT else "v",
+                         output=k.value) for k in agg_kinds)
+    st = keyed_bins.KeyedBinState(aggs, slide_micros=SEC, width_micros=SEC,
+                                  capacity=capacity)
+    rng = np.random.default_rng(7)
+    batches = []
+    for m in batch_rows:
+        kh = rng.integers(0, n_keys, m).astype(np.uint64)
+        ts = rng.integers(0, 4 * SEC, m).astype(np.int64)
+        v = rng.integers(-1000, 1000, m).astype(np.float64)
+        st.update(kh, ts, {"v": v})
+        batches.append((kh, ts, v))
+    st.flush_updates()
+    assert not st._pending and seen == shapes
+    counts, planes = _planes_from_rows(st, batches)
+    np.testing.assert_array_equal(np.asarray(st.counts), counts)
+    got = np.asarray(st.values)
+    for j, kind in enumerate(st._ch_kinds):
+        # an untouched min/max cell holds the f64 extreme, the oracle's inf
+        want = np.clip(planes[j], keyed_bins.NEG_INF, keyed_bins.POS_INF)
+        np.testing.assert_array_equal(got[j], want, err_msg=f"{j}:{kind}")
+
+
+def test_flushes_dispatch_only_the_warmed_shapes(monkeypatch):
+    """After ``warm_fire`` a stream of batches of varied sizes and its
+    fires ask ``_update_kernel`` for no shape beyond the two warmed: the
+    flushes and the warm-up are packed by one helper from one rule.  The
+    bound is a module constant a test can still move."""
+    from arroyo_tpu.ops import keyed_bins
+
+    monkeypatch.setattr(keyed_bins, "UPDATE_FLUSH_CELLS", 1024)
+    aggs = (AggSpec(kind=AggKind.COUNT, column=None, output="n"),
+            AggSpec(kind=AggKind.SUM, column="v", output="s"))
+    st = keyed_bins.KeyedBinState(aggs, slide_micros=SEC, width_micros=SEC,
+                                  capacity=4096)
+    assert st._update_rows_floor() == 1024
+    cold = keyed_bins._update_kernel.cache_info().misses
+    counted = keyed_bins.perf.counter("pane_update_dispatches")
+    assert st.warm_fire() == 2 + 1 + 3  # flush x 2, scan, pick 1024..4096
+    warmed = keyed_bins._update_kernel.cache_info().misses
+    assert warmed - cold <= 2
+    # the warm-up is no dispatch of the stream's: the counter ratios that
+    # read update_pad_share and rows per dispatch do not see it
+    assert keyed_bins.perf.counter("pane_update_dispatches") == counted
+    rng = np.random.default_rng(5)
+    rows = fired = 0
+    for step, m in enumerate((40, 900, 333, 1000, 7, 650, 980, 120, 64, 811)):
+        kh = rng.integers(0, 3000, m).astype(np.uint64)
+        ts = rng.integers(step * SEC, (step + 1) * SEC, m).astype(np.int64)
+        st.update(kh, ts, {"v": np.ones(m)})
+        rows += m
+        if step in (4, 9):
+            _keys, cols, _wend, _cnts = st.fire_panes((step + 1) * SEC)
+            fired += int(cols["n"].sum())
+    assert fired == rows
+    assert keyed_bins._update_kernel.cache_info().misses == warmed
+    assert keyed_bins.perf.counter("pane_update_dispatches") - counted >= 3

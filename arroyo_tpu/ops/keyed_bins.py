@@ -9,13 +9,15 @@ watermark advance, adds/retracts bins from an in-memory per-key view.  Here:
 * the **key directory** lives on host: a sorted uint64 array of known key
   hashes with a parallel slot array (lookups are one vectorized
   ``np.searchsorted`` per batch; inserts are a vectorized merge);
-* the **bin ring** lives in HBM: ``values[n_aggs, C, B]`` device arrays — C
-  key slots x B time bins of ``slide`` width each, scatter-reduced per batch
-  by one jitted kernel;
+* the **bin ring** lives in HBM as flat planes, one device array per
+  channel (``values``) and one of row counts (``counts``) — C key slots x
+  B time bins of ``slide`` width each, bin-major: the cell of slot ``s`` in
+  ring bin ``b`` lies at ``b * C + s``.  A plane is scatter-reduced per
+  flush by one jitted kernel that takes it donated and writes into it;
 * **pane emission** on watermark advance is one device kernel over all
-  pending panes at once: for sums/counts a bins-x-pane-mask **matmul**
-  (``[C,B] @ [B,k]`` — MXU work), for min/max a gathered window reduce;
-* eviction is O(1): expired ring slots are zeroed on device.
+  pending panes at once: a gather of the panes' bin rows (a bin's C slots
+  lie together in a plane) and a window reduce;
+* eviction resets the expired bin rows on device, in place.
 
 Capacity doubles when the key directory fills; shapes are powers of two so
 recompiles are O(log keys).
@@ -56,12 +58,42 @@ def _init_value(kind: AggKind) -> float:
     return 0.0
 
 
+def init_planes(ch_kinds: Tuple[str, ...], C: int, B: int):
+    """Empty bin planes for C slots x B ring bins: one flat f64[B * C] per
+    channel, filled with the channel's identity, and the flat i32 row counts.
+    A plane is bin-major (slot ``s`` of ring bin ``b`` at ``b * C + s``)
+    and one-dimensional because that is the form the TPU scatters into
+    where it lies: a ``[C, B]`` operand is tiled (8, 128) in HBM, and each
+    scatter over it was a copy of the plane to a line and, in a loop of B
+    slices, back (PERF.md section 6, PR 33).  Each channel is an array of
+    its own so that each is donated and aliased to its output by itself."""
+    values = tuple(jnp.full((B * C,), _init_value(AggKind(kind)),
+                            jnp.float64) for kind in ch_kinds)
+    return values, jnp.zeros((B * C,), jnp.int32)
+
+
+_LANES = 128  # the minor extent of a TPU tile
+
+
+def _bin_rows(plane, C: int, B: int):
+    """A flat plane by ring bin: ``[B, C / 128, 128]``, row ``b`` the C
+    slots of bin ``b`` (``[B, 1, C]`` under 128 slots).  This and not
+    ``[B, C]``, because with 128 slots innermost the tiled form of the
+    view is the line itself and the reshape moves nothing; ``[B, C]``
+    tiles bins with slots, and the TPU compiler copied the plane into
+    that form, in a loop of B slices, in every program that read it."""
+    lanes = min(C, _LANES)
+    return plane.reshape(B, C // lanes, lanes)
+
+
 @functools.lru_cache(maxsize=256)
 def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
                    dup: Tuple[int, ...] = ()):
     dup_set = frozenset(dup)
+    # a cell's place in a plane, and the one past its end, are int32
+    assert B * C < 2 ** 31, (C, B)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     @kernel_name("bins_update")
     def run(values, counts, idx, packed):
         # TWO packed inputs (two host->device transfers: indices stay
@@ -71,16 +103,24 @@ def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
         # cell.  rowcount 0 marks padding.  Channels in ``dup`` (COUNT(*))
         # accumulate exactly the rowcount, so their input never rides the
         # transfer — the kernel reconstructs it from packed[0].
+        #
+        # The planes arrive donated and every scatter writes into the
+        # array it was given.  Padding and cells out of range are sent
+        # past the plane's end and dropped there: they touch no cell.
+        # (The host hands over each (slot, bin) at most once per
+        # dispatch, so a cell takes one add whatever order the device
+        # applies them in; a scatter told so, ``unique_indices``, ran no
+        # faster on the chip: PERF.md section 6, PR 33.)
         slots = idx[0]
         bins = idx[1]
         rowcnt = packed[0]
-        valid = rowcnt > 0.5
         vals = packed[1:]
-        s = jnp.where(valid, slots, C)  # trash row
-        b = jnp.where(valid, bins, 0)
+        ok = ((rowcnt > 0.5) & (slots >= 0) & (slots < C)
+              & (bins >= 0) & (bins < B))
+        at = jnp.where(ok, bins * C + slots, B * C)
         with jax.named_scope("scatter_counts"):
-            counts = counts.at[s.clip(0, C - 1), b].add(
-                jnp.where(valid & (s < C), rowcnt, 0.0).astype(counts.dtype))
+            counts = counts.at[at].add(rowcnt.astype(counts.dtype),
+                                       mode="drop")
         outs = []
         r = 0
         for i, kind in enumerate(kinds):
@@ -90,35 +130,42 @@ def _update_kernel(kinds: Tuple[str, ...], C: int, B: int, n: int,
             else:
                 x = vals[r]
                 r += 1
-            ok = valid & (s < C)
-            si = s.clip(0, C - 1)
             with jax.named_scope("scatter_accumulate"):
                 if kind in ("sum", "avg", "count"):
-                    v = v.at[si, b].add(jnp.where(ok, x, 0.0))
+                    v = v.at[at].add(x, mode="drop")
                 elif kind == "min":
-                    v = v.at[si, b].min(jnp.where(ok, x, POS_INF))
+                    v = v.at[at].min(x, mode="drop")
                 elif kind == "max":
-                    v = v.at[si, b].max(jnp.where(ok, x, NEG_INF))
+                    v = v.at[at].max(x, mode="drop")
                 else:
                     raise ValueError(kind)
             outs.append(v)
-        with jax.named_scope("plane_write_back"):
-            return jnp.stack(outs), counts
+        return tuple(outs), counts
 
     return run
 
 
-def _pane_reduce(kind: str, g, bin_ok):
-    """Reduce one channel's gathered [..., k, W] window bins to [..., k]
-    pane aggregates (shared by the dense and compacted emit kernels so the
-    two paths cannot diverge)."""
+def _pane_reduce(kind: str, plane, C: int, B: int, ring, bin_ok):
+    """One channel's pane aggregates ``[k, C]``: the bin rows of each
+    pane's window (``ring[k, W]``, those in range marked by ``bin_ok``)
+    gathered from the flat plane and reduced over W (shared by the dense
+    and compacted emit kernels so the two paths cannot diverge)."""
+    g = _bin_rows(plane, C, B)[ring]  # [k, W, C / 128, 128]
+    ok = bin_ok[:, :, None, None]
     if kind in ("sum", "avg", "count"):
-        return jnp.sum(jnp.where(bin_ok[None], g, 0.0), axis=-1)
-    if kind == "min":
-        return jnp.min(jnp.where(bin_ok[None], g, POS_INF), axis=-1)
-    if kind == "max":
-        return jnp.max(jnp.where(bin_ok[None], g, NEG_INF), axis=-1)
-    raise ValueError(kind)
+        r = jnp.sum(jnp.where(ok, g, 0), axis=1)  # the plane's dtype
+    elif kind == "min":
+        r = jnp.min(jnp.where(ok, g, POS_INF), axis=1)
+    elif kind == "max":
+        r = jnp.max(jnp.where(ok, g, NEG_INF), axis=1)
+    else:
+        raise ValueError(kind)
+    return r.reshape(-1, C)
+
+
+def _pane_counts(counts, C: int, B: int, ring, bin_ok):
+    """Rows per key per pane ``[C, k]`` from the flat counts plane."""
+    return _pane_reduce("sum", counts, C, B, ring, bin_ok).T
 
 
 @functools.lru_cache(maxsize=256)
@@ -137,18 +184,16 @@ def _emit_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int, k: int,
     @jax.jit
     @kernel_name("bins_emit")
     def run(values, counts, ring, bin_ok):
-        # counts per key per pane: gather [C, k, W] then sum
         with jax.named_scope("window_reduce_counts"):
-            cnt_g = counts[:, ring]  # [C, k, W]
-            cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)
+            cnt = _pane_counts(counts, C, B, ring, bin_ok)
 
         outs = []
         for i in keep:
             # (avg division happens on host from the validity-count
             # channel — NOT from cnt, which counts null rows too)
             with jax.named_scope("window_reduce"):
-                outs.append(_pane_reduce(kinds[i], values[i][:, ring],
-                                         bin_ok))
+                outs.append(_pane_reduce(kinds[i], values[i], C, B, ring,
+                                         bin_ok).T)
         return (jnp.stack(outs) if outs else jnp.zeros((0, C, k))), cnt
 
     return run
@@ -166,8 +211,7 @@ def _argmax_nnz_kernel(C: int, B: int, W: int, k: int, minmax: str):
     @kernel_name("bins_argmax_nnz")
     def run(counts, ring, bin_ok):
         with jax.named_scope("window_reduce"):
-            cnt_g = counts[:, ring]  # [C, k, W]
-            cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)
+            cnt = _pane_counts(counts, C, B, ring, bin_ok)
         with jax.named_scope("pane_extremum"):
             if minmax == "max":
                 ext = jnp.max(cnt, axis=0)  # counts >= 0: empty cells lose
@@ -250,8 +294,7 @@ def _emit_count_kernel(C: int, B: int, W: int, k: int):
     @jax.jit
     @kernel_name("bins_emit_count")
     def run(counts, ring, bin_ok):
-        cnt_g = counts[:, ring]  # [C, k, W]
-        cnt = jnp.sum(jnp.where(bin_ok[None], cnt_g, 0), axis=-1)  # [C, k]
+        cnt = _pane_counts(counts, C, B, ring, bin_ok)
         flat = cnt.reshape(-1) > 0
         return cnt, _live_cells(flat), jnp.sum(flat)
 
@@ -282,8 +325,8 @@ def _emit_compact_kernel(kinds: Tuple[str, ...], C: int, B: int, W: int,
         cnt_c = jnp.where(ok, flat[safe], 0)
         outs = []
         for i in keep:
-            r = _pane_reduce(kinds[i], values[i][:, ring], bin_ok)
-            outs.append(r.reshape(-1)[safe])
+            r = _pane_reduce(kinds[i], values[i], C, B, ring, bin_ok)
+            outs.append(r[pane_idx, key_idx])
         idx2 = jnp.stack([key_idx, pane_idx])
         return idx2, cnt_c, (jnp.stack(outs) if outs else
                              jnp.zeros((0, npad), jnp.float64))
@@ -302,10 +345,11 @@ def _linearize_kernel(kinds: Tuple[str, ...], C: int, B: int, L: int):
     def run(values, counts, ring_idx, ok):
         outs = []
         for i, kind in enumerate(kinds):
-            g = values[i][:, ring_idx]  # [C, L]
+            g = _bin_rows(values[i], C, B)[ring_idx].reshape(L, C).T
             outs.append(jnp.where(ok[None, :], g,
                                   _init_value(AggKind(kind))))
-        cg = jnp.where(ok[None, :], counts[:, ring_idx], 0)
+        cg = jnp.where(ok[None, :],
+                       _bin_rows(counts, C, B)[ring_idx].reshape(L, C).T, 0)
         return (jnp.stack(outs) if outs else
                 jnp.zeros((0, C, L), jnp.float64)), cg
 
@@ -314,18 +358,20 @@ def _linearize_kernel(kinds: Tuple[str, ...], C: int, B: int, L: int):
 
 @functools.lru_cache(maxsize=256)
 def _evict_kernel(kinds: Tuple[str, ...], C: int, B: int):
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     @kernel_name("bins_evict")
     def run(values, counts, ring_slots, slot_valid):
-        # zero expired ring columns
+        # reset the expired ring bins' rows, each plane where it lies
         mask = jnp.zeros((B,), dtype=bool).at[
             jnp.where(slot_valid, ring_slots, 0)].max(slot_valid)
-        counts = jnp.where(mask[None, :], 0, counts)
-        outs = []
-        for i, kind in enumerate(kinds):
-            init = _init_value(AggKind(kind))
-            outs.append(jnp.where(mask[None, :], init, values[i]))
-        return jnp.stack(outs), counts
+
+        def reset(plane, init):
+            return jnp.where(mask[:, None, None], init,
+                             _bin_rows(plane, C, B)).reshape(-1)
+
+        return (tuple(reset(values[i], _init_value(AggKind(kind)))
+                      for i, kind in enumerate(kinds)),
+                reset(counts, 0))
 
     return run
 
@@ -633,13 +679,12 @@ class KeyedBinState:
 
         self._ndir = NativeDir.create(self.C)
 
-        self.values = jnp.zeros((len(self._ch_kinds), self.C, self.B),
-                                dtype=jnp.float64)
-        for j, kind in enumerate(self._ch_kinds):
-            iv = _init_value(AggKind(kind))
-            if iv != 0.0:
-                self.values = self.values.at[j].set(iv)
-        self.counts = jnp.zeros((self.C, self.B), dtype=jnp.int32)
+        # the planes (``init_planes`` has their layout): ``values`` one
+        # flat f64 array per channel, ``counts`` the flat row counts.  The
+        # update and the evict take them donated, so a handle read before
+        # such a call is dead after it: every caller rebinds both at once
+        self.values, self.counts = init_planes(self._ch_kinds, self.C,
+                                               self.B)
 
         self.min_bin: Optional[int] = None  # oldest retained absolute bin
         self.max_bin: Optional[int] = None
@@ -691,15 +736,18 @@ class KeyedBinState:
         while newC < needed:
             newC <<= 1
         pad = newC - self.C
-        self.values = jnp.concatenate([
-            self.values,
-            jnp.stack([jnp.full((pad, self.B),
-                                _init_value(AggKind(kind)), jnp.float64)
-                       for kind in self._ch_kinds]) if self._ch_kinds else
-            jnp.zeros((0, pad, self.B), jnp.float64)], axis=1)
-        self.counts = jnp.concatenate(
-            [self.counts, jnp.zeros((pad, self.B), self.counts.dtype)],
-            axis=0)
+
+        def widen(plane, init):
+            # every bin row grows by ``pad`` empty slots
+            return jnp.concatenate(
+                [plane.reshape(self.B, self.C),
+                 jnp.full((self.B, pad), init, plane.dtype)],
+                axis=1).reshape(-1)
+
+        self.values = tuple(
+            widen(v, _init_value(AggKind(kind)))
+            for v, kind in zip(self.values, self._ch_kinds))
+        self.counts = widen(self.counts, 0)
         self.slot_to_key = np.concatenate(
             [self.slot_to_key, np.zeros(pad, dtype=np.uint64)])
         self.C = newC
@@ -943,20 +991,14 @@ class KeyedBinState:
         newB = self.B
         while newB < needed:
             newB <<= 1
-        vals = np.asarray(self.values)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
-        cnts = np.asarray(self.counts)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
-        new_vals = np.zeros((len(self._ch_kinds), self.C, newB),
-                            dtype=ACC_DTYPE)
-        for j, kind in enumerate(self._ch_kinds):
-            new_vals[j] = _init_value(AggKind(kind))
-        new_cnts = np.zeros((self.C, newB), dtype=cnts.dtype)
+        vals, cnts = self.host_planes()
+        oldB, self.B = self.B, newB
+        new_vals, new_cnts = self._empty_host_planes(cnts.dtype)
         if self.min_bin is not None and self.max_bin is not None:
             for ab in range(self.min_bin, self.max_bin + 1):
-                new_vals[:, :, ab % newB] = vals[:, :, ab % self.B]
-                new_cnts[:, ab % newB] = cnts[:, ab % self.B]
-        self.values = jnp.asarray(new_vals)
-        self.counts = jnp.asarray(new_cnts)
-        self.B = newB
+                new_vals[:, ab % newB] = vals[:, ab % oldB]
+                new_cnts[ab % newB] = cnts[ab % oldB]
+        self._set_planes(new_vals, new_cnts)
 
     # -- pane emission ------------------------------------------------------
 
@@ -1360,14 +1402,37 @@ class KeyedBinState:
         """Resident device footprint of the bin planes (metadata-only:
         reads ``.nbytes`` off the array handles, no transfer) — feeds
         the per-job device-memory ledger (obs/latency.py)."""
-        return int(self.values.nbytes) + int(self.counts.nbytes)
+        return (sum(int(v.nbytes) for v in self.values)
+                + int(self.counts.nbytes))
+
+    def _empty_host_planes(self, counts_dtype
+                           ) -> Tuple[np.ndarray, np.ndarray]:
+        """Host planes in ``host_planes``' form holding no row: every
+        channel at its identity, the counts (of ``counts_dtype``) zero."""
+        values = np.empty((len(self._ch_kinds), self.B, self.C), ACC_DTYPE)
+        values[:] = channel_inits(self._ch_kinds)[:, None, None]
+        return values, np.zeros((self.B, self.C), counts_dtype)
+
+    def host_planes(self) -> Tuple[np.ndarray, np.ndarray]:
+        """Host copies of the planes as they lie, bin rows first:
+        ``values[n_ch, B, C]`` and ``counts[B, C]`` (a blocking readback;
+        buffered updates are not flushed here)."""
+        _prefetch_host(*self.values, self.counts)
+        values = np.empty((len(self._ch_kinds), self.B, self.C), ACC_DTYPE)
+        for j, v in enumerate(self.values):
+            values[j] = np.asarray(v).reshape(self.B, self.C)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        counts = np.asarray(self.counts).reshape(self.B, self.C)  # arroyolint: disable=host-sync -- intentional canonical-snapshot/ring-relayout readback: rescale merges and ring growth operate on host copies by design
+        return values, counts
+
+    def _set_planes(self, values: np.ndarray, counts: np.ndarray) -> None:
+        """Replace the planes by host arrays in ``host_planes``' form."""
+        self.values = tuple(jnp.asarray(v.reshape(-1)) for v in values)
+        self.counts = jnp.asarray(counts.reshape(-1))
 
     def snapshot(self) -> Dict[str, np.ndarray]:
         self.flush_updates()  # buffered cells belong to this epoch
         n = self.next_slot
-        _prefetch_host(self.values, self.counts)
-        values = np.asarray(jax.device_get(self.values))
-        counts = np.asarray(jax.device_get(self.counts))
+        values, counts = self.host_planes()
         if self.min_bin is not None and self.max_bin is not None:
             lo = self.min_bin
             cols = (np.arange(lo, self.max_bin + 1) % self.B)
@@ -1376,8 +1441,10 @@ class KeyedBinState:
             cols = np.zeros(0, dtype=np.int64)
         return {
             "bin_keys": self.slot_to_key[:n],
-            "bin_vals": values[:, :n][:, :, cols],
-            "bin_counts": counts[:n][:, cols],
+            # canonical form: a row per key, a column per linear bin
+            "bin_vals": np.ascontiguousarray(
+                values[:, cols, :n].transpose(0, 2, 1)),
+            "bin_counts": np.ascontiguousarray(counts[cols, :n].T),
             "ch_init": channel_inits(self._ch_kinds),
             "mesh_shards": np.array([1], dtype=np.int64),
             "key_sorted": self.key_sorted,
@@ -1420,20 +1487,16 @@ class KeyedBinState:
         bin_counts = raw_counts.astype(cnt_dtype)
         span = bin_vals.shape[-1]
         self.B = _bucket(max(span, 2 * self.W + 4), floor=8)
-        values = np.zeros((len(self._ch_kinds), self.C, self.B), ACC_DTYPE)
-        for j, k in enumerate(self._ch_kinds):
-            values[j] = _init_value(AggKind(k))
-        counts = np.zeros((self.C, self.B), cnt_dtype)
+        values, counts = self._empty_host_planes(cnt_dtype)
         if len(bin_keys) and span and lo >= 0:
             # bin rows land at their DIRECTORY slot (restores from a mesh
             # snapshot may order rows differently than this host's slots)
             idx = np.searchsorted(self.key_sorted, bin_keys)
             slots = self.slot_of_sorted[idx]
             cols = (np.arange(lo, lo + span) % self.B)
-            values[:, slots[:, None], cols[None, :]] = bin_vals
-            counts[slots[:, None], cols[None, :]] = bin_counts
-        self.values = jnp.asarray(values)
-        self.counts = jnp.asarray(counts)
+            values[:, cols[None, :], slots[:, None]] = bin_vals
+            counts[cols[None, :], slots[:, None]] = bin_counts
+        self._set_planes(values, counts)
 
 
 def filter_canonical_snapshot(arrays: Dict[str, np.ndarray],

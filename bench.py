@@ -1280,32 +1280,29 @@ def run_kernel_microbench() -> dict:
     # update kernel: the q5-shaped hot loop.  C keys x B bins resident
     # state, n pre-aggregated (key,bin) cells per step, one i32[2, n]
     # index + one f64[k+1, n] value transfer per step — exactly
-    # KeyedBinState.update's device path (keyed_bins.py:61-95), with
+    # KeyedBinState.update's device path (keyed_bins._update_kernel), with
     # i32 counts state as the engine holds it.
     kinds = ("count", "sum", "max")
     C, B, n = 8192, 16, 16384
     kern = kb._update_kernel(kinds, C, B, n)
-    values = jax.device_put(jnp.stack(
-        [jnp.full((C, B), kb._init_value(kb.AggKind(k)), jnp.float64)
-         for k in kinds]), dev)
-    counts = jax.device_put(jnp.zeros((C, B), jnp.int32), dev)
+    state = list(jax.device_put(kb.init_planes(kinds, C, B), dev))
     rng = np.random.default_rng(1)
-    idx_np = np.empty((2, n), np.int32)
-    idx_np[0] = rng.integers(0, C, n)
-    idx_np[1] = rng.integers(0, B, n)
+    # n distinct (slot, bin) cells: the engine pre-aggregates, so a
+    # dispatch never carries a cell twice
+    cells = rng.choice(C * B, size=n, replace=False)
+    idx_np = np.stack([cells // B, cells % B]).astype(np.int32)
     packed_np = np.empty((1 + len(kinds), n), np.float64)
     packed_np[0] = 1.0
     packed_np[1:] = rng.standard_normal((len(kinds), n))
 
-    state = [values, counts]
-
     def step():
-        # two transfers per step (indices stay i32, values exact f64)
+        # two transfers per step (indices stay i32, values exact f64);
+        # the kernel takes the planes donated, so the handles it returns
+        # replace the ones it was given, as in the engine
         idx = jax.device_put(idx_np, dev)
         packed = jax.device_put(packed_np, dev)
-        v, c = kern(state[0], state[1], idx, packed)
-        state[0], state[1] = v, c
-        jax.block_until_ready(c)
+        state[0], state[1] = kern(state[0], state[1], idx, packed)
+        jax.block_until_ready(state[1])
 
     dt = timeit(step, warmup=3, iters=20)
     out["update_step_ms"] = round(dt * 1e3, 3)

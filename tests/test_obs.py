@@ -391,7 +391,7 @@ def test_bin_kernels_carry_stable_names(factory):
         f.name for f in ast.parse(inspect.getsource(keyed_bins)).body
         if isinstance(f, ast.FunctionDef) and any(
             isinstance(g, ast.FunctionDef) and g.decorator_list
-            and ast.unparse(g.decorator_list[0]) == "jax.jit"
+            and "jax.jit" in ast.unparse(g.decorator_list[0])
             for g in ast.walk(f))]
     assert sorted(jitted) == sorted(BIN_KERNELS)
 

@@ -967,6 +967,20 @@ def _planes_from_rows(st, batches):
     return counts, planes
 
 
+def _assert_planes_equal_rows(st, batches):
+    """The state's planes, read back as they lie (bin rows first), equal
+    ``_planes_from_rows``' numpy scatter of ``batches``."""
+    from arroyo_tpu.ops import keyed_bins
+
+    counts, planes = _planes_from_rows(st, batches)
+    got, got_counts = st.host_planes()
+    np.testing.assert_array_equal(got_counts.T, counts)
+    for j, kind in enumerate(st._ch_kinds):
+        # an untouched min/max cell holds the f64 extreme, the oracle's inf
+        want = np.clip(planes[j], keyed_bins.NEG_INF, keyed_bins.POS_INF)
+        np.testing.assert_array_equal(got[j].T, want, err_msg=f"{j}:{kind}")
+
+
 @pytest.mark.parametrize("agg_kinds,capacity,n_keys,batch_rows,shapes", [
     # additive channels, a few hundred cells a batch, one flush at the end
     ((AggKind.COUNT, AggKind.SUM), 64, 40, (700,) * 4, {256}),
@@ -977,7 +991,12 @@ def _planes_from_rows(st, batches):
      {131_072, 65_536}),
     # min and max beside a COUNT(*) that rides no transfer
     ((AggKind.COUNT, AggKind.MIN, AggKind.MAX), 64, 40, (700,) * 4, {256}),
-], ids=["additive", "over_flush_bound", "minmax_with_count_star"])
+    # every slot taken, and then (a batch of 0 rows stands for it) a flush
+    # that is all padding but one cell at (C - 1, 0): where every pad cell
+    # was aimed, with a zero, while the pads stayed inside the plane
+    ((AggKind.COUNT, AggKind.SUM, AggKind.MIN), 64, 64, (700, 0), {256}),
+], ids=["additive", "over_flush_bound", "minmax_with_count_star",
+        "last_cell"])
 def test_update_kernel_equals_numpy_scatter(monkeypatch, agg_kinds, capacity,
                                             n_keys, batch_rows, shapes):
     """What ``update`` -> ``flush_updates`` leaves in the planes is what
@@ -1003,17 +1022,188 @@ def test_update_kernel_equals_numpy_scatter(monkeypatch, agg_kinds, capacity,
         kh = rng.integers(0, n_keys, m).astype(np.uint64)
         ts = rng.integers(0, 4 * SEC, m).astype(np.int64)
         v = rng.integers(-1000, 1000, m).astype(np.float64)
+        if m == 0:  # one row for the last slot's first bin, flushed alone
+            st.flush_updates()
+            assert st.next_slot == st.C == capacity
+            kh, ts, v = (st.slot_to_key[[st.C - 1]], np.zeros(1, np.int64),
+                         np.array([-7.0]))
         st.update(kh, ts, {"v": v})
         batches.append((kh, ts, v))
+        assert m or st._pending_cells == 1
     st.flush_updates()
     assert not st._pending and seen == shapes
-    counts, planes = _planes_from_rows(st, batches)
-    np.testing.assert_array_equal(np.asarray(st.counts), counts)
-    got = np.asarray(st.values)
-    for j, kind in enumerate(st._ch_kinds):
-        # an untouched min/max cell holds the f64 extreme, the oracle's inf
-        want = np.clip(planes[j], keyed_bins.NEG_INF, keyed_bins.POS_INF)
-        np.testing.assert_array_equal(got[j], want, err_msg=f"{j}:{kind}")
+    _assert_planes_equal_rows(st, batches)
+
+
+def _compiled_aliases(kernel, *args):
+    """{parameter number: output number} of the input-output aliases of
+    ``kernel`` compiled for ``args``, and the bytes they cover."""
+    import re
+
+    compiled = kernel.lower(*args).compile()
+    head = compiled.as_text().split("\n", 1)[0]
+    aliases = re.search(r"input_output_alias=\{(.*?)\}, entry_", head)
+    assert aliases, head
+    pairs = re.findall(r"\{(\d+)\}: \((\d+), \{\}", aliases.group(1))
+    return ({int(p): int(o) for o, p in pairs},
+            compiled.memory_analysis().alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("kernel", ["update", "evict"])
+@pytest.mark.parametrize("kinds,dup", [
+    (("count",), (0,)), (("min", "max", "sum", "sum"), ()),
+], ids=["count_star", "min_max"])
+def test_update_and_evict_write_the_planes_they_are_given(kernel, kinds,
+                                                          dup):
+    """The two programs that rewrite the planes take them donated, and
+    the compiled program aliases every plane to the output of its place:
+    no second copy of the state while one runs.  At the tiny shape
+    ``tests/test_obs.py`` names its kernels at, and at a min/max plan
+    with its two hidden validity channels."""
+    import jax.numpy as jnp
+
+    from arroyo_tpu.ops import keyed_bins
+
+    C, B, n = 64, 8, 256
+    values, counts = keyed_bins.init_planes(kinds, C, B)
+    if kernel == "update":
+        fn = keyed_bins._update_kernel(kinds, C, B, n, dup)
+        rest = (jnp.zeros((2, n), jnp.int32),
+                jnp.zeros((1 + len(kinds) - len(dup), n)))
+    else:
+        fn = keyed_bins._evict_kernel(kinds, C, B)
+        rest = (jnp.zeros(8, jnp.int32), jnp.zeros(8, bool))
+    aliases, nbytes = _compiled_aliases(fn, values, counts, *rest)
+    # parameters and outputs are the flattened (values..., counts)
+    assert aliases == {i: i for i in range(len(kinds) + 1)}
+    assert nbytes == sum(v.nbytes for v in values) + counts.nbytes
+    out_values, out_counts = fn(values, counts, *rest)
+    assert counts.is_deleted() and all(v.is_deleted() for v in values)
+    assert len(out_values) == len(kinds) and out_counts.shape == (B * C,)
+
+
+def test_every_reader_survives_the_donated_writes(monkeypatch):
+    """One state through every program that reads or replaces the planes,
+    each after a donated write: no handle from before an update or an
+    evict is used again ("Array has been deleted"), and at the end the
+    planes hold what numpy's scatter of the surviving rows holds."""
+    from arroyo_tpu.ops import keyed_bins
+
+    aggs = (AggSpec(kind=AggKind.COUNT, column=None, output="n"),
+            AggSpec(kind=AggKind.SUM, column="v", output="s"),
+            AggSpec(kind=AggKind.MAX, column="v", output="mx"))
+    rng = np.random.default_rng(33)
+
+    def rows(n_keys, t0, t1, m=300, key0=0):
+        kh = rng.integers(key0, key0 + n_keys, m).astype(np.uint64)
+        ts = rng.integers(t0, t1, m).astype(np.int64)
+        return kh, ts, rng.integers(-99, 99, m).astype(np.float64)
+
+    def feed(st, batch):
+        st.update(batch[0], batch[1], {"v": batch[2]})
+
+    # W == 1, so drain_deltas applies; the promotion of the counts plane
+    # to i64 (a new array on the way) falls between two updates
+    monkeypatch.setattr(keyed_bins.KeyedBinState, "_i32_promote", 500)
+    st = keyed_bins.KeyedBinState(aggs, slide_micros=SEC, width_micros=SEC,
+                                  capacity=64)
+    first = rows(40, 0, 2 * SEC)
+    feed(st, first)
+    st.flush_updates()
+    assert st.counts.dtype == np.int32
+    snap = st.snapshot()
+    assert int(snap["bin_counts"].sum()) == 300
+    second = rows(40, SEC, 3 * SEC)
+    feed(st, second)
+    assert st.counts.dtype == np.int64  # 600 rows >= 500: promoted
+    assert st.device_bytes() == (len(st._ch_kinds) * 8 + 8) * st.C * st.B
+    fired = st.fire_panes(SEC)  # fires bin 0, evicts it
+    assert fired is not None and int(fired[3].sum()) == int(
+        (first[1] < SEC).sum())
+    drained = st.drain_deltas()  # reads bins 1..2, resets them (evict)
+    assert drained is not None and int(drained[3].sum()) == 600 - int(
+        (first[1] < SEC).sum())
+    third = rows(200, 2 * SEC, 3 * SEC, key0=1000)  # past 64 slots: _grow
+    feed(st, third)
+    assert st.C == 256
+    fourth = rows(40, 2 * SEC, 3 * SEC)
+    feed(st, fourth)
+    st.flush_updates()
+    _assert_planes_equal_rows(st, [third, fourth])
+    # a restore replaces the planes; updates and warm_fire's two empty
+    # flushes go on from there
+    st2 = keyed_bins.KeyedBinState(aggs, slide_micros=SEC,
+                                   width_micros=SEC, capacity=64)
+    st2.restore(st.snapshot())
+    fifth = rows(40, 2 * SEC, 4 * SEC)
+    feed(st2, fifth)
+    assert st2.warm_fire() >= 3
+    st2.flush_updates()
+    _assert_planes_equal_rows(st2, [third, fourth, fifth])
+    # and the state the snapshot was taken from is still whole
+    _assert_planes_equal_rows(st, [third, fourth])
+
+
+@pytest.mark.parametrize("route", ["native", "numpy", "merge_inputs"])
+def test_dispatch_cells_never_gets_a_cell_twice(monkeypatch, route):
+    """No (slot, bin) comes twice in a dispatch, so every cell takes one
+    add and the planes are the same bit for bit whatever order the device
+    applies a dispatch's cells in (and a scatter may one day be told so:
+    ``unique_indices``).  That holds on every route into
+    ``_dispatch_cells``: a single run as ``update`` pre-aggregated it
+    (natively or in numpy), several runs through ``_merge_cells``, the
+    merge-input path, and ring-modular bins after the ring wrapped or
+    was laid out anew."""
+    import arroyo_tpu.native as native
+    from arroyo_tpu.ops import keyed_bins
+
+    if route == "numpy":
+        monkeypatch.setattr(native, "HAVE_NATIVE", False)
+    elif not native.HAVE_NATIVE:
+        pytest.skip("no native host library")
+    monkeypatch.setattr(keyed_bins, "UPDATE_FLUSH_CELLS", 2048)
+    dispatched = []
+    dispatch = keyed_bins.KeyedBinState._dispatch_cells
+
+    def checking(self, slots_c, bins_c, rowcnt, vals_c):
+        cells = np.asarray(slots_c, np.int64) * self.B + bins_c
+        assert len(np.unique(cells)) == len(cells)
+        assert (np.asarray(slots_c) < self.C).all() and (rowcnt > 0).all()
+        assert (0 <= np.asarray(bins_c)).all() and (bins_c < self.B).all()
+        dispatched.append(len(cells))
+        return dispatch(self, slots_c, bins_c, rowcnt, vals_c)
+
+    monkeypatch.setattr(keyed_bins.KeyedBinState, "_dispatch_cells",
+                        checking)
+    aggs = (AggSpec(kind=AggKind.COUNT, column=None, output="n"),
+            AggSpec(kind=AggKind.SUM, column="v", output="s"),
+            AggSpec(kind=AggKind.MIN, column="v", output="mn"))
+    st = keyed_bins.KeyedBinState(aggs, slide_micros=SEC,
+                                  width_micros=2 * SEC, capacity=256)
+    if route == "merge_inputs":
+        st.set_merge_inputs({j: f"c{j}" for j in st._xfer_ch}, "rows")
+    rng = np.random.default_rng(len(route))
+    total = fired = 0
+    for step in range(40):
+        # hot keys, so rows repeat a cell within a batch and across the
+        # batches of one flush; time runs on, so bins wrap round the ring
+        # (B = 8); step 25 jumps ahead by more than the ring holds
+        m = int(rng.integers(1, 1500))
+        kh = rng.zipf(1.3, m).astype(np.uint64) % 600
+        t0 = (step // 3 + (40 if step >= 25 else 0)) * SEC
+        ts = rng.integers(t0, t0 + 3 * SEC, m).astype(np.int64)
+        cols = {"v": rng.normal(size=m)}
+        if route == "merge_inputs":
+            cols = {f"c{j}": rng.normal(size=m) for j in st._xfer_ch}
+            cols["rows"] = np.ones(m)
+        st.update(kh, ts, cols)
+        total += m
+        if step % 7 == 6:
+            out = st.fire_panes(t0)
+            fired += 0 if out is None else 1
+    st.flush_updates()
+    assert st.total_rows == total and fired >= 3
+    assert len(dispatched) >= 6 and max(dispatched) > 1
 
 
 def test_flushes_dispatch_only_the_warmed_shapes(monkeypatch):

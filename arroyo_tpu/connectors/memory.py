@@ -10,6 +10,7 @@ import numpy as np
 
 from ..engine.context import Context
 from ..engine.operator import Operator, SourceFinishType, SourceOperator
+from ..obs import perf
 from ..types import Batch
 from .registry import ConnectorMeta, register_connector
 
@@ -64,6 +65,7 @@ class MemorySink(Operator):
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
         import time
 
+        perf.count("sink_rows", len(batch))
         sink_output(self.name).append(batch)
         sink_arrivals(self.name).append(time.monotonic())
 

@@ -310,18 +310,25 @@ class BinAggOperator(Operator):
         # the host half of the fire after the readbacks: the `emit` phase
         # and, for a watermark fire, the `window.fire.emit` span
         # (``watermark`` ties it to its `window.fire`; a checkpoint drain
-        # has none); ``ctx.collect`` below keeps its own phases
+        # has none); ``ctx.collect`` below keeps its own phases and, for a
+        # watermark fire, is the `window.fire.collect` span: what the
+        # chain runs downstream (projections, a key map) and the
+        # hand-over to the next task before the fire has the loop back
         tok = perf.begin_phase("emit")
         t0 = tracing.now_us()
         try:
             out = self._fired_batch(fired)
         finally:
             perf.end_phase(tok)
-        if watermark is not None:
-            tracing.record_span(
-                "window.fire.emit", "window", t0, tracing.now_us() - t0,
-                tid=tracing.ctx_tid(ctx), args={"watermark": watermark})
-        await ctx.collect(out)
+        if watermark is None:
+            await ctx.collect(out)
+            return
+        tid, args = tracing.ctx_tid(ctx), {"watermark": watermark}
+        tracing.record_span("window.fire.emit", "window", t0,
+                            tracing.now_us() - t0, tid=tid, args=args)
+        with tracing.span("window.fire.collect", "window", tid=tid,
+                          args=args):
+            await ctx.collect(out)
 
     def _fired_batch(self, fired) -> Batch:
         keys, out_cols, window_end, counts = fired

@@ -29,16 +29,17 @@ def _on_compile(name, _secs, **_kw):
         COMPILES.append(time.monotonic())
 
 
-def _run_q8(seed, stream_s, batch_size=4096, capacity=32768):
-    """The q8 cell's own SQL at a CPU size, run to the end of its stream;
-    returns (cell, sink batches, sink arrival times)."""
+def _run_q8(seed, stream_s, batch_size=4096, capacity=32768,
+            cell_name="nexmark_q8.catchup"):
+    """A cell's own SQL (q8's, or the cell named) at a CPU size, run to the
+    end of its stream; returns (cell, sink batches, sink arrival times)."""
     from arroyo_tpu import config as program_config
     from arroyo_tpu.connectors.memory import (clear_sink, sink_arrivals,
                                               sink_output)
     from arroyo_tpu.engine.engine import LocalRunner
     from arroyo_tpu.sql import plan_sql
 
-    cell = spec.load_cell("nexmark_q8.catchup", rehearsal=True)
+    cell = spec.load_cell(cell_name, rehearsal=True)
     cell.config["stream_s"] = stream_s
     cell.traffic["source_options"]["batch_size"] = batch_size
     os.environ["STATE_CAPACITY"] = str(capacity)

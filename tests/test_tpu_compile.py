@@ -34,7 +34,7 @@ def on_chip():
                                                     sharding=one_chip)
 
 
-def _plane_sized_ops(text):
+def _plane_sized_ops(text, plane=B * C):
     """(instruction, opcode) of the entry computation's instructions whose
     result is as large as a plane, parameters and views aside."""
     entry = text[text.index("ENTRY"):]
@@ -46,7 +46,7 @@ def _plane_sized_ops(text):
         for d in (dims.group(1).split(",") if dims and dims.group(1)
                   else []):
             size *= int(d)
-        if size >= B * C and op not in (
+        if size >= plane and op not in (
                 "parameter", "bitcast", "get-tuple-element", "tuple"):
             found.append((name, op))
     return found
@@ -82,3 +82,23 @@ def test_fire_scan_reads_the_counts_where_they_lie(on_chip):
     text = compiled.as_text()
     assert " while(" not in text
     assert not _plane_sized_ops(text)
+
+
+def test_hop_scan_reads_five_bin_rows_of_sixteen(on_chip):
+    """``nexmark_q5_counts.catchup``'s scan, C = 2^22 slots, a ring of 16
+    bins, HOP(2 s, 10 s): the five bin rows of the window are gathered from
+    the counts where they lie.  Nothing as large as the plane is made, no
+    loop lays it out anew, and what the program holds beside its arguments
+    is the gathered rows and the sort's, well under the plane's 268 MB."""
+    import jax.numpy as jnp
+
+    from arroyo_tpu.ops import keyed_bins
+
+    c, b, w = 1 << 22, 16, 5
+    compiled = keyed_bins._emit_count_kernel(c, b, w, 1).lower(
+        on_chip((b * c,), jnp.int32), on_chip((1, w), jnp.int32),
+        on_chip((1, w), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert " while(" not in text
+    assert not _plane_sized_ops(text, b * c)
+    assert compiled.memory_analysis().temp_size_in_bytes < b * c * 4

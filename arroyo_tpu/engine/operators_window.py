@@ -331,12 +331,11 @@ class BinAggOperator(Operator):
             await ctx.collect(out)
 
     def _fired_batch(self, fired) -> Batch:
-        keys, out_cols, window_end, counts = fired
-        # key_idx into slot arrays for key-column recovery
-        slot_idx = self.state.slot_of_sorted[
-            np.searchsorted(self.state.key_sorted, keys)]
+        keys, out_cols, window_end, _counts, slots = fired
         cols: Dict[str, np.ndarray] = {}
-        cols.update(self.keyvals.gather(slot_idx))
+        # the state hands over each row's slot (FiredPanes): the key
+        # columns are gathered by it, no key is looked up by hash here
+        cols.update(self.keyvals.gather(slots))
         cols["window_start"] = window_end - self.width
         cols["window_end"] = window_end
         cols.update(out_cols)

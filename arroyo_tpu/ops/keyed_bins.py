@@ -26,7 +26,7 @@ recompiles are O(log keys).
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -631,6 +631,22 @@ def _append_new_keys(state, new_keys: np.ndarray, ensure_capacity) -> None:
     state.slot_of_sorted = merged_slots[order]
 
 
+class FiredPanes(NamedTuple):
+    """What a fire or a drain hands the operator: a row per fired
+    (key, pane) cell.  ``slots`` is each row's host slot, the index the
+    operator's key columns are stored under: a state resolves it itself
+    (:class:`KeyedBinState` fires slots and keeps them; the mesh state
+    fires cells of a shard and looks the slot up by hash, counted in
+    ``fire_slot_lookups``), so the operator never asks which state it
+    holds."""
+
+    keys: np.ndarray
+    cols: Dict[str, np.ndarray]
+    window_end: np.ndarray
+    counts: np.ndarray
+    slots: np.ndarray
+
+
 class KeyedBinState:
     """Sharded keyed bin-ring aggregation state for one subtask."""
 
@@ -1183,13 +1199,12 @@ class KeyedBinState:
                 np.zeros((0, self.C, k))), cnts
 
     def fire_panes(self, watermark: int, final: bool = False
-                   ) -> Optional[Tuple[np.ndarray, Dict[str, np.ndarray],
-                                       np.ndarray, np.ndarray]]:
+                   ) -> Optional[FiredPanes]:
         """Emit all panes whose window end <= watermark.
 
         Pane with absolute end-bin e covers bins (e-W, e]; its window end time
         is (e+1)*slide.  Returns (keys, {agg_output: values}, window_end,
-        counts) flattened over (pane, key-with-data), or None.
+        counts, slots) flattened over (pane, key-with-data), or None.
         """
         if self.max_bin is None or self.next_slot == 0:
             return None
@@ -1264,11 +1279,7 @@ class KeyedBinState:
         else:
             key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
                 outs, cnts, k)
-        if len(key_idx) == 0:
-            return None
-        keys = self.slot_to_key[key_idx]
-        window_end = (pane_ends[pane_idx] + 1) * self.slide
-        return keys, self._out_cols(cnt_sel, ch_sel), window_end, cnt_sel
+        return self._fired(key_idx, pane_idx, cnt_sel, ch_sel, pane_ends)
 
     def _c_slice(self) -> int:
         """Key rows a dense read transfers: the power-of-two bucket of the
@@ -1333,9 +1344,21 @@ class KeyedBinState:
             out_cols[a.output] = col
         return out_cols
 
-    def drain_deltas(self) -> Optional[Tuple[np.ndarray,
-                                             Dict[str, np.ndarray],
-                                             np.ndarray, np.ndarray]]:
+    def _fired(self, key_idx: np.ndarray, pane_idx: np.ndarray,
+               cnt_sel: np.ndarray, ch_sel: np.ndarray,
+               pane_ends: np.ndarray) -> Optional[FiredPanes]:
+        """The fired value of flattened cells (shared by fire_panes and
+        drain_deltas).  ``key_idx`` is the cell's slot on every route
+        (the compaction, the argmax pick and the dense flatten index the
+        planes by slot), so it goes to the operator as it is."""
+        if len(key_idx) == 0:
+            return None
+        window_end = (pane_ends[pane_idx] + 1) * self.slide
+        return FiredPanes(self.slot_to_key[key_idx],
+                          self._out_cols(cnt_sel, ch_sel), window_end,
+                          cnt_sel, key_idx)
+
+    def drain_deltas(self) -> Optional[FiredPanes]:
         """Checkpoint-barrier drain for FACTOR pane rings (W == 1): read
         every un-fired (key, bin) cell as a pane DELTA and reset those
         cells to their channel identities — WITHOUT advancing
@@ -1383,11 +1406,7 @@ class KeyedBinState:
 
         key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
             outs, cnts, k)
-        if len(key_idx) == 0:
-            return None
-        keys = self.slot_to_key[key_idx]
-        window_end = (pane_ends[pane_idx] + 1) * self.slide
-        return keys, self._out_cols(cnt_sel, ch_sel), window_end, cnt_sel
+        return self._fired(key_idx, pane_idx, cnt_sel, ch_sel, pane_ends)
 
     # -- checkpoint ---------------------------------------------------------
     #

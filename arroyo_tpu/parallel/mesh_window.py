@@ -47,6 +47,7 @@ from ..obs.perf import kernel_name
 from ..ops.keyed_bins import (
     NEG_INF,
     POS_INF,
+    FiredPanes,
     KeyedBinState,
     _bucket,
     _fire_done,
@@ -823,6 +824,12 @@ class MeshKeyedBinState:
         if len(cell_idx) == 0:
             return None
         keys = keys_h[cell_idx]
+        # a fired cell is a row of a shard's sorted table, not a host
+        # slot: the slot is found by hash, here and not in the operator
+        from ..obs import perf as _perf
+
+        _perf.count("fire_slot_lookups", len(keys))
+        slots = self.slot_of_sorted[np.searchsorted(self.key_sorted, keys)]
         # pane_idx is relative to the transferred slice [first_rel, ..]
         window_end = (base + first_rel + pane_idx.astype(np.int64) + 1) \
             * self.slide
@@ -837,7 +844,8 @@ class MeshKeyedBinState:
                     col = col / np.maximum(nv, 1)
                 col = np.where(nv > 0, col, np.nan)
             out_cols[a.output] = col
-        return keys, out_cols, window_end, cnts[cell_idx, pane_idx]
+        return FiredPanes(keys, out_cols, window_end,
+                          cnts[cell_idx, pane_idx], slots)
 
     def fire_panes(self, watermark: int, final: bool = False):
         if self.max_bin is None or self.next_slot == 0:

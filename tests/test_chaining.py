@@ -625,7 +625,7 @@ def test_update_coalescing_parity_with_snapshot_roundtrip():
         out = state.fire_panes(10 * SEC)
         if out is None:
             return None
-        keys, cols, wend, cnts = out
+        keys, cols, wend, cnts, _slots = out
         return sorted(zip(keys.tolist(), wend.tolist(),
                           cols["n"].tolist(), cols["s"].tolist()))
 

@@ -763,7 +763,7 @@ def test_fuzz_rescale_reshard(seed):
     def drain(f):
         if f is None:
             return
-        kk, oc, wend, _ = f
+        kk, oc, wend, *_ = f
         for j in range(len(kk)):
             key = (int(kk[j]), int(wend[j]))
             assert key not in got, f"pane duplicated across shards: {key}"

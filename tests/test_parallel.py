@@ -53,7 +53,7 @@ def drive(st, kh, ts, vals, batches=3, final=True):
         f = st.fire_panes(1 << 60, final=True)
         if f:
             outs.append(f)
-    for kk, oc, wend, _cnts in outs:
+    for kk, oc, wend, _cnts, _slots in outs:
         for j in range(len(kk)):
             key = (int(kk[j]), int(wend[j]))
             assert key not in got, f"pane fired twice: {key}"
@@ -118,7 +118,7 @@ def test_mesh_null_skipping(rng):
     st._lookup_or_insert(kh)
     st.update(kh, ts, {"v": col})
     f = st.fire_panes(1 << 60, final=True)
-    kk, oc, wend, _ = f
+    kk, oc, wend, *_ = f
     exp = {}
     for t, k, v, isn in zip(ts.tolist(), kh.tolist(), vals.tolist(),
                             nulls.tolist()):
@@ -165,7 +165,7 @@ def test_mesh_snapshot_restore_rescale(rng):
     for f in (f1, f2):
         if f is None:
             continue
-        kk, oc, wend, _ = f
+        kk, oc, wend, *_ = f
         for j in range(len(kk)):
             key = (int(kk[j]), int(wend[j]))
             assert key not in got
@@ -202,7 +202,7 @@ def test_merge_snapshots_min_max_across_disjoint_spans():
     st.restore(merged)
     f = st.fire_panes(1 << 60, final=True)
     assert f is not None
-    kk, oc, wend, _ = f
+    kk, oc, wend, *_ = f
     got = {(int(kk[j]), int(wend[j])):
            (int(oc["cnt"][j]), int(oc["total"][j]),
             int(oc["lo"][j]), int(oc["hi"][j]))
@@ -314,7 +314,7 @@ def test_snapshot_cross_topology(rng):
         for f in (f1, f2):
             if f is None:
                 continue
-            kk, oc, wend, _ = f
+            kk, oc, wend, *_ = f
             for j in range(len(kk)):
                 key = (int(kk[j]), int(wend[j]))
                 assert key not in got
@@ -335,7 +335,7 @@ def test_mesh_out_of_order_before_fire(rng):
     st._lookup_or_insert(kh[1:2])
     st.update(kh[1:2], np.array([2 * SEC], np.int64), {"v": np.array([9])})
     f = st.fire_panes(1 << 60, final=True)
-    kk, oc, wend, _ = f
+    kk, oc, wend, *_ = f
     got = {int(w): (int(c), int(t)) for w, c, t in
            zip(wend, oc["cnt"], oc["total"])}
     # t=2s feeds windows ending 3s and 4s; t=10s feeds 11s and 12s
@@ -687,6 +687,6 @@ def test_mesh_i32_counts_plane_promotes_to_i64(rng, monkeypatch):
     assert st2.d_counts.dtype == jnp.int64
     r = st2.fire_panes(10 ** 9, final=True)
     assert r is not None
-    _, cols, _, cnts = r
+    _, cols, _, cnts, _ = r
     assert int(cols["cnt"].sum()) == 2 * total  # W=2 panes, nothing lost
     assert (cnts > 0).all()

@@ -352,7 +352,7 @@ def test_sum_exactness_hot_key_large_magnitudes(rng):
         st.update(kh[s:e], ts[s:e], {"v": vals[s:e]})
     f = st.fire_panes(1 << 60, final=True)
     assert f is not None
-    _kk, oc, _wend, _cnt = f
+    _kk, oc, _wend, _cnt, _slots = f
     exact = int(vals.sum())  # ~7.5e11, exact in int64 and in f64 < 2^53
     assert int(oc["total"][0]) == exact
     assert int(oc["cnt"][0]) == n
@@ -378,7 +378,7 @@ def test_mesh_sum_exactness_hot_key(rng):
         st.update(kh[s:e], ts[s:e], {"v": vals[s:e]})
     f = st.fire_panes(1 << 60, final=True)
     assert f is not None
-    _kk, oc, _wend, _cnt = f
+    _kk, oc, _wend, _cnt, _slots = f
     assert int(oc["total"][0]) == int(vals.sum())
 
 
@@ -418,7 +418,7 @@ def test_ring_growth_does_not_ghost_duplicate(rng):
     def fire(wm, final=False):
         f = st.fire_panes(wm, final=final)
         if f:
-            kk, oc, wend, _ = f
+            kk, oc, wend, *_ = f
             for j in range(len(kk)):
                 key = (int(kk[j]), int(wend[j]) // 100_000 - 1)
                 assert key not in got, f"pane refire {key}"
@@ -450,7 +450,7 @@ def test_min_max_beyond_float32_range():
 
     st = KeyedBinState(aggs, SEC, SEC, capacity=16)
     st.update(kh, ts, {"v": vals})
-    _k, oc, _w, _c = st.fire_panes(1 << 60, final=True)
+    _k, oc, _w, _c, _s = st.fire_panes(1 << 60, final=True)
     assert oc["lo"][0] == -1e300 and oc["hi"][0] == 1e300
 
     _u, cols, _t, _rc, _vc = segment_aggregate(kh, ts, {"v": vals}, aggs)
@@ -621,14 +621,14 @@ def test_i32_counts_plane_promotes_to_i64(monkeypatch):
     st2 = KeyedBinState(aggs, 1000, 1000, capacity=16)
     st2.restore(st.snapshot())
     assert st2.total_rows == total
-    keys_o, cols, wend, cnts = st.fire_panes(10**9, final=True)
+    keys_o, cols, wend, cnts, _slots = st.fire_panes(10**9, final=True)
     assert int(cols["n"].sum()) == total  # every row counted, no wrap
     # ring emission follows the promoted dtype instead of recasting i32
     monkeypatch.setenv("ARROYO_RING", "on")
     st3 = KeyedBinState(aggs, 1000, 1000, capacity=16)
     st3.restore(st2.snapshot())
     assert st3.counts.dtype == jnp.int64
-    k3, c3, w3, n3 = st3.fire_panes(10**9, final=True)
+    k3, c3, w3, n3, _s3 = st3.fire_panes(10**9, final=True)
     assert n3.dtype == np.int64
     assert int(c3["n"].sum()) == total
 
@@ -652,7 +652,7 @@ def test_count_star_skips_f64_transfer():
     ts = rng.integers(0, 5000, n).astype(np.int64)
     v = rng.normal(size=n)
     st.update(keys, ts, {"v": v})
-    keys_o, cols, wend, cnts = st.fire_panes(10**9, final=True)
+    keys_o, cols, wend, cnts, _slots = st.fire_panes(10**9, final=True)
     assert int(cols["n"].sum()) == 2 * n  # each row in W=2 panes
     np.testing.assert_array_equal(cols["n"], cnts)  # COUNT(*) == row count
     oracle = {}
@@ -725,8 +725,9 @@ def test_compact_emission_matches_dense(monkeypatch):
     dense = run("dense")
     comp = run("compact")
     assert len(dense) == len(comp) >= 2
-    for (k1, c1, w1, n1), (k2, c2, w2, n2) in zip(dense, comp):
+    for (k1, c1, w1, n1, s1), (k2, c2, w2, n2, s2) in zip(dense, comp):
         np.testing.assert_array_equal(k1, k2)
+        np.testing.assert_array_equal(s1, s2)
         np.testing.assert_array_equal(w1, w2)
         np.testing.assert_array_equal(n1, n2)
         for name in ("n", "s", "a", "mn"):
@@ -751,7 +752,7 @@ def test_count_over_u16_survives_restore():
     st2 = KeyedBinState(aggs, 1000, 1000, capacity=16)
     st2.restore(st.snapshot())
     assert st2.total_rows == n  # restore sees the restored mass
-    keys_o, cols, wend, cnts = st2.fire_panes(10 ** 9, final=True)
+    keys_o, cols, wend, cnts, _slots = st2.fire_panes(10 ** 9, final=True)
     assert int(cols["n"][0]) == n  # not n % 65536
 
 
@@ -1235,7 +1236,7 @@ def test_flushes_dispatch_only_the_warmed_shapes(monkeypatch):
         st.update(kh, ts, {"v": np.ones(m)})
         rows += m
         if step in (4, 9):
-            _keys, cols, _wend, _cnts = st.fire_panes((step + 1) * SEC)
+            _keys, cols, _wend, _cnts, _slots = st.fire_panes((step + 1) * SEC)
             fired += int(cols["n"].sum())
     assert fired == rows
     assert keyed_bins._update_kernel.cache_info().misses == warmed

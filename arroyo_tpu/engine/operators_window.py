@@ -421,15 +421,33 @@ def _apply_top_n(batch: Batch, partition_cols: Tuple[str, ...],
                  sort_column: str, max_elements: Optional[int],
                  rank_column: Optional[str] = None) -> Batch:
     """Keep the top ``max_elements`` rows by ``sort_column`` (desc) per
-    partition — one fused device sort over (partition, window) segments
+    partition — one device program over (partition, window) segments
     (ops/topk.py; SURVEY #14/#15 device top-k).  Tiny batches stay on a
     host lexsort: kernel dispatch costs more than the sort itself.
 
     ``max_elements=None`` ranks without pruning; ``rank_column`` emits
     the 1-based per-partition rank (ROW_NUMBER() materialized) — ranks
-    are computed on the (small) surviving row set on host."""
+    are computed on the (small) surviving row set on host.
+
+    Every selection of a non-empty batch is one `topn.select` span and
+    counts itself (``topn_selects``) and its rows in and out, wherever
+    it runs: the TopN operator's timer or a bin aggregate's fused fire."""
     if len(batch) == 0:
         return batch
+    from ..obs import perf, tracing
+
+    perf.count("topn_selects")
+    perf.count("topn_rows_in", len(batch))
+    with tracing.span("topn.select", "window"):
+        out = _top_n_rows(batch, partition_cols, sort_column, max_elements,
+                          rank_column)
+    perf.count("topn_rows_out", len(out))
+    return out
+
+
+def _top_n_rows(batch: Batch, partition_cols: Tuple[str, ...],
+                sort_column: str, max_elements: Optional[int],
+                rank_column: Optional[str]) -> Batch:
     sort_val = batch.columns[sort_column]
     part = _topn_partition(batch, partition_cols)
     if max_elements is not None:
@@ -891,6 +909,9 @@ class TumblingTopNOperator(Operator):
         self.buffer = ctx.state.get_batch_buffer("t")
 
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
+        from ..obs import perf
+
+        perf.count("topn_buffer_rows", len(batch))
         self.buffer.append(batch)
         ends = np.unique((batch.timestamp // self.width + 1) * self.width)
         for e in ends.tolist():
@@ -898,7 +919,15 @@ class TumblingTopNOperator(Operator):
 
     async def handle_timer(self, time: int, key: Any, payload: Any,
                            ctx: Context) -> None:
-        end = key[1]
+        from ..obs import tracing
+
+        # one window's query of the buffer, its selection (`topn.select`
+        # inside), the projection and the hand-over downstream
+        with tracing.span("topn.fire", "window", tid=tracing.ctx_tid(ctx),
+                          args={"window_end": int(key[1])}):
+            await self._fire(key[1], ctx)
+
+    async def _fire(self, end: int, ctx: Context) -> None:
         start = end - self.width
         rows = self.buffer.query_range(start, end)
         if rows is not None and len(rows):

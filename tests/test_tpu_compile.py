@@ -102,3 +102,26 @@ def test_hop_scan_reads_five_bin_rows_of_sixteen(on_chip):
     assert " while(" not in text
     assert not _plane_sized_ops(text, b * c)
     assert compiled.memory_analysis().temp_size_in_bytes < b * c * 4
+
+
+def test_top_k_compiles_at_a_windows_size_without_a_sort(on_chip):
+    """``nexmark_topn_price.catchup``'s selection, 600,000 rows in the
+    bucket 2^20: the segment top-k is scans over ``[1024, 1024]`` arrays
+    inside one loop over k, and no ``sort``.  A ``lax.sort`` on (segment,
+    value, row) keys took this compiler 38 s at 2^14 rows and 200 s at
+    2^16 (PERF.md section 6, PR 36), so what is held here is that the
+    program compiles at all inside a test's time, and stays small."""
+    import jax.numpy as jnp
+
+    from arroyo_tpu.ops import topk
+
+    n_pad = 1 << 20
+    lines = topk._lines(n_pad)
+    compiled = topk._topk_kernel(n_pad).lower(
+        on_chip(lines, jnp.int8), on_chip(lines, jnp.int32),
+        on_chip(lines, jnp.int32), on_chip((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert " sort(" not in text
+    assert text.count(" while(") == 1  # the k passes, and no other loop
+    # the three inputs are 9 MB; the passes keep a few such arrays
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -51,6 +51,7 @@ import logging
 import os
 import time as _time
 import weakref
+from contextvars import ContextVar
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -72,6 +73,13 @@ from .operator import Operator
 from .operators_basic import ExpressionOperator, KeyByOperator, UdfOperator
 
 logger = logging.getLogger(__name__)
+
+# child-inclusive seconds of the ``_feed`` call under way in this asyncio
+# task (a one-element list, None outside any): per task, because a window
+# fire's tail feeds the members behind its aggregate beside the runner's
+# own feed of the next batch
+_LAT_CHILD: ContextVar[Optional[List[float]]] = ContextVar(
+    "arroyo_chain_lat_child", default=None)
 
 
 def ingest_fusion_enabled() -> bool:
@@ -267,7 +275,6 @@ class ChainedOperator(Operator):
         # execution steps: (exec_operator, member_indices, exec_ctx_idx)
         self._steps: List[Tuple[Operator, List[int], int]] = []
         self._step_by_start: Dict[int, Tuple[Operator, List[int], int]] = {}
-        self._lat_stack: List[float] = []  # child-inclusive seconds
         # latency observatory: when this chain ends the dataflow (tail
         # Collector has no outgoing edges), the feed into the tail
         # member is the sink boundary — observing there (not at chain
@@ -347,6 +354,14 @@ class ChainedOperator(Operator):
         for member, mctx in zip(self.members, self.ctxs):
             await member.on_close(mctx)
 
+    async def settle(self, ctx: Context) -> None:
+        for member, mctx in zip(self.members, self.ctxs):
+            await member.settle(mctx)
+
+    def abandon(self) -> None:
+        for member in self.members:
+            member.abandon()
+
     async def checkpoint_state(self, barrier: CheckpointBarrier,
                                ctx: Context) -> List[Any]:
         metas: List[Any] = []
@@ -410,7 +425,8 @@ class ChainedOperator(Operator):
                     m.messages_sent.inc(n)
         # exclusive latency: inclusive minus time spent in downstream
         # members this call recursed into (collect is synchronous)
-        self._lat_stack.append(0.0)
+        parent, child = _LAT_CHILD.get(), [0.0]
+        lat_token = _LAT_CHILD.set(child)
         token = perf.set_active_task(self._accs[idxs[0]])
         prof = profiler.active()
         frame = (prof.begin(self.infos[idxs[0]].operator_id, "proc")
@@ -426,12 +442,12 @@ class ChainedOperator(Operator):
                 prof.end(frame)
             perf.reset_active_task(token)
             inclusive = _time.perf_counter() - t0
-            child = self._lat_stack.pop()
-            if self._lat_stack:
-                self._lat_stack[-1] += inclusive
+            _LAT_CHILD.reset(lat_token)
+            if parent is not None:
+                parent[0] += inclusive
             m0 = self.ctxs[idxs[0]].metrics
             if m0 is not None:
-                m0.batch_latency.observe(max(inclusive - child, 0.0))
+                m0.batch_latency.observe(max(inclusive - child[0], 0.0))
 
     # -- watermarks / timers ----------------------------------------------
 
@@ -482,4 +498,6 @@ class ChainedOperator(Operator):
                 if frame is not None:
                     prof.end(frame)
         elif wm.is_idle and mctx.watermarks.all_idle():
+            # what the member still owes downstream goes before the idle
+            await self.members[i].settle(mctx)
             await mctx.broadcast(Message.wm(Watermark.idle()))

@@ -109,6 +109,22 @@ class Operator:
         right before the state store snapshot."""
         pass
 
+    async def settle(self, ctx: Context) -> None:
+        """Await what this operator started beside its serial path and
+        has not yet sent downstream (a window fire's tail,
+        ``BinAggOperator.handle_watermark``); an exception raised there is
+        raised here.  The operator calls it wherever order asks for it
+        (the next fire, a barrier, a commit, the close); the runner calls it
+        before it forwards past the operator a message the operator does
+        not handle itself (an idle watermark)."""
+        pass
+
+    def abandon(self) -> None:
+        """The task ends without ``on_close`` (a failure, an immediate
+        stop, a cancel): drop what ``settle`` would have awaited.  Called
+        by the runner as its last act."""
+        pass
+
     async def handle_commit(self, epoch: int, ctx: Context) -> None:
         """Second phase of two-phase commit (sinks only)."""
         pass

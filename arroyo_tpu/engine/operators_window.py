@@ -32,6 +32,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import asyncio
+import logging
 import time as _time
 
 import numpy as np
@@ -56,6 +57,8 @@ from ..types import Batch, Message, UpdateOp, UPDATE_OP_COLUMN, Watermark
 from .build import register_builder
 from .context import Context
 from .operator import Operator
+
+logger = logging.getLogger(__name__)
 
 MAX_SESSION_SIZE_MICROS = 24 * 3600 * 1_000_000  # windows.rs:17
 
@@ -191,6 +194,10 @@ class BinAggOperator(Operator):
         # stamp, monotonic arrival) pending until the next pane fire
         self._lat_pending: Optional[Tuple[int, float]] = None
         self._ledger_updates = 0  # throttles the pane_state_registry note
+        # the last task started beside the serial path (handle_watermark):
+        # a fire's tail, or a watermark's forward queued behind it;
+        # awaited by ``settle``
+        self._tail: Optional[asyncio.Future] = None
 
     def _offload_transfers(self) -> bool:
         """Run device update/emit in an executor thread on accelerators:
@@ -249,23 +256,31 @@ class BinAggOperator(Operator):
         if hasattr(self.state, "warm_fire"):  # not the mesh state's
             self.state.warm_fire()
 
+    async def _run_state(self, fn, *args):
+        """``fn(*args)`` of the state, in an executor thread where
+        transfers block (``_offload_transfers``).  Safe to offload: the
+        serial path awaits it, and a fire's tail touches its handle
+        alone."""
+        if self._offload_transfers():
+            from ..obs import perf
+
+            return await perf.run_offloaded(asyncio.get_running_loop(),
+                                            fn, *args)
+        return fn(*args)
+
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
         assert batch.key_hash is not None, f"{self.name} requires keyed input"
+        if await self._tail_in_flight(ctx):
+            from ..obs import perf
+
+            perf.count("fire_overlap_batches")
         self._lat_pending = _lat_track(self._lat_pending, batch)
         self._key_cols = batch.key_cols
         prev = self.state.next_slot
         slots = self.state._lookup_or_insert(batch.key_hash)
         self.keyvals.ensure(batch, slots, prev, self.state.next_slot)
-        # safe to offload: this operator's messages are processed
-        # serially, so state is never touched concurrently
-        if self._offload_transfers():
-            from ..obs import perf
-
-            await perf.run_offloaded(
-                asyncio.get_running_loop(), self.state.update,
-                batch.key_hash, batch.timestamp, batch.columns)
-        else:
-            self.state.update(batch.key_hash, batch.timestamp, batch.columns)
+        await self._run_state(self.state.update, batch.key_hash,
+                              batch.timestamp, batch.columns)
         self._ledger_updates += 1
         if self._ledger_updates % 16 == 1 and hasattr(self.state,
                                                       "device_bytes"):
@@ -280,30 +295,127 @@ class BinAggOperator(Operator):
             reg[self.name] = self.state.device_bytes()
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
+        """A fire in two halves.  The head is what the operator's serial
+        path waits for: everything that reads or writes what an update
+        does (``KeyedBinState.fire_head``).  The tail, the read-back's
+        wait, the fired batch, its collect and the watermark's broadcast,
+        touches the head's handle alone and, where transfers block, runs
+        as a task of its own beside the next batches.  What leaves the
+        operator leaves in the serial order: a fire's tail starts when
+        the tail before it is over (the head waits for it: two fires'
+        outputs are on the device for no longer), and a watermark that
+        fires nothing (sources send one a batch) is forwarded from behind
+        the tail in flight without waiting for it here; ``settle`` awaits
+        them all.  Span ``window.fire.hold`` is the head, ``window.fire``
+        a fire's watermark in to its batch sent on."""
         from ..obs import tracing
         from ..types import MAX_TIMESTAMP
 
         final = watermark >= int(MAX_TIMESTAMP) - 1
-        # flight-recorder tap: pane firing is where windowed pipelines
-        # spend their watermark-driven time
-        with tracing.span("window.fire", "window",
-                          tid=tracing.ctx_tid(ctx),
-                          args={"watermark": int(watermark)}):
-            # pane emission device_get is the biggest device->host transfer
-            # in the pipeline (same offload rationale as update)
-            if self._offload_transfers():
-                from ..obs import perf
-
-                fired = await perf.run_offloaded(
-                    asyncio.get_running_loop(),
-                    lambda: self.state.fire_panes(watermark, final=final))
+        t0 = tracing.now_us()
+        tid, args = tracing.ctx_tid(ctx), {"watermark": int(watermark)}
+        forward = Message.wm(Watermark.event_time(watermark))
+        with tracing.span("window.fire.hold", "window", tid=tid, args=args):
+            in_flight = await self._tail_in_flight(ctx)
+            # the mesh state has no head and tail: its fire is serial
+            tail = getattr(self.state, "fire_tail", None)
+            fire = await self._run_state(
+                self.state.fire_panes if tail is None
+                else self.state.fire_head, watermark, final)
+            if fire is None:
+                tracing.record_span("window.fire", "window", t0,
+                                    tracing.now_us() - t0, tid=tid,
+                                    args=args)
+                if in_flight:
+                    self._tail = asyncio.ensure_future(self._beside(
+                        self._tail, ctx.broadcast, forward))
+                else:
+                    await ctx.broadcast(forward)
+                return
+            # the stamp leaves with this fire: the next batch's would
+            # overwrite it before the tail built its batch
+            lat, self._lat_pending = self._lat_pending, None
+            await self.settle(ctx)
+            rest = (fire, tail, lat, forward, t0, ctx)
+            if tail is None or not self._offload_transfers():
+                await self._finish_fire(*rest)
             else:
-                fired = self.state.fire_panes(watermark, final=final)
-            if fired is not None:
-                await self._emit(fired, ctx, int(watermark))
-        await ctx.broadcast(Message.wm(Watermark.event_time(watermark)))
+                self._tail = asyncio.ensure_future(self._beside(
+                    None, self._finish_fire, *rest))
 
-    async def _emit(self, fired, ctx: Context,
+    async def _finish_fire(self, fire, tail, lat, forward: Message,
+                           t0: float, ctx: Context) -> None:
+        """A fire's tail: the pane emission's device_get, the biggest
+        device->host transfer in the pipeline (offloaded as the update
+        is), the fired batch downstream, then the watermark."""
+        from ..obs import tracing
+
+        watermark = int(forward.watermark.time)
+        try:
+            if tail is not None:
+                fire = await self._run_state(tail, fire)
+            await self._emit(fire, ctx, lat, watermark)
+        finally:
+            # flight-recorder tap: pane firing is where windowed pipelines
+            # spend their watermark-driven time
+            tracing.record_span("window.fire", "window", t0,
+                                tracing.now_us() - t0,
+                                tid=tracing.ctx_tid(ctx),
+                                args={"watermark": watermark})
+        await ctx.broadcast(forward)
+
+    async def _beside(self, prev, fn, *args) -> None:
+        """``fn(*args)`` as a task of its own, after the task ``prev``:
+        its profiler frames on a stack of their own, under a ``watermark``
+        phase as the runner opens around ``handle_watermark``."""
+        from ..obs import perf, profiler
+
+        if prev is not None:
+            await prev
+        profiler.detach_stack()
+        tok = perf.begin_phase("watermark")
+        try:
+            await fn(*args)
+        finally:
+            perf.end_phase(tok)
+
+    async def _tail_in_flight(self, ctx: Context) -> bool:
+        """Whether a task started beside the serial path still runs; one
+        that is over is let go of here, and what it raised is raised."""
+        if self._tail is not None and self._tail.done():
+            await self.settle(ctx)
+        return self._tail is not None
+
+    async def settle(self, ctx: Context) -> None:
+        if self._tail is not None:
+            try:
+                await self._tail  # raises what the tail raised
+            finally:
+                self._tail = None
+
+    def abandon(self) -> None:
+        tail, self._tail = self._tail, None
+        if tail is None:
+            return
+        if not tail.done():
+            tail.cancel()
+        elif not tail.cancelled() and tail.exception() is not None:
+            logger.error("%s: a fire's tail failed: %r", self.name,
+                         tail.exception())
+
+    async def pre_checkpoint(self, barrier, ctx: Context) -> None:
+        # a window fired before the barrier is downstream before it, and
+        # the snapshot's last_fired_pane has no un-emitted row behind it
+        await self.settle(ctx)
+
+    async def handle_commit(self, epoch: int, ctx: Context) -> None:
+        # chain members behind this one commit with no batch on its way
+        await self.settle(ctx)
+
+    async def on_close(self, ctx: Context) -> None:
+        await self.settle(ctx)
+
+    async def _emit(self, fired, ctx: Context, lat=None,
                     watermark: Optional[int] = None) -> None:
         from ..obs import perf, tracing
 
@@ -313,11 +425,11 @@ class BinAggOperator(Operator):
         # has none); ``ctx.collect`` below keeps its own phases and, for a
         # watermark fire, is the `window.fire.collect` span: what the
         # chain runs downstream (projections, a key map) and the
-        # hand-over to the next task before the fire has the loop back
+        # hand-over to the next task
         tok = perf.begin_phase("emit")
         t0 = tracing.now_us()
         try:
-            out = self._fired_batch(fired)
+            out = self._fired_batch(fired, lat)
         finally:
             perf.end_phase(tok)
         if watermark is None:
@@ -330,7 +442,9 @@ class BinAggOperator(Operator):
                           args=args):
             await ctx.collect(out)
 
-    def _fired_batch(self, fired) -> Batch:
+    def _fired_batch(self, fired, lat=None) -> Batch:
+        """The output batch of fired cells; ``lat`` is the latency stamp
+        that was pending when they fired (``_lat_pending``'s form)."""
         keys, out_cols, window_end, _counts, slots = fired
         cols: Dict[str, np.ndarray] = {}
         # the state hands over each row's slot (FiredPanes): the key
@@ -342,8 +456,7 @@ class BinAggOperator(Operator):
         ts = window_end - 1  # emit at w.end - 1ns analog (windows.rs:95)
         key_cols = self._key_cols or tuple(self.keyvals.cols)
         out = Batch(ts, cols, keys.astype(np.uint64), key_cols,
-                    lat_stamp=_lat_consume(self._lat_pending))
-        self._lat_pending = None
+                    lat_stamp=_lat_consume(lat))
 
         if self.top_n is not None:
             out = _apply_top_n(out, *self.top_n)
@@ -369,15 +482,11 @@ class FactorPaneOperator(BinAggOperator):
         super().__init__(name, pane_micros, pane_micros, aggs)
 
     async def pre_checkpoint(self, barrier, ctx: Context) -> None:
-        if self._offload_transfers():
-            from ..obs import perf
-
-            fired = await perf.run_offloaded(
-                asyncio.get_running_loop(), self.state.drain_deltas)
-        else:
-            fired = self.state.drain_deltas()
+        await super().pre_checkpoint(barrier, ctx)  # the fire's tail first
+        fired = await self._run_state(self.state.drain_deltas)
         if fired is not None:
-            await self._emit(fired, ctx)
+            lat, self._lat_pending = self._lat_pending, None
+            await self._emit(fired, ctx, lat)
 
 
 class DerivedWindowOperator(BinAggOperator):

@@ -143,6 +143,7 @@ class TaskRunner:
             except Exception:
                 pass
         finally:
+            self.operator.abandon()  # nothing after a normal close
             tracing.record_span(
                 "task.run", "task", run_start,
                 tracing.now_us() - run_start, tid=self.task_info.task_id,
@@ -314,6 +315,8 @@ class TaskRunner:
                         await self._advance_watermark(advanced)
                     elif (msg.watermark.is_idle
                           and self.ctx.watermarks.all_idle()):
+                        # a fire's tail still in flight goes before it
+                        await self.operator.settle(self.ctx)
                         await self.out_ctx.broadcast(
                             Message.wm(Watermark.idle()))
                 elif msg.kind == MessageKind.BARRIER:

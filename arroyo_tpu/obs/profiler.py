@@ -217,6 +217,14 @@ class _StackBox:
 _STACK: ContextVar[Optional[_StackBox]] = ContextVar(
     "arroyo_profiler_stack", default=None)
 
+
+def detach_stack() -> None:
+    """Give the current asyncio task a frame stack of its own.  A task
+    started from inside another task (a window fire's tail) copies a
+    context whose box is its parent's live one, same thread and all."""
+    _STACK.set(None)
+
+
 # frame layout: [op_id, phase, is_wait, t0, child_inclusive_secs,
 #                open TraceAnnotation or None]
 _OP, _PHASE, _WAIT, _T0, _CHILD, _ANN = range(6)

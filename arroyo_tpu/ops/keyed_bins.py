@@ -25,6 +25,7 @@ recompiles are O(log keys).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
@@ -425,7 +426,9 @@ def _h2d(*host_arrays):
 def _readback(state, dev) -> np.ndarray:
     """One blocking device->host readback of a fire or drain: the
     ``d2h_wait`` phase, counted, and kept on ``state._d2h`` for the fire's
-    ``window.fire.d2h`` span."""
+    ``window.fire.d2h`` span (``state`` is whoever keeps that list: a
+    state while the head of a fire or a drain runs, the
+    :class:`PendingFire` in a fire's tail)."""
     tok = perf.begin_phase("d2h_wait")
     t0 = tracing.now_us()
     try:
@@ -645,6 +648,31 @@ class FiredPanes(NamedTuple):
     window_end: np.ndarray
     counts: np.ndarray
     slots: np.ndarray
+
+
+@dataclasses.dataclass(eq=False)
+class PendingFire:
+    """What the head of a fire (:meth:`KeyedBinState.fire_head`) leaves
+    its tail (:meth:`KeyedBinState.fire_tail`): everything the read-back
+    and the flatten need, so that the tail touches nothing an update
+    reads or writes and may run beside the next batches.
+
+    ``devs`` are the pick's outputs on the device, their copies to the
+    host already started (``idx2[2, npad]``, the counts, and the channel
+    block where channels ride the transfer), ``nnz`` how many of their
+    rows are live; the bin-sharded ring route reads back in the head and
+    leaves the flattened host ``cells`` instead.  ``slot_to_key`` is the
+    array the head saw (a grow replaces the state's, an insert writes
+    only past the slots that fired).  ``_d2h`` are the spans of the
+    fire's blocking read-backs so far (:func:`_readback`)."""
+
+    watermark: int
+    pane_ends: np.ndarray
+    slot_to_key: np.ndarray
+    nnz: int
+    devs: tuple = ()
+    cells: Optional[tuple] = None
+    _d2h: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
 
 
 class KeyedBinState:
@@ -1096,12 +1124,12 @@ class KeyedBinState:
             npad <<= 1
 
     def _emit_argmax(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int
-                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                np.ndarray]:
-        """Candidate-only emission: (key_idx, pane_idx, counts, empty
-        channel block) for cells at their pane's count extremum — a
-        ~1000x smaller readback (ties-per-pane instead of every
-        (key, pane) cell)."""
+                     ) -> Tuple[int, tuple]:
+        """Candidate-only emission, the head's half: scan, the live count
+        read back, the pick dispatched and its copies to the host started.
+        ``(nnz, (idx2, counts))`` for :meth:`_read_picked`: only the cells
+        at their pane's count extremum, a ~1000x smaller readback
+        (ties-per-pane instead of every (key, pane) cell)."""
         ring_j, ok_j = _h2d(ring, bin_ok)
         nk = _argmax_nnz_kernel(self.C, self.B, self.W, kpad,
                                 self._argmax_local)
@@ -1110,25 +1138,21 @@ class KeyedBinState:
         nnz = int(_readback(self, nnz_dev))  # waits for the whole scan
         perf.count("pane_emit_cells", nnz)
         if nnz == 0:
-            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
-                    np.zeros(0, np.int64),
-                    np.zeros((len(self._xfer_ch), 0)))
+            return 0, ()
         npad = _bucket(nnz, floor=8)
         gk = _argmax_gather_kernel(self.C, self.B, self.W, kpad, npad)
-        idx2_d, cnt_d = timed_device(gk, cnt_dev, sel_dev)
-        _prefetch_host(idx2_d, cnt_d)
-        idx2 = _readback(self, idx2_d)
-        return (idx2[0, :nnz].astype(np.int64),
-                idx2[1, :nnz].astype(np.int64),
-                _readback(self, cnt_d)[:nnz],
-                np.zeros((len(self._xfer_ch), nnz)))
+        devs = tuple(timed_device(gk, cnt_dev, sel_dev))
+        _prefetch_host(*devs)
+        return nnz, devs
 
     def _emit_compact(self, ring: np.ndarray, bin_ok: np.ndarray, kpad: int
-                      ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
-                                 np.ndarray]:
-        """(key_idx, pane_idx, counts, channel values [n_xfer, m]) for the
-        live cells only, compacted on device (row-major order — identical
-        to the dense path's np.nonzero order)."""
+                      ) -> Tuple[int, tuple]:
+        """The live cells only, compacted on the device (row-major order:
+        the dense path's ``np.nonzero`` order), the head's half: scan, the
+        live count read back (one scalar sizes the pick), the pick
+        dispatched on the planes as they are now and its copies to the
+        host started.  ``(nnz, (idx2, counts[, channels]))`` for
+        :meth:`_read_picked`."""
         ring_j, ok_j = _h2d(ring, bin_ok)
         ck = _emit_count_kernel(self.C, self.B, self.W, kpad)
         cnt_dev, live_dev, nnz_dev = timed_device(ck, self.counts, ring_j,
@@ -1136,21 +1160,29 @@ class KeyedBinState:
         nnz = int(_readback(self, nnz_dev))  # one scalar sizes phase 2
         perf.count("pane_emit_cells", nnz)
         if nnz == 0:
-            return (np.zeros(0, np.int64), np.zeros(0, np.int64),
-                    np.zeros(0, np.int64),
-                    np.zeros((len(self._xfer_ch), 0)))
+            return 0, ()
         gk = _emit_compact_kernel(self._ch_kinds, self.C, self.B, self.W,
                                   kpad, self._xfer_ch,
                                   _bucket(nnz, _EMIT_ROWS_FLOOR))
         idx2_d, cnt_d, ch_d = timed_device(gk, self.values, cnt_dev,
                                            live_dev, ring_j, ok_j)
-        _prefetch_host(idx2_d, cnt_d, ch_d)
-        idx2 = _readback(self, idx2_d)
-        return (idx2[0, :nnz].astype(np.int64),
-                idx2[1, :nnz].astype(np.int64),
-                _readback(self, cnt_d)[:nnz],
-                (_readback(self, ch_d)[:, :nnz] if self._xfer_ch
-                 else np.zeros((0, nnz))))
+        devs = (idx2_d, cnt_d) + ((ch_d,) if self._xfer_ch else ())
+        _prefetch_host(*devs)
+        return nnz, devs
+
+    def _read_picked(self, fire: PendingFire
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray,
+                                np.ndarray]:
+        """The tail's half of :meth:`_emit_compact` and
+        :meth:`_emit_argmax`: the blocking read-backs of the pick's
+        outputs.  (key_idx, pane_idx, counts, channel values [n_xfer,
+        nnz]); reads the handle only."""
+        n = fire.nnz
+        idx2 = _readback(fire, fire.devs[0])
+        return (idx2[0, :n].astype(np.int64), idx2[1, :n].astype(np.int64),
+                _readback(fire, fire.devs[1])[:n],
+                (_readback(fire, fire.devs[2])[:, :n] if len(fire.devs) > 2
+                 else np.zeros((len(self._xfer_ch), n))))
 
     def _ring_shards(self) -> int:
         nk = 1
@@ -1200,12 +1232,26 @@ class KeyedBinState:
 
     def fire_panes(self, watermark: int, final: bool = False
                    ) -> Optional[FiredPanes]:
-        """Emit all panes whose window end <= watermark.
+        """Emit all panes whose window end <= watermark: a fire's head and
+        its tail, one after the other.
 
         Pane with absolute end-bin e covers bins (e-W, e]; its window end time
         is (e+1)*slide.  Returns (keys, {agg_output: values}, window_end,
         counts, slots) flattened over (pane, key-with-data), or None.
         """
+        fire = self.fire_head(watermark, final)
+        return None if fire is None else self.fire_tail(fire)
+
+    def fire_head(self, watermark: int, final: bool = False
+                  ) -> Optional[PendingFire]:
+        """The half of a fire that reads or writes what a later update
+        reads or writes, so the half its caller waits for before the next
+        batch: the flush of the buffered updates, the pane arithmetic, the
+        scan and the read-back of its live count, the dispatch of the pick
+        (on the planes as they are now: the evict and the next update take
+        them donated) and of the evict, ``last_fired_pane`` and ``min_bin``
+        (an update drops its late rows by them).  Returns what
+        :meth:`fire_tail` needs, or None where no row fires."""
         if self.max_bin is None or self.next_slot == 0:
             return None
         if final:
@@ -1241,19 +1287,22 @@ class KeyedBinState:
         # what the scan has to read whatever implements it: the bins of
         # the occupied slots' firing panes
         perf.count("pane_scan_cells", self.next_slot * k * self.W)
-        compact = None
+        devs, cells = (), None
         if self._use_ring():
-            outs, cnts = self._emit_ring(pane_ends, k)
+            # the bin-sharded sweep reads back here, whole in the head:
+            # its reads are sized by the open span and no cell runs it
+            cells = self._flatten_dense(*self._emit_ring(pane_ends, k), k)
+            nnz = len(cells[0])
         elif self._argmax_candidates():
             # candidate-only emission: every output column derives from
             # the counts plane (bare COUNT(*) aggs), so nothing else
             # needs to ride the transfer; with f64 channels present the
             # compacted path runs and the downstream argmax stage filters
-            compact = self._emit_argmax(ring, bin_ok, kpad)
+            nnz, devs = self._emit_argmax(ring, bin_ok, kpad)
         else:
             # live cells only, compacted on the device: a readback sized
             # by a bucket of the live count, at any density
-            compact = self._emit_compact(ring, bin_ok, kpad)
+            nnz, devs = self._emit_compact(ring, bin_ok, kpad)
 
         self.last_fired_pane = last_pane
         # evict bins that no future pane needs: abs bins <= last_pane - W + 1
@@ -1272,14 +1321,24 @@ class KeyedBinState:
                     in_total=False)
             self.min_bin = new_min
 
-        _fire_done(self, int(watermark))
-        # flatten (key, pane) pairs with data
-        if compact is not None:
-            key_idx, pane_idx, cnt_sel, ch_sel = compact
-        else:
-            key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
-                outs, cnts, k)
-        return self._fired(key_idx, pane_idx, cnt_sel, ch_sel, pane_ends)
+        fire = PendingFire(int(watermark), pane_ends, self.slot_to_key,
+                           nnz, devs, cells, self._d2h)
+        self._d2h = []
+        if nnz == 0:
+            _fire_done(fire, fire.watermark)
+            return None
+        return fire
+
+    def fire_tail(self, fire: PendingFire) -> FiredPanes:
+        """The half of a fire that touches its :class:`PendingFire` alone:
+        the blocking read-backs of the pick's outputs, the fire's accounts
+        and the flatten.  Nothing here reads the planes, the directory or
+        a field an update sets, so it may run while the next batches are
+        inserted and applied."""
+        cells = fire.cells if fire.cells is not None else \
+            self._read_picked(fire)
+        _fire_done(fire, fire.watermark)
+        return self._fired(*cells, fire.pane_ends, fire.slot_to_key)
 
     def _c_slice(self) -> int:
         """Key rows a dense read transfers: the power-of-two bucket of the
@@ -1346,15 +1405,18 @@ class KeyedBinState:
 
     def _fired(self, key_idx: np.ndarray, pane_idx: np.ndarray,
                cnt_sel: np.ndarray, ch_sel: np.ndarray,
-               pane_ends: np.ndarray) -> Optional[FiredPanes]:
-        """The fired value of flattened cells (shared by fire_panes and
+               pane_ends: np.ndarray, slot_to_key: np.ndarray
+               ) -> Optional[FiredPanes]:
+        """The fired value of flattened cells (shared by fire_tail and
         drain_deltas).  ``key_idx`` is the cell's slot on every route
         (the compaction, the argmax pick and the dense flatten index the
-        planes by slot), so it goes to the operator as it is."""
+        planes by slot), so it goes to the operator as it is;
+        ``slot_to_key`` is the directory's array as the pass that read the
+        cells saw it."""
         if len(key_idx) == 0:
             return None
         window_end = (pane_ends[pane_idx] + 1) * self.slide
-        return FiredPanes(self.slot_to_key[key_idx],
+        return FiredPanes(slot_to_key[key_idx],
                           self._out_cols(cnt_sel, ch_sel), window_end,
                           cnt_sel, key_idx)
 
@@ -1406,7 +1468,8 @@ class KeyedBinState:
 
         key_idx, pane_idx, cnt_sel, ch_sel = self._flatten_dense(
             outs, cnts, k)
-        return self._fired(key_idx, pane_idx, cnt_sel, ch_sel, pane_ends)
+        return self._fired(key_idx, pane_idx, cnt_sel, ch_sel, pane_ends,
+                           self.slot_to_key)
 
     # -- checkpoint ---------------------------------------------------------
     #

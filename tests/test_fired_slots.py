@@ -201,7 +201,7 @@ def test_sql_hop_count_rows_carry_their_own_key_column(
         inner = BinAggOperator._fired_batch
         seen = []
 
-        def guarded(self, fired):
+        def guarded(self, fired, lat=None):
             assert isinstance(self.state, KeyedBinState)
 
             def refuse(*_a, **_kw):
@@ -209,7 +209,7 @@ def test_sql_hop_count_rows_carry_their_own_key_column(
 
             with pytest.MonkeyPatch.context() as mp:
                 mp.setattr(np, "searchsorted", refuse)
-                out = inner(self, fired)
+                out = inner(self, fired, lat)
             seen.append(len(fired.slots))
             return out
 

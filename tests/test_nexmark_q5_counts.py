@@ -217,7 +217,9 @@ def test_emit_compact_at_w5_equals_the_planes(with_channels):
     ring = np.array([[14, 15, 0, 1, 2], [15, 0, 1, 2, 3]], np.int32)
     bin_ok = np.ones((2, 5), bool)
     bin_ok[0, 0] = False
-    key_idx, pane_idx, cnt, ch = st._emit_compact(ring, bin_ok, 2)
+    nnz, devs = st._emit_compact(ring, bin_ok, 2)  # the head's half
+    key_idx, pane_idx, cnt, ch = st._read_picked(
+        keyed_bins.PendingFire(0, None, None, nnz, devs))
 
     def reduce(plane, how, empty):
         rows = np.where(bin_ok[:, :, None], plane[ring], empty)

@@ -700,8 +700,10 @@ def test_compact_emission_matches_dense(monkeypatch):
         outs = keyed_bins._readback(self, outs)[:, :used]
         cnts = keyed_bins._readback(self, cnts)[:used]
         key_idx, pane_idx = np.nonzero(cnts)
-        return (key_idx, pane_idx, cnts[key_idx, pane_idx],
-                outs[:, key_idx, pane_idx])
+        # the head's half hands over (nnz, (idx2, counts, channels))
+        return len(key_idx), (np.stack([key_idx, pane_idx]),
+                              cnts[key_idx, pane_idx],
+                              outs[:, key_idx, pane_idx])
 
     def run(mode):
         if mode == "dense":

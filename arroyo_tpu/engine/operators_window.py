@@ -162,6 +162,17 @@ class _SlotKeyValues:
                 self.cols[k[3:]] = v.copy()
 
 
+# what ``perf.run_offloaded`` calls the parts of a fire's two hops in the
+# flight recorder: the pick-up and the way back share a name between the
+# head and the tail (``args.part`` tells them apart); the tail's run is
+# ``window.fire.d2h`` and the flatten, and has no span of its own
+_HEAD_HOP = {"cat": "window", "queue": "window.fire.hop",
+             "run": "window.fire.head.run",
+             "resume": "window.fire.loop_wait"}
+_TAIL_HOP = {"cat": "window", "queue": "window.fire.hop",
+             "resume": "window.fire.loop_wait"}
+
+
 class BinAggOperator(Operator):
     """Two-phase binned window aggregate over device state (sliding or
     tumbling; SURVEY kernel #2)."""
@@ -256,16 +267,18 @@ class BinAggOperator(Operator):
         if hasattr(self.state, "warm_fire"):  # not the mesh state's
             self.state.warm_fire()
 
-    async def _run_state(self, fn, *args):
+    async def _run_state(self, fn, *args, span=None, span_args=None):
         """``fn(*args)`` of the state, in an executor thread where
         transfers block (``_offload_transfers``).  Safe to offload: the
         serial path awaits it, and a fire's tail touches its handle
-        alone."""
+        alone.  ``span`` / ``span_args`` are ``perf.run_offloaded``'s: a
+        fire names the parts of its hops, an update does not."""
         if self._offload_transfers():
             from ..obs import perf
 
             return await perf.run_offloaded(asyncio.get_running_loop(),
-                                            fn, *args)
+                                            fn, *args, span=span,
+                                            span_args=span_args)
         return fn(*args)
 
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
@@ -307,7 +320,14 @@ class BinAggOperator(Operator):
         fires nothing (sources send one a batch) is forwarded from behind
         the tail in flight without waiting for it here; ``settle`` awaits
         them all.  Span ``window.fire.hold`` is the head, ``window.fire``
-        a fire's watermark in to its batch sent on."""
+        a fire's watermark in to its batch sent on.  Where the halves hop
+        to an executor thread, three more spans say what a fire waited
+        for besides its own work (``_HEAD_HOP``, ``_TAIL_HOP``):
+        ``window.fire.head.run`` the executor's part of the head,
+        ``window.fire.hop`` the pick-up of the head's and of the tail's
+        job (``args.part``), ``window.fire.loop_wait`` every stretch in
+        which the fire waited for the loop: each hop's way back into its
+        coroutine and the tail task's wait for its first step."""
         from ..obs import tracing
         from ..types import MAX_TIMESTAMP
 
@@ -321,7 +341,9 @@ class BinAggOperator(Operator):
             tail = getattr(self.state, "fire_tail", None)
             fire = await self._run_state(
                 self.state.fire_panes if tail is None
-                else self.state.fire_head, watermark, final)
+                else self.state.fire_head, watermark, final,
+                span=dict(_HEAD_HOP, tid=tid),
+                span_args=dict(args, part="head"))
             if fire is None:
                 tracing.record_span("window.fire", "window", t0,
                                     tracing.now_us() - t0, tid=tid,
@@ -341,7 +363,8 @@ class BinAggOperator(Operator):
                 await self._finish_fire(*rest)
             else:
                 self._tail = asyncio.ensure_future(self._beside(
-                    None, self._finish_fire, *rest))
+                    None, self._finish_fire, *rest,
+                    queued=(tracing.now_us(), tid, args)))
 
     async def _finish_fire(self, fire, tail, lat, forward: Message,
                            t0: float, ctx: Context) -> None:
@@ -353,7 +376,9 @@ class BinAggOperator(Operator):
         watermark = int(forward.watermark.time)
         try:
             if tail is not None:
-                fire = await self._run_state(tail, fire)
+                fire = await self._run_state(
+                    tail, fire, span=dict(_TAIL_HOP, tid=tracing.ctx_tid(ctx)),
+                    span_args={"watermark": watermark, "part": "tail"})
             await self._emit(fire, ctx, lat, watermark)
         finally:
             # flight-recorder tap: pane firing is where windowed pipelines
@@ -364,12 +389,20 @@ class BinAggOperator(Operator):
                                 args={"watermark": watermark})
         await ctx.broadcast(forward)
 
-    async def _beside(self, prev, fn, *args) -> None:
+    async def _beside(self, prev, fn, *args, queued=None) -> None:
         """``fn(*args)`` as a task of its own, after the task ``prev``:
         its profiler frames on a stack of their own, under a ``watermark``
-        phase as the runner opens around ``handle_watermark``."""
-        from ..obs import perf, profiler
+        phase as the runner opens around ``handle_watermark``.  A fire's
+        tail hands in ``queued``, (when its task was made, trace track,
+        span args): how long it waited for its first step is a
+        ``window.fire.loop_wait`` span."""
+        from ..obs import perf, profiler, tracing
 
+        if queued is not None:
+            t_us, tid, span_args = queued
+            tracing.record_span("window.fire.loop_wait", "window", t_us,
+                                tracing.now_us() - t_us, tid=tid,
+                                args=dict(span_args, part="start"))
         if prev is not None:
             await prev
         profiler.detach_stack()
@@ -975,13 +1008,8 @@ class SessionWindowOperator(Operator):
         await ctx.collect(out)
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
-        from ..obs import tracing
-
-        with tracing.span("window.session_fire", "window",
-                          tid=tracing.ctx_tid(ctx),
-                          args={"watermark": int(watermark)}):
-            self._collect_expired(watermark, ctx)
-            await self._flush_fires(ctx)
+        self._collect_expired(watermark, ctx)
+        await self._flush_fires(ctx)
         # evict data older than every live session start
         if self._device_state:
             ls = self.windows.min_live_start()
@@ -2162,12 +2190,7 @@ class NonWindowAggOperator(Operator):
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
         if self.flush_key is not None:
-            from ..obs import tracing
-
-            with tracing.span("window.flush_ready", "window",
-                              tid=tracing.ctx_tid(ctx),
-                              args={"watermark": int(watermark)}):
-                await self._flush_ready(watermark, ctx)
+            await self._flush_ready(watermark, ctx)
         await ctx.broadcast(Message.wm(Watermark.event_time(watermark)))
 
     async def _flush_ready(self, watermark: int, ctx: Context) -> None:

@@ -115,7 +115,7 @@ def in_phase(phase: str):
     return wrap
 
 
-async def run_offloaded(loop, fn, *args):
+async def run_offloaded(loop, fn, *args, span=None, span_args=None):
     """``loop.run_in_executor`` with contextvars propagated: executor
     threads don't inherit the caller's context, so kernels dispatched
     from an offloaded transfer would otherwise bypass the active task's
@@ -123,16 +123,62 @@ async def run_offloaded(loop, fn, *args):
     backends where offload is enabled.  The await is an ``offload_wait``
     wait child of the caller's profiler frame: what the executor thread
     does is accounted on its own stack, and never charged to the
-    caller's ``proc``/``watermark`` as well."""
+    caller's ``proc``/``watermark`` as well.
+
+    Where the await goes is counted on every hop (four clock reads, four
+    counters): ``offload_us.queue``, from the submit on the loop thread
+    to the executor thread's first instruction (the pool's pick-up, a
+    thread's start, its first wait for the GIL); ``offload_us.run``, the
+    executor's own wall around ``fn``; ``offload_us.resume``, from the
+    executor's last instruction to the loop thread back in this coroutine
+    (``call_soon_threadsafe``, the loop's wake-up, and every task that
+    held the loop before this one got it back); ``offload_hops``.
+
+    ``span`` names flight-recorder spans for those parts, ``{"queue" |
+    "run" | "resume": span name, "cat": category, "tid": trace track}``,
+    recorded with ``span_args``; a part it leaves out, and every part of
+    a hop that names none (an update a batch would churn the ring), stays
+    off the ring."""
     ctx = contextvars.copy_context()
+    t_start = t_end = 0
+
+    def job():
+        nonlocal t_start, t_end
+        t_start = time.perf_counter_ns()
+        try:
+            return ctx.run(fn, *args)
+        finally:
+            t_end = time.perf_counter_ns()
+
     prof = _profiler.active()
     frame = (prof.begin(active_operator_id() or "offload", "offload_wait",
                         wait=True) if prof is not None else None)
+    t_submit = time.perf_counter_ns()
     try:
-        return await loop.run_in_executor(None, lambda: ctx.run(fn, *args))
+        return await loop.run_in_executor(None, job)
     finally:
+        t_resume = time.perf_counter_ns()
         if frame is not None:
             prof.end(frame)
+        if t_end:  # not cancelled before the executor was through
+            count("offload_us.queue", (t_start - t_submit) // 1000)
+            count("offload_us.run", (t_end - t_start) // 1000)
+            count("offload_us.resume", (t_resume - t_end) // 1000)
+            count("offload_hops")
+            if span:
+                from . import tracing
+
+                to_us = tracing.now_us() - time.perf_counter_ns() / 1e3
+                cat = span.get("cat", "offload")
+                tid = span.get("tid") or active_task_id()
+                for part, t0, t1 in (("queue", t_submit, t_start),
+                                     ("run", t_start, t_end),
+                                     ("resume", t_end, t_resume)):
+                    if part in span:
+                        tracing.record_span(span[part], cat,
+                                            to_us + t0 / 1e3,
+                                            (t1 - t0) / 1e3, tid=tid,
+                                            args=span_args)
 
 
 def kernel_name(name: str):
@@ -155,13 +201,18 @@ def counter(key: str) -> int:
     """Counter read (plain counts like ``kernel_dispatches`` — the number
     of device-kernel dispatches made through :func:`timed_device`, which
     bench.py turns into dispatches-per-event — and the microsecond sums
-    ``wait_us.<phase>``)."""
+    ``wait_us.<phase>``, ``cpu_us.<phase>`` and ``offload_us.<part>``)."""
     return _COUNTERS.get(key, 0)
 
 
 def count(key: str, n: int = 1) -> None:
     """Increment a plain process-wide counter (join-state merge/spill
-    accounting, bench attribution).  Cheap: one dict update."""
+    accounting, bench attribution).  Cheap: one dict update, and no lock:
+    a key has one writing thread (the loop thread's ``offload_us.*`` and
+    ``wait_us.*``, a fire's counters on whichever thread runs it, one
+    hop at a time), but for ``cpu_us.<phase>``, which the profiler bumps
+    from the loop, the source and the executor thread and therefore only
+    under its own lock (``Profiler.end``)."""
     _COUNTERS[key] = _COUNTERS.get(key, 0) + n
 
 

@@ -73,6 +73,17 @@ frames also add their microseconds to the ``perf`` counters
 ``wait_us.send_wait`` / ``wait_us.offload_wait``, summed over operators
 (:data:`COUNTED_WAITS`).
 
+Every frame also reads the **CPU seconds of its thread**
+(``time.thread_time_ns``) where it reads the wall clock, and a work
+frame's CPU is exclusive exactly as its wall is (a wait child takes with
+it the thread CPU that passed while it was open: on the loop thread that
+is other tasks' work).  Wall less CPU is what the thread spent off the
+CPU inside the phase: blocked on the device (``d2h_wait``), waiting for
+the GIL behind another thread, or without a core.  The CPU microseconds
+of every work phase also land on the ``perf`` counter ``cpu_us.<phase>``,
+summed over operators, and the loop thread's whole CPU, frames or not, on
+``cpu_us.thread.loop`` (the watchdog's ticker adds its own thread's).
+
 Accounting model
 ----------------
 
@@ -226,8 +237,9 @@ def detach_stack() -> None:
 
 
 # frame layout: [op_id, phase, is_wait, t0, child_inclusive_secs,
-#                open TraceAnnotation or None]
-_OP, _PHASE, _WAIT, _T0, _CHILD, _ANN = range(6)
+#                open TraceAnnotation or None, thread CPU ns at begin,
+#                child_inclusive_cpu_ns]
+_OP, _PHASE, _WAIT, _T0, _CHILD, _ANN, _C0, _CCHILD = range(8)
 
 
 class Profiler:
@@ -243,11 +255,18 @@ class Profiler:
         # exclusive work seconds by the thread that ran the frame: the
         # check of "per thread, work never exceeds the thread's wall"
         self._thread_work: Dict[str, float] = {}
+        # the CPU twins of ``_work`` and ``_thread_work``: exclusive
+        # seconds of ``time.thread_time_ns`` (frames only)
+        self._cpu: Dict[Tuple[str, str], float] = {}
+        self._thread_cpu: Dict[str, float] = {}
         self._t0 = time.perf_counter()
         self.watchdog = LoopWatchdog(job_id=job_id)
         from jax.profiler import TraceAnnotation
 
+        from . import perf  # perf imports this module
+
         self._annotation = TraceAnnotation
+        self._count = perf.count
 
     # -- hot-path API ------------------------------------------------------
 
@@ -262,7 +281,10 @@ class Profiler:
     def begin(self, op_id: str, phase: str, wait: bool = False) -> list:
         """Open a phase frame; returns the token for :meth:`end`.  Work
         frames must not span an await except through nested wait
-        children (the site discipline the accounting model rests on)."""
+        children (the site discipline the accounting model rests on).
+        A frame ends on the thread that began it: its stack is that
+        thread's (``_frames``), and its CPU is the difference of two
+        readings of one thread's clock."""
         frames = self._frames()
         ann = None
         if wait:
@@ -274,11 +296,13 @@ class Profiler:
         else:
             ann = self._annotation(phase, op=op_id)
             ann.__enter__()
-        f = [op_id, phase, wait, time.perf_counter(), 0.0, ann]
+        f = [op_id, phase, wait, time.perf_counter(), 0.0, ann,
+             time.thread_time_ns(), 0]
         frames.append(f)
         return f
 
     def end(self, f: list) -> None:
+        cpu = time.thread_time_ns() - f[_C0]  # read inside the wall span
         now = time.perf_counter()
         if f[_ANN] is not None:
             f[_ANN].__exit__(None, None, None)
@@ -300,11 +324,11 @@ class Profiler:
             excl = 0.0
         if frames:
             frames[-1][_CHILD] += dt
+            # a wait child's share is what the thread burnt for others
+            frames[-1][_CCHILD] += cpu
         if f[_WAIT]:
             if f[_PHASE] in COUNTED_WAITS:
-                from . import perf  # perf imports this module
-
-                perf.count("wait_us." + f[_PHASE], int(dt * 1e6))
+                self._count("wait_us." + f[_PHASE], int(dt * 1e6))
             if not any(g[_WAIT] for g in frames):
                 # back from the await: the work phases go on
                 for g in frames:
@@ -319,11 +343,19 @@ class Profiler:
                 name = threading.current_thread().name
                 self._thread_work[name] = self._thread_work.get(
                     name, 0.0) + excl
+                excl_cpu = max(cpu - f[_CCHILD], 0)
+                secs = excl_cpu / 1e9
+                self._cpu[key] = self._cpu.get(key, 0.0) + secs
+                self._thread_cpu[name] = self._thread_cpu.get(
+                    name, 0.0) + secs
+                # three threads write these keys: only under this lock
+                self._count("cpu_us." + f[_PHASE], excl_cpu // 1000)
 
     def add(self, op_id: str, phase: str, secs: float,
             wait: bool = False, count: int = 1) -> None:
         """Direct accounting for sites that measure their own span and
-        cannot nest (the task loop's input waits)."""
+        cannot nest (the task loop's input waits); knows no thread, so
+        records no CPU."""
         key = (op_id, phase)
         with self._lock:
             d = self._waits if wait else self._work
@@ -348,6 +380,8 @@ class Profiler:
             self._waits.clear()
             self._counts.clear()
             self._thread_work.clear()
+            self._cpu.clear()
+            self._thread_cpu.clear()
             self._t0 = time.perf_counter()
         self.watchdog.reset()
 
@@ -359,19 +393,17 @@ class Profiler:
         with self._lock:
             return dict(self._waits)
 
-    def thread_work_snapshot(self) -> Dict[str, float]:
-        """{thread name: exclusive work seconds of the frames it ran}
-        (frames only: :meth:`add` knows no thread)."""
-        with self._lock:
-            return dict(self._thread_work)
-
     def snapshot(self) -> Dict[str, Any]:
         """Full structured snapshot: per-operator work/wait phase maps,
-        job-level phase totals, wall since arm/reset, watchdog stats."""
+        job-level phase totals (wall, CPU, and wall less CPU), exclusive
+        work seconds by the thread that ran the frames (``threads``,
+        ``threads_cpu``; frames only: :meth:`add` knows no thread), wall
+        since arm/reset, watchdog stats."""
         with self._lock:
             work, waits = dict(self._work), dict(self._waits)
             counts = dict(self._counts)
             threads = dict(self._thread_work)
+            cpu, threads_cpu = dict(self._cpu), dict(self._thread_cpu)
             wall = time.perf_counter() - self._t0
         ops: Dict[str, Dict[str, Any]] = {}
         phases: Dict[str, float] = {}
@@ -384,11 +416,18 @@ class Profiler:
             ops.setdefault(op, {"phases": {}, "waits": {}})[
                 "waits"][ph] = round(secs, 6)
             wait_totals[ph] = wait_totals.get(ph, 0.0) + secs
+        cpu_phases: Dict[str, float] = {}
+        for (_op, ph), secs in cpu.items():
+            cpu_phases[ph] = cpu_phases.get(ph, 0.0) + secs
         attributed = sum(phases.values())
         return {
             "job_id": self.job_id,
             "wall_secs": round(wall, 6),
             "phases": {k: round(v, 6) for k, v in sorted(phases.items())},
+            "cpu_phases": {k: round(v, 6) for k, v in sorted(
+                cpu_phases.items())},
+            "off_cpu_phases": {k: round(max(v - cpu_phases.get(k, 0.0), 0.0),
+                                        6) for k, v in sorted(phases.items())},
             "waits": {k: round(v, 6) for k, v in sorted(
                 wait_totals.items())},
             "attributed_secs": round(attributed, 6),
@@ -398,6 +437,8 @@ class Profiler:
                 max(1.0 - attributed / wall, 0.0), 4) if wall > 0 else 0.0,
             "operators": {op: v for op, v in sorted(ops.items())},
             "threads": {t: round(v, 6) for t, v in sorted(threads.items())},
+            "threads_cpu": {t: round(v, 6) for t, v in sorted(
+                threads_cpu.items())},
             "counts": {f"{op}/{ph}": n for (op, ph), n in sorted(
                 counts.items())},
             "watchdog": self.watchdog.stats(),
@@ -426,7 +467,11 @@ class LoopWatchdog:
 
     The on-loop ticker (:meth:`run`) sleeps ``interval`` and records how
     late the loop woke it — the scheduling lag every other coroutine on
-    that loop also experiences.  A daemon sampler thread watches the
+    that loop also experiences — and adds the CPU its thread burnt since
+    the tick before to the ``perf`` counter ``cpu_us.thread.loop``: the
+    loop thread on the CPU, inside a profiler frame or not (asyncio's own
+    turns, a caller polling on the same loop); the rest of the wall it was
+    parked in ``select`` or waiting for the GIL.  A daemon sampler thread watches the
     ticker's heartbeat; when it stalls past ``stall_threshold`` the
     thread snapshots the loop thread's current Python stack, so the
     blocking call is named **while it is still blocking** (the runtime
@@ -476,6 +521,7 @@ class LoopWatchdog:
                              daemon=True).start()
         import asyncio
 
+        from . import perf
         from .metrics import event_loop_lag_gauge, event_loop_stalls_counter
 
         gauge_p50 = event_loop_lag_gauge(self.job_id, "p50")
@@ -483,12 +529,16 @@ class LoopWatchdog:
         stalls_c = event_loop_stalls_counter(self.job_id)
         reported_stalls = 0
         last_gauge = 0.0
+        cpu0 = time.thread_time_ns()
         try:
             while True:
                 t0 = time.perf_counter()
                 await asyncio.sleep(self.interval)
                 now = time.perf_counter()
                 self._last_tick = now
+                cpu = time.thread_time_ns()
+                perf.count("cpu_us.thread.loop", (cpu - cpu0) // 1000)
+                cpu0 = cpu
                 self.lags.append(max(now - t0 - self.interval, 0.0))
                 if now - last_gauge >= 1.0:
                     last_gauge = now

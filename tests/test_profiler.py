@@ -387,16 +387,23 @@ def q5_offloaded():
     from arroyo_tpu.sql import plan_sql
 
     admit = KeyedBinState._admit_bins
+    run_state = BinAggOperator._run_state
+    hops = []  # the state method behind every executor hop, in order
 
     def slow_admit(self, timestamps):
         time.sleep(ADMIT_SLEEP_S)
         return admit(self, timestamps)
+
+    async def counted_run_state(self, fn, *args, **kw):
+        hops.append(fn.__name__)
+        return await run_state(self, fn, *args, **kw)
 
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("ARROYO_MESH", "off")
         mp.setenv("STATE_CAPACITY", "256")  # the keys outgrow it: _grow
         config.reset_config()
         mp.setattr(BinAggOperator, "_offload_transfers", lambda self: True)
+        mp.setattr(BinAggOperator, "_run_state", counted_run_state)
         mp.setattr(KeyedBinState, "_admit_bins", slow_admit)
         prog = plan_sql(Q5_SQL)
         prof = profiler.arm("q5-offloaded")
@@ -407,9 +414,14 @@ def q5_offloaded():
         t0 = time.perf_counter()
         LocalRunner(prog).run()
         wall = time.perf_counter() - t0
+        snap = prof.snapshot()
         run = {"wall": wall, "work": prof.work_snapshot(),
                "waits": prof.wait_snapshot(),
-               "threads": prof.thread_work_snapshot(),
+               "threads": snap["threads"],
+               "threads_cpu": snap["threads_cpu"],
+               "cpu_phases": snap["cpu_phases"],
+               "off_cpu_phases": snap["off_cpu_phases"],
+               "frames": snap["counts"], "hops": hops,
                "spans": tracing.spans(),
                "counter": dict(perf._COUNTERS),
                "rows": sum(len(b) for b in sink_output("results"))}
@@ -457,6 +469,9 @@ def test_q5_records_work_phase(q5_offloaded, phase):
     "pane_update_cells", "pane_update_pad_cells", "keys_inserted",
     "state_grows", "window_fires", "d2h_syncs", "d2h_bytes",
     "wait_us.send_wait", "wait_us.offload_wait",
+    "offload_us.queue", "offload_us.run", "offload_us.resume",
+    "offload_hops", "cpu_us.dir_insert", "cpu_us.preagg",
+    "cpu_us.source_decode", "cpu_us.thread.loop",
     "kernel_dispatches.bins_update", "kernel_dispatches.bins_argmax_nnz",
     "kernel_dispatches.bins_argmax_gather", "kernel_dispatches.bins_evict"])
 def test_q5_counts(q5_offloaded, counter):
@@ -526,3 +541,281 @@ def test_work_frames_are_mirrored_as_trace_annotations(monkeypatch):
         ("exit", "proc"),  # the wait begins: the thread leaves `proc`
         ("new", "proc", {"op": "op"}), ("enter", "proc"),  # and is back
         ("exit", "proc")], log
+
+
+# -- CPU seconds beside wall seconds, and the executor hop in three parts --
+
+def _spin(secs):
+    t_end = time.perf_counter() + secs
+    while time.perf_counter() < t_end:
+        pass
+
+
+def _cpu_and_wall(prof, key):
+    """(exclusive CPU, exclusive wall) seconds of one (op, phase)."""
+    snap = prof.snapshot()
+    assert snap["phases"][key[1]] == pytest.approx(
+        prof.work_snapshot()[key], abs=1e-6)
+    return snap["cpu_phases"][key[1]], snap["phases"][key[1]]
+
+
+def _burn(cpu_secs):
+    """Compute until this thread has burnt ``cpu_secs`` of CPU: the same
+    work whatever a loaded machine does to the wall."""
+    t_end = time.thread_time() + cpu_secs
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_spinning_frame_reads_cpu_near_wall():
+    """A frame that computes for 50 ms of CPU reads them, to 20 %, and
+    never more CPU than wall.  On a quiet machine its wall is the same
+    50 ms; neighbours stretch the wall alone, which is what the pair of
+    readings is for."""
+    prof = profiler.arm("cpu")
+    prof.reset()
+    t0 = time.perf_counter()
+    with prof.phase("op", "proc"):
+        _burn(0.05)
+    around = time.perf_counter() - t0
+    cpu, wall = _cpu_and_wall(prof, ("op", "proc"))
+    assert 0.05 <= cpu <= 0.06, (cpu, wall)
+    assert cpu <= wall + 1e-4 and wall <= around, (cpu, wall, around)
+    assert prof.snapshot()["off_cpu_phases"]["proc"] == pytest.approx(
+        wall - cpu, abs=1e-5)
+
+
+def test_sleeping_frame_reads_no_cpu():
+    prof = profiler.arm("cpu")
+    prof.reset()
+    with prof.phase("op", "d2h_wait"):
+        time.sleep(0.05)
+    cpu, wall = _cpu_and_wall(prof, ("op", "d2h_wait"))
+    assert wall >= 0.045 and cpu < 0.005, (cpu, wall)
+    assert prof.snapshot()["off_cpu_phases"]["d2h_wait"] >= 0.04
+
+
+def test_cpu_nesting_is_exclusive():
+    """A parent that computes around a child that sleeps: the child's span
+    leaves the parent's wall and the child's CPU leaves the parent's CPU,
+    so both stay exclusive, by thread as by phase."""
+    prof = profiler.arm("cpu")
+    prof.reset()
+    t0 = time.perf_counter()
+    outer = prof.begin("op", "preagg")
+    _burn(0.03)
+    with prof.phase("op", "d2h_wait"):
+        time.sleep(0.03)
+    prof.end(outer)
+    around = time.perf_counter() - t0
+    snap = prof.snapshot()
+    cpu, wall = snap["cpu_phases"], snap["phases"]
+    assert 0.03 <= cpu["preagg"] <= 0.036, cpu
+    assert cpu["preagg"] <= wall["preagg"] + 1e-4 <= around - 0.025, \
+        (cpu, wall, around)
+    assert wall["d2h_wait"] >= 0.025 and cpu["d2h_wait"] < 0.005, (cpu, wall)
+    (name, secs), = snap["threads_cpu"].items()
+    assert name in snap["threads"]
+    assert secs == pytest.approx(cpu["preagg"] + cpu["d2h_wait"], abs=1e-5)
+
+
+def test_awaited_wait_child_takes_the_loops_cpu_with_it():
+    """While a work frame's wait child is open the loop thread burns CPU
+    for another task: none of it is the work frame's."""
+    prof = profiler.arm("cpu")
+    prof.reset()
+
+    async def waiter():
+        outer = prof.begin("op", "proc")
+        wait = prof.begin("op", "offload_wait", wait=True)
+        await asyncio.sleep(0.06)
+        prof.end(wait)
+        prof.end(outer)
+
+    async def burner():
+        await asyncio.sleep(0.005)  # the waiter is inside its await
+        _spin(0.04)
+
+    async def scenario():
+        await asyncio.gather(waiter(), burner())
+
+    asyncio.run(scenario())
+    snap = prof.snapshot()
+    assert snap["waits"]["offload_wait"] >= 0.05, snap
+    assert snap["cpu_phases"]["proc"] < 0.005, snap["cpu_phases"]
+    assert snap["phases"]["proc"] < 0.005, snap["phases"]
+    # a wait is no work: its CPU, the burner's, is in no table
+    assert "offload_wait" not in snap["cpu_phases"]
+
+
+def test_cpu_counters_equal_the_cpu_table(q5_offloaded):
+    """``cpu_us.<phase>`` is the CPU table summed over operators (to the
+    microsecond a frame's truncation loses), for every work phase that
+    ran; CPU never exceeds wall, by phase or by thread; and the planted
+    sleeps of ``preagg`` read as wall without CPU."""
+    c, cpu = q5_offloaded["counter"], q5_offloaded["cpu_phases"]
+    wall = _by_phase(q5_offloaded["work"])
+    frames = {}
+    for key, n in q5_offloaded["frames"].items():
+        frames[key.split("/")[-1]] = frames.get(key.split("/")[-1], 0) + n
+    assert set(cpu) == set(wall) and "preagg" in cpu, (cpu, wall)
+    for phase, secs in cpu.items():
+        assert phase in profiler.WORK_PHASES
+        assert abs(c["cpu_us." + phase] - secs * 1e6) <= frames[phase] + 1
+        assert secs <= wall[phase] + 1e-4 * frames[phase], (phase, cpu, wall)
+    assert not any(k.startswith("cpu_us.") and k[7:] not in cpu
+                   and k != "cpu_us.thread.loop" for k in c), sorted(c)
+    for name, secs in q5_offloaded["threads_cpu"].items():
+        assert secs <= q5_offloaded["threads"][name] + 1e-2, name
+    batches = 240000 // 8192
+    assert q5_offloaded["off_cpu_phases"]["preagg"] >= \
+        0.9 * batches * ADMIT_SLEEP_S, q5_offloaded["off_cpu_phases"]
+    # the loop thread's own clock holds at least what its frames burnt
+    assert c["cpu_us.thread.loop"] <= q5_offloaded["wall"] * 1e6
+
+
+def test_off_path_counts_no_cpu():
+    """Disarmed: no frame, so no ``cpu_us.*`` counter and no ticker."""
+    from arroyo_tpu.obs import perf
+
+    assert profiler.active() is None
+    perf.reset()
+    _run_pipeline()
+    assert not [k for k in perf._COUNTERS
+                if k.startswith(("cpu_us.", "wait_us."))], perf._COUNTERS
+
+
+def test_offload_parts_sum_to_the_wait(q5_offloaded):
+    """queue + run + resume is the ``offload_wait`` frame's span, hop for
+    hop: every update, every watermark's head and every fire's tail."""
+    c, hops = q5_offloaded["counter"], q5_offloaded["hops"]
+    parts = (c["offload_us.queue"] + c["offload_us.run"]
+             + c["offload_us.resume"])
+    assert parts == pytest.approx(c["wait_us.offload_wait"], rel=0.02)
+    assert parts <= c["wait_us.offload_wait"]
+    assert c["offload_hops"] == len(hops)
+    assert set(hops) == {"update", "fire_head", "fire_tail"}, set(hops)
+    assert hops.count("update") >= 240000 // 8192
+    assert hops.count("fire_tail") == c["window_fires"]
+    # the executor's own run holds the planted sleeps
+    assert c["offload_us.run"] >= 0.9 * hops.count("update") * \
+        ADMIT_SLEEP_S * 1e6
+
+
+def _window_spans(run):
+    by_name = {}
+    for name, _cat, start, dur, _pid, tid, args in run["spans"]:
+        if name.startswith("window.fire"):
+            by_name.setdefault(name, []).append((start, dur, tid, args))
+    return by_name
+
+
+@pytest.mark.parametrize("child, parts", [
+    ("window.fire.head.run", ("head",)),
+    ("window.fire.hop", ("head", "tail")),
+    ("window.fire.loop_wait", ("head", "start", "tail"))])
+def test_hop_spans_lie_inside_their_fire(q5_offloaded, child, parts):
+    """The parts of a fire's hops share their ``window.fire``'s watermark
+    and lie inside it: one of each ``part`` per hop of that kind."""
+    by_name = _window_spans(q5_offloaded)
+    fires = {}
+    for start, dur, tid, args in by_name["window.fire"]:
+        fires.setdefault((tid, args["watermark"]), []).append((start, dur))
+    hops = q5_offloaded["hops"]
+    want = {"head": hops.count("fire_head"), "tail": hops.count("fire_tail"),
+            "start": hops.count("fire_tail")}
+    got = {}
+    for start, dur, tid, args in by_name[child]:
+        got[args["part"]] = got.get(args["part"], 0) + 1
+        assert dur >= 0
+        assert any(f_start <= start + 1 and start + dur <= f_start + f_dur + 1
+                   for f_start, f_dur in fires[tid, args["watermark"]]), \
+            (child, args, start, dur)
+    assert got == {p: want[p] for p in parts}
+    assert want["head"] == len(by_name["window.fire"])
+
+
+def test_fire_is_its_named_parts(q5_offloaded):
+    """``window.fire`` less d2h, emit, collect, the head's run, the hops
+    and the waits for the loop leaves little: what no span names (the
+    tail's flatten, the settle of a tail before) is the smaller share of
+    the fires' sum."""
+    by_name = _window_spans(q5_offloaded)
+    total = {name: sum(s[1] for s in spans)
+             for name, spans in by_name.items()}
+    named = sum(total[n] for n in (
+        "window.fire.d2h", "window.fire.emit", "window.fire.collect",
+        "window.fire.head.run", "window.fire.hop", "window.fire.loop_wait"))
+    # the head's read-back of the live count is in .d2h and in .head.run
+    assert 0.5 * total["window.fire"] <= named <= \
+        1.02 * total["window.fire"] + total["window.fire.d2h"], total
+
+
+def test_run_offloaded_without_span_keeps_off_the_ring(run_async):
+    """An unnamed hop counts its parts and records no span; a named one
+    records the parts it names, end to end."""
+    from arroyo_tpu.obs import perf, tracing
+
+    async def scenario():
+        loop = asyncio.get_running_loop()
+        perf.reset()
+        tracing.reset()
+        assert await perf.run_offloaded(loop, time.sleep, 0.01) is None
+        assert tracing.spans() == []
+        assert perf.counter("offload_hops") == 1
+        assert perf.counter("offload_us.run") >= 9000
+        assert "wait_us.offload_wait" not in perf._COUNTERS  # disarmed
+        await perf.run_offloaded(
+            loop, time.sleep, 0.01, span_args={"watermark": 7},
+            span={"cat": "window", "queue": "q", "resume": "r"})
+        (q, r) = tracing.spans("window")
+        assert (q[0], r[0]) == ("q", "r") and q[6] == {"watermark": 7}
+        gap = r[2] - (q[2] + q[3])  # the run between them, not recorded
+        assert 9000 <= gap <= 500000, gap
+        assert perf.counter("offload_hops") == 2
+
+    run_async(scenario())
+
+
+def test_cpu_counter_loses_no_update_across_threads(monkeypatch):
+    """Eight threads end frames of one phase at once: ``cpu_us.<phase>`` is
+    bumped under the profiler's lock, so no read-modify-write is lost.  A
+    thread CPU clock that steps 5 us a reading makes every frame 5 us."""
+    import sys
+    import threading
+
+    from arroyo_tpu.obs import perf
+
+    local = threading.local()
+
+    def stepping_clock():
+        local.ns = getattr(local, "ns", 0) + 5000
+        return local.ns
+
+    prof = profiler.arm("stress")
+    prof.reset()
+    perf.reset()
+    monkeypatch.setattr(profiler.time, "thread_time_ns", stepping_clock)
+    threads, frames = 8, 2000
+
+    def work():
+        for _ in range(frames):
+            prof.end(prof.begin("op", "proc"))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        pool = [threading.Thread(target=work) for _ in range(threads)]
+        for t in pool:
+            t.start()
+        for t in pool:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in pool)
+    snap = prof.snapshot()
+    assert snap["counts"]["op/proc"] == threads * frames
+    assert perf.counter("cpu_us.proc") == 5 * threads * frames
+    assert snap["cpu_phases"]["proc"] == pytest.approx(
+        5e-6 * threads * frames, rel=1e-6)
+    assert len(snap["threads_cpu"]) == threads

@@ -209,6 +209,10 @@ class BinAggOperator(Operator):
         # a fire's tail, or a watermark's forward queued behind it;
         # awaited by ``settle``
         self._tail: Optional[asyncio.Future] = None
+        # the executor half of the last batch's update, where transfers
+        # block (``perf.Offloaded``); at most one, awaited by the next
+        # hand-off, a grow, a firing head and ``settle``
+        self._update = None
 
     def _offload_transfers(self) -> bool:
         """Run device update/emit in an executor thread on accelerators:
@@ -272,7 +276,8 @@ class BinAggOperator(Operator):
         transfers block (``_offload_transfers``).  Safe to offload: the
         serial path awaits it, and a fire's tail touches its handle
         alone.  ``span`` / ``span_args`` are ``perf.run_offloaded``'s: a
-        fire names the parts of its hops, an update does not."""
+        fire names the parts of its hops, the mesh state's update does
+        not."""
         if self._offload_transfers():
             from ..obs import perf
 
@@ -282,30 +287,93 @@ class BinAggOperator(Operator):
         return fn(*args)
 
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
+        """A batch's update in two halves where the state has them
+        (``KeyedBinState.admit`` and ``apply``).  The loop half settles
+        what the next batch and a fire's check read: the bins, the
+        directory, the key columns.  The executor half (the row mass,
+        the reduce, the enqueue, at the bound the flush) is handed to
+        the executor and left in flight while the next batch's loop half
+        runs: at most one, in order, awaited by the next hand-off, by a
+        loop half that may grow the planes, by a firing head and by
+        ``settle``.  Where transfers are free it runs inline.  The mesh
+        state's update is whole and awaited."""
         assert batch.key_hash is not None, f"{self.name} requires keyed input"
-        if await self._tail_in_flight(ctx):
-            from ..obs import perf
+        from ..obs import perf
 
+        if await self._tail_in_flight(ctx):
             perf.count("fire_overlap_batches")
+        if self._update_in_flight():
+            perf.count("update_overlap_batches")
         self._lat_pending = _lat_track(self._lat_pending, batch)
         self._key_cols = batch.key_cols
-        prev = self.state.next_slot
-        slots = self.state._lookup_or_insert(batch.key_hash)
-        self.keyvals.ensure(batch, slots, prev, self.state.next_slot)
-        await self._run_state(self.state.update, batch.key_hash,
-                              batch.timestamp, batch.columns)
+        state = self.state
+        admit = getattr(state, "admit", None)  # not the mesh state's
+        bins = None
+        if admit is not None:
+            bins = state.assign(batch.timestamp)
+            if state.replaces_planes(len(batch), bins):
+                await self._await_update()  # it holds the planes
+        prev = state.next_slot
+        slots = state._lookup_or_insert(batch.key_hash)
+        self.keyvals.ensure(batch, slots, prev, state.next_slot)
+        if admit is None:
+            await self._run_state(state.update, batch.key_hash,
+                                  batch.timestamp, batch.columns)
+        else:
+            rows = admit(batch.key_hash, batch.timestamp, batch.columns,
+                         slots, bins)
+            await self._await_update()
+            if rows is not None and self._offload_transfers():
+                self._update = perf.Offloaded(asyncio.get_running_loop(),
+                                              state.apply, rows)
+            elif rows is not None:
+                state.apply(rows)
         self._ledger_updates += 1
         if self._ledger_updates % 16 == 1 and hasattr(self.state,
                                                       "device_bytes"):
             # throttled device-memory ledger note (join_state_registry
             # idiom): one entry per operator instance, metadata-only
-            from ..obs import perf
-
             reg = perf.get_note("pane_state_registry")
             if not isinstance(reg, dict):
                 reg = {}
                 perf.note("pane_state_registry", reg)
             reg[self.name] = self.state.device_bytes()
+
+    def _update_in_flight(self) -> bool:
+        """Whether the update handed off last is still on the executor;
+        one that is over is let go of here (its hop counted, the loop's
+        look its resume), and what it raised is raised."""
+        upd = self._update
+        if upd is not None and upd.done():
+            self._update = None
+            upd.result()
+        return self._update is not None
+
+    async def _await_update(self) -> None:
+        """The serial path waits for the update in flight, if any: the
+        wait is counted (``wait_us.update_wait``, always; the profiler's
+        ``offload_wait`` frame, armed) and what the update raised is
+        raised."""
+        upd = self._update
+        if upd is None:
+            return
+        if not upd.done():
+            from ..obs import perf, profiler
+
+            prof = profiler.active()
+            frame = (prof.begin(perf.active_operator_id() or self.name,
+                                "offload_wait", wait=True)
+                     if prof is not None else None)
+            t0 = _time.perf_counter_ns()
+            try:
+                await asyncio.wait((upd.future,))
+            finally:
+                if frame is not None:
+                    prof.end(frame)
+                perf.count("wait_us.update_wait",
+                           (_time.perf_counter_ns() - t0) // 1000)
+        self._update = None
+        upd.result()
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
         """A fire in two halves.  The head is what the operator's serial
@@ -319,7 +387,12 @@ class BinAggOperator(Operator):
         outputs are on the device for no longer), and a watermark that
         fires nothing (sources send one a batch) is forwarded from behind
         the tail in flight without waiting for it here; ``settle`` awaits
-        them all.  Span ``window.fire.hold`` is the head, ``window.fire``
+        them all.  Whether a watermark fires is checked here on the loop
+        (``KeyedBinState.fire_due``), from what the loop half of a
+        batch's update settled: one that fires nothing neither hops nor
+        waits for the update in flight, one that fires waits for it
+        first (the head flushes what it enqueues).  Span
+        ``window.fire.hold`` is the head, ``window.fire``
         a fire's watermark in to its batch sent on.  Where the halves hop
         to an executor thread, three more spans say what a fire waited
         for besides its own work (``_HEAD_HOP``, ``_TAIL_HOP``):
@@ -337,13 +410,18 @@ class BinAggOperator(Operator):
         forward = Message.wm(Watermark.event_time(watermark))
         with tracing.span("window.fire.hold", "window", tid=tid, args=args):
             in_flight = await self._tail_in_flight(ctx)
+            self._update_in_flight()  # one that failed fails here
             # the mesh state has no head and tail: its fire is serial
             tail = getattr(self.state, "fire_tail", None)
-            fire = await self._run_state(
-                self.state.fire_panes if tail is None
-                else self.state.fire_head, watermark, final,
-                span=dict(_HEAD_HOP, tid=tid),
-                span_args=dict(args, part="head"))
+            fire = None
+            if tail is None or self.state.fire_due(watermark, final):
+                # the head flushes what the update in flight enqueues
+                await self._await_update()
+                fire = await self._run_state(
+                    self.state.fire_panes if tail is None
+                    else self.state.fire_head, watermark, final,
+                    span=dict(_HEAD_HOP, tid=tid),
+                    span_args=dict(args, part="head"))
             if fire is None:
                 tracing.record_span("window.fire", "window", t0,
                                     tracing.now_us() - t0, tid=tid,
@@ -416,25 +494,32 @@ class BinAggOperator(Operator):
         """Whether a task started beside the serial path still runs; one
         that is over is let go of here, and what it raised is raised."""
         if self._tail is not None and self._tail.done():
-            await self.settle(ctx)
+            await self._settle_tail()
         return self._tail is not None
 
-    async def settle(self, ctx: Context) -> None:
+    async def _settle_tail(self) -> None:
         if self._tail is not None:
             try:
                 await self._tail  # raises what the tail raised
             finally:
                 self._tail = None
 
+    async def settle(self, ctx: Context) -> None:
+        await self._await_update()
+        await self._settle_tail()
+
     def abandon(self) -> None:
+        upd, self._update = self._update, None
         tail, self._tail = self._tail, None
-        if tail is None:
-            return
-        if not tail.done():
-            tail.cancel()
-        elif not tail.cancelled() and tail.exception() is not None:
-            logger.error("%s: a fire's tail failed: %r", self.name,
-                         tail.exception())
+        for fut, what in ((upd and upd.future, "an update"),
+                          (tail, "a fire's tail")):
+            if fut is None:
+                continue
+            if not fut.done():
+                fut.cancel()
+            elif not fut.cancelled() and fut.exception() is not None:
+                logger.error("%s: %s failed: %r", self.name, what,
+                             fut.exception())
 
     async def pre_checkpoint(self, barrier, ctx: Context) -> None:
         # a window fired before the barrier is downstream before it, and

@@ -115,70 +115,100 @@ def in_phase(phase: str):
     return wrap
 
 
+class Offloaded:
+    """``fn(*args)`` handed to the loop's executor at once, with the
+    caller's contextvars (executor threads don't inherit the caller's
+    context, so kernels dispatched from an offloaded transfer would
+    otherwise bypass the active task's accumulator and report zero
+    kernel time exactly on the accelerator backends where offload is
+    enabled), and collected later by :meth:`result` on the loop thread:
+    what :func:`run_offloaded` awaits, and what a bin state's update
+    leaves in flight (``BinAggOperator.process_batch``).
+
+    Where the hop goes is counted at its collection (four clock reads,
+    four counters): ``offload_us.queue``, from the submit on the loop
+    thread to the executor thread's first instruction (the pool's
+    pick-up, a thread's start, its first wait for the GIL);
+    ``offload_us.run``, the executor's own wall around ``fn``;
+    ``offload_us.resume``, from the executor's last instruction to the
+    loop thread's look at the result (for an awaited hop
+    ``call_soon_threadsafe``, the loop's wake-up, and every task that held
+    the loop before this one got it back; for an update left in flight,
+    the loop's next look at it); ``offload_hops``."""
+
+    __slots__ = ("future", "_t_submit", "_t_start", "_t_end")
+
+    def __init__(self, loop, fn, *args):
+        ctx = contextvars.copy_context()
+        self._t_start = self._t_end = 0
+
+        def job():
+            self._t_start = time.perf_counter_ns()
+            try:
+                return ctx.run(fn, *args)
+            finally:
+                self._t_end = time.perf_counter_ns()
+
+        self._t_submit = time.perf_counter_ns()
+        self.future = loop.run_in_executor(None, job)
+
+    def done(self) -> bool:
+        return self.future.done()
+
+    def result(self):
+        """What ``fn`` returned, or raise what it raised, once
+        :meth:`done`; counts the hop with now as the loop's look at it."""
+        self.stamp(time.perf_counter_ns())
+        return self.future.result()
+
+    def stamp(self, t_resume: int, span=None, span_args=None) -> None:
+        """Count the hop's parts (:func:`run_offloaded` has ``span``)."""
+        t_submit, t_start, t_end = self._t_submit, self._t_start, self._t_end
+        if not t_end:  # cancelled before the executor was through
+            return
+        self._t_end = 0  # counted once
+        count("offload_us.queue", (t_start - t_submit) // 1000)
+        count("offload_us.run", (t_end - t_start) // 1000)
+        count("offload_us.resume", (t_resume - t_end) // 1000)
+        count("offload_hops")
+        if span:
+            from . import tracing
+
+            to_us = tracing.now_us() - time.perf_counter_ns() / 1e3
+            cat = span.get("cat", "offload")
+            tid = span.get("tid") or active_task_id()
+            for part, t0, t1 in (("queue", t_submit, t_start),
+                                 ("run", t_start, t_end),
+                                 ("resume", t_end, t_resume)):
+                if part in span:
+                    tracing.record_span(span[part], cat,
+                                        to_us + t0 / 1e3,
+                                        (t1 - t0) / 1e3, tid=tid,
+                                        args=span_args)
+
+
 async def run_offloaded(loop, fn, *args, span=None, span_args=None):
-    """``loop.run_in_executor`` with contextvars propagated: executor
-    threads don't inherit the caller's context, so kernels dispatched
-    from an offloaded transfer would otherwise bypass the active task's
-    accumulator and report zero kernel time exactly on the accelerator
-    backends where offload is enabled.  The await is an ``offload_wait``
-    wait child of the caller's profiler frame: what the executor thread
-    does is accounted on its own stack, and never charged to the
-    caller's ``proc``/``watermark`` as well.
+    """``fn(*args)`` on the executor, awaited (:class:`Offloaded`, whose
+    four counters it bumps).  The await is an ``offload_wait`` wait child
+    of the caller's profiler frame: what the executor thread does is
+    accounted on its own stack, and never charged to the caller's
+    ``proc``/``watermark`` as well.
 
-    Where the await goes is counted on every hop (four clock reads, four
-    counters): ``offload_us.queue``, from the submit on the loop thread
-    to the executor thread's first instruction (the pool's pick-up, a
-    thread's start, its first wait for the GIL); ``offload_us.run``, the
-    executor's own wall around ``fn``; ``offload_us.resume``, from the
-    executor's last instruction to the loop thread back in this coroutine
-    (``call_soon_threadsafe``, the loop's wake-up, and every task that
-    held the loop before this one got it back); ``offload_hops``.
-
-    ``span`` names flight-recorder spans for those parts, ``{"queue" |
+    ``span`` names flight-recorder spans for the hop's parts, ``{"queue" |
     "run" | "resume": span name, "cat": category, "tid": trace track}``,
     recorded with ``span_args``; a part it leaves out, and every part of
-    a hop that names none (an update a batch would churn the ring), stays
-    off the ring."""
-    ctx = contextvars.copy_context()
-    t_start = t_end = 0
-
-    def job():
-        nonlocal t_start, t_end
-        t_start = time.perf_counter_ns()
-        try:
-            return ctx.run(fn, *args)
-        finally:
-            t_end = time.perf_counter_ns()
-
+    a hop that names none, stays off the ring."""
     prof = _profiler.active()
     frame = (prof.begin(active_operator_id() or "offload", "offload_wait",
                         wait=True) if prof is not None else None)
-    t_submit = time.perf_counter_ns()
+    job = Offloaded(loop, fn, *args)
     try:
-        return await loop.run_in_executor(None, job)
+        return await job.future
     finally:
         t_resume = time.perf_counter_ns()
         if frame is not None:
             prof.end(frame)
-        if t_end:  # not cancelled before the executor was through
-            count("offload_us.queue", (t_start - t_submit) // 1000)
-            count("offload_us.run", (t_end - t_start) // 1000)
-            count("offload_us.resume", (t_resume - t_end) // 1000)
-            count("offload_hops")
-            if span:
-                from . import tracing
-
-                to_us = tracing.now_us() - time.perf_counter_ns() / 1e3
-                cat = span.get("cat", "offload")
-                tid = span.get("tid") or active_task_id()
-                for part, t0, t1 in (("queue", t_submit, t_start),
-                                     ("run", t_start, t_end),
-                                     ("resume", t_end, t_resume)):
-                    if part in span:
-                        tracing.record_span(span[part], cat,
-                                            to_us + t0 / 1e3,
-                                            (t1 - t0) / 1e3, tid=tid,
-                                            args=span_args)
+        job.stamp(t_resume, span, span_args)
 
 
 def kernel_name(name: str):

@@ -68,7 +68,8 @@ plus overlapping **wait** phases (reported separately, never summed into
 the work table): ``queue_wait``, ``coalesce_wait``, ``send_wait``
 (backpressure enqueue), ``net_flush`` (socket drain), ``offload_wait``
 (the loop-side await of ``perf.run_offloaded`` while an executor thread
-runs the bin state's update or fire).  ``send_wait`` and ``offload_wait``
+runs the bin state's fire, and of ``BinAggOperator._await_update`` for
+an update still in flight when the serial path needs it).  ``send_wait`` and ``offload_wait``
 frames also add their microseconds to the ``perf`` counters
 ``wait_us.send_wait`` / ``wait_us.offload_wait``, summed over operators
 (:data:`COUNTED_WAITS`).

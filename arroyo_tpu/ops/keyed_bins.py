@@ -634,6 +634,31 @@ def _append_new_keys(state, new_keys: np.ndarray, ensure_capacity) -> None:
     state.slot_of_sorted = merged_slots[order]
 
 
+class BinsOf(NamedTuple):
+    """Each row's ring bin and liveness, and the live rows' least and
+    greatest absolute bin (None where no row is live):
+    :meth:`KeyedBinState.assign`."""
+
+    bins: np.ndarray
+    live: np.ndarray
+    n_live: int
+    lo: Optional[int]
+    hi: Optional[int]
+
+
+class AdmittedRows(NamedTuple):
+    """What the loop half of an update (:meth:`KeyedBinState.admit`)
+    hands its executor half (:meth:`KeyedBinState.apply`): every row's
+    slot and ring bin, which rows are live and how many, and the
+    batch's columns."""
+
+    slots: np.ndarray
+    bins: np.ndarray
+    live: np.ndarray
+    n_live: int
+    agg_inputs: Dict[str, np.ndarray]
+
+
 class FiredPanes(NamedTuple):
     """What a fire or a drain hands the operator: a row per fired
     (key, pane) cell.  ``slots`` is each row's host slot, the index the
@@ -813,28 +838,80 @@ class KeyedBinState:
         self._merge_cols = dict(channel_cols)
         self._rows_col = rows_col
 
-    @_in_phase("preagg")  # its directory lookup, h2d and dispatch nest
     def update(self, key_hash: np.ndarray, timestamps: np.ndarray,
                agg_inputs: Dict[str, np.ndarray]) -> None:
+        """An update in one call: its loop half (:meth:`admit`) and its
+        executor half (:meth:`apply`), one after the other."""
+        rows = self.admit(key_hash, timestamps, agg_inputs)
+        if rows is not None:
+            self.apply(rows)
+
+    def assign(self, timestamps: np.ndarray) -> BinsOf:
+        """Each row's ring bin and liveness against ``last_fired_pane``,
+        and the live rows' least and greatest absolute bin: one native
+        pass that writes nothing, so its caller can ask
+        :meth:`replaces_planes` before :meth:`admit` takes it."""
+        from ..native import assign_bins
+
+        threshold = (self.last_fired_pane - self.W + 2
+                     if self.last_fired_pane is not None else None)
+        return BinsOf(*assign_bins(timestamps, self.slide, self.B,
+                                   threshold))
+
+    def replaces_planes(self, n_rows: int, bins: BinsOf) -> bool:
+        """Whether admitting ``n_rows`` rows of ``bins`` may rebind the
+        planes: a ``_grow`` (the rows bound their new keys) or a
+        ``_grow_ring``.  An update in flight holds the planes, so its
+        caller waits for it first."""
+        if self.next_slot + n_rows > self.C:
+            return True
+        if bins.n_live == 0:
+            return False
+        lo = bins.lo if self.min_bin is None else min(self.min_bin, bins.lo)
+        hi = bins.hi if self.max_bin is None else max(self.max_bin, bins.hi)
+        return hi - lo >= self.B
+
+    @_in_phase("preagg")  # its directory lookup nests
+    def admit(self, key_hash: np.ndarray, timestamps: np.ndarray,
+              agg_inputs: Dict[str, np.ndarray],
+              slots: Optional[np.ndarray] = None,
+              bins: Optional[BinsOf] = None) -> Optional[AdmittedRows]:
+        """The loop half of an update: what a later batch's admission or
+        a fire's check reads.  The bins (``_admit_bins``: liveness,
+        ``min_bin`` / ``max_bin``, a ring growth) and the directory (the
+        rows' slots, new keys inserted, a ``_grow``).  ``slots`` and
+        ``bins`` where the caller has them already (its key columns are
+        stored by slot; :meth:`replaces_planes` took the bins).  Returns
+        what :meth:`apply` needs, or None where no row is live."""
         n = len(key_hash)
         if n == 0:
-            return
+            return None
         # the factor-window cost claim, made measurable: rows entering
         # pane-update state per event is ~K unfactored (every ring sees
         # every event) vs ~1 + O(panes) factored (derived rings see only
         # fired pane cells) — the correlated_windows bench reads these
         perf.count("pane_update_rows", n)
-        if self._merge_cols is not None:
-            self._update_merged(key_hash, timestamps, agg_inputs)
-            return
-        admitted = self._admit_bins(timestamps)
+        admitted = self._admit_bins(timestamps, bins)
         if admitted is None:
+            return None
+        if slots is None:
+            slots = self._lookup_or_insert(key_hash)
+        return AdmittedRows(slots, admitted[0], admitted[1],
+                            int(admitted[2]), agg_inputs)
+
+    @_in_phase("preagg")  # its h2d and dispatch nest
+    def apply(self, rows: AdmittedRows) -> None:
+        """The executor half of an update: the row mass, the channel
+        inputs, the per-(slot, bin) reduce, the enqueue and, at the
+        bound, the flush.  It reads the slots and bins :meth:`admit`
+        settled and never the directory, so it may run beside the next
+        batch's :meth:`admit` (one at a time, in order: the caller's)."""
+        if self._merge_cols is not None:
+            self._apply_merged(rows)
             return
-        bins_mod, live, n_live, _lo, _hi = admitted
-        self._note_mass(int(n_live))
-
-        slots = self._lookup_or_insert(key_hash)
-
+        slots, bins_mod, live, n_live, agg_inputs = rows
+        n = len(slots)
+        self._note_mass(n_live)
         # two-phase, local half: reduce rows per (slot, bin) on the host
         # before any device work (TumblingLocalAggregator analog) — under
         # hot-key skew this collapses the batch by orders of magnitude
@@ -863,21 +940,19 @@ class KeyedBinState:
                 slots, bins_mod, xfer_kinds, vals)
         self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c)
 
-    def _admit_bins(self, timestamps: np.ndarray
+    def _admit_bins(self, timestamps: np.ndarray,
+                    bins: Optional[BinsOf] = None
                     ) -> Optional[Tuple[np.ndarray, np.ndarray, int,
                                         int, int]]:
         """Shared update prologue (raw AND merge-input paths): a row in
         bin b feeds panes b..b+W-1 and is late (dropped) only when all
         those panes already fired — the reference's drop-behind-watermark
         semantics.  Bin assignment + liveness + min/max run as one
-        native pass; returns (bins_mod, live, n_live, lo, hi), or None
-        when nothing is live."""
-        from ..native import assign_bins
-
-        threshold = (self.last_fired_pane - self.W + 2
-                     if self.last_fired_pane is not None else None)
-        bins_mod, live, n_live, lo, hi = assign_bins(
-            timestamps, self.slide, self.B, threshold)
+        native pass (:meth:`assign`, or ``bins`` where the caller ran
+        it); returns (bins_mod, live, n_live, lo, hi), or None when
+        nothing is live."""
+        bins_mod, live, n_live, lo, hi = (self.assign(timestamps)
+                                          if bins is None else bins)
         if n_live == 0:
             return None
         lo_new = lo if self.min_bin is None else min(self.min_bin, lo)
@@ -916,26 +991,20 @@ class KeyedBinState:
         if self._pending_cells >= UPDATE_FLUSH_CELLS:
             self.flush_updates()
 
-    def _update_merged(self, key_hash: np.ndarray, timestamps: np.ndarray,
-                       agg_inputs: Dict[str, np.ndarray]) -> None:
-        """Merge-input update (derived windows): inputs are fired factor
-        panes, one row per (key, pane) — channel values come straight
-        from the mapped partial columns (their kinds reduce partial →
-        partial losslessly) and the per-cell rowcount is the SUM of the
-        pane row-mass column, so the resulting ring is the one the
-        unfactored member would hold after the same raw rows."""
-        n = len(key_hash)
+    def _apply_merged(self, rows: AdmittedRows) -> None:
+        """Merge-input update (derived windows), its executor half:
+        inputs are fired factor panes, one row per (key, pane) — channel
+        values come straight from the mapped partial columns (their kinds
+        reduce partial → partial losslessly) and the per-cell rowcount is
+        the SUM of the pane row-mass column, so the resulting ring is the
+        one the unfactored member would hold after the same raw rows."""
         from ..formats import coerce_float
 
-        admitted = self._admit_bins(timestamps)
-        if admitted is None:
-            return
-        bins_mod, live, _n_live, _lo, _hi = admitted
+        slots, bins_mod, live, _n_live, agg_inputs = rows
+        n = len(slots)
         w = coerce_float(agg_inputs[self._rows_col], ACC_DTYPE)
         w = np.where(np.isnan(w), 0.0, w)
         self._note_mass(int(np.ceil(w[live].sum())))
-
-        slots = self._lookup_or_insert(key_hash)
 
         xfer = self._xfer_ch
         xfer_kinds = tuple(self._ch_kinds[j] for j in xfer)
@@ -1242,16 +1311,12 @@ class KeyedBinState:
         fire = self.fire_head(watermark, final)
         return None if fire is None else self.fire_tail(fire)
 
-    def fire_head(self, watermark: int, final: bool = False
-                  ) -> Optional[PendingFire]:
-        """The half of a fire that reads or writes what a later update
-        reads or writes, so the half its caller waits for before the next
-        batch: the flush of the buffered updates, the pane arithmetic, the
-        scan and the read-back of its live count, the dispatch of the pick
-        (on the planes as they are now: the evict and the next update take
-        them donated) and of the evict, ``last_fired_pane`` and ``min_bin``
-        (an update drops its late rows by them).  Returns what
-        :meth:`fire_tail` needs, or None where no row fires."""
+    def _panes_due(self, watermark: int, final: bool
+                   ) -> Optional[Tuple[int, int]]:
+        """The first and last absolute pane ``watermark`` closes, or None
+        where it closes none.  Reads only what the loop half of an
+        update settles (``admit``: ``max_bin``, ``min_bin``,
+        ``next_slot``) and a fire's head (``last_fired_pane``)."""
         if self.max_bin is None or self.next_slot == 0:
             return None
         if final:
@@ -1265,8 +1330,30 @@ class KeyedBinState:
                       else (self.min_bin or 0))
         if last_pane < first_pane:
             return None
+        return first_pane, last_pane
+
+    def fire_due(self, watermark: int, final: bool = False) -> bool:
+        """Whether :meth:`fire_head` would close a pane: its check alone,
+        which an update in flight cannot change, so a watermark that
+        fires nothing need not wait for one."""
+        return self._panes_due(watermark, final) is not None
+
+    def fire_head(self, watermark: int, final: bool = False
+                  ) -> Optional[PendingFire]:
+        """The half of a fire that reads or writes what a later update
+        reads or writes, so the half its caller waits for before the next
+        batch: the flush of the buffered updates, the pane arithmetic, the
+        scan and the read-back of its live count, the dispatch of the pick
+        (on the planes as they are now: the evict and the next update take
+        them donated) and of the evict, ``last_fired_pane`` and ``min_bin``
+        (an update drops its late rows by them).  Returns what
+        :meth:`fire_tail` needs, or None where no row fires."""
+        due = self._panes_due(watermark, final)
+        if due is None:
+            return None
+        first_pane, last_pane = due
         # panes will actually fire: buffered batch updates must be in the
-        # planes first (the early returns above keep no-op watermark
+        # planes first (the early return above keeps no-op watermark
         # advances from forcing a flush per batch)
         self.flush_updates()
         pane_ends = np.arange(first_pane, last_pane + 1, dtype=np.int64)

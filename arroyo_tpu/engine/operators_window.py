@@ -124,36 +124,52 @@ class _SlotKeyValues:
     def __init__(self) -> None:
         self.cols: Dict[str, np.ndarray] = {}
         self.size = 0
+        # marks the slots of the batch's new keys while ``ensure`` runs
+        self._fresh = np.zeros(0, dtype=bool)
 
-    def ensure(self, batch: Batch, slots: np.ndarray, prev_next: int,
+    def ensure(self, batch: Batch, slots: np.ndarray, new_slots: np.ndarray,
                new_next: int) -> None:
-        if new_next <= self.size and self.cols:
+        """Store the key columns of the rows whose slot a new key took
+        (``new_slots``: fresh ones up to ``new_next``, or released ones
+        below it that a new key now holds)."""
+        if not len(new_slots) and self.cols:
             return
-        cap = max(new_next, 64)
-        for c in list(self.cols):
-            old = self.cols[c]
-            if len(old) < cap:
-                grown = np.empty(cap * 2, dtype=old.dtype)
-                grown[:len(old)] = old
-                self.cols[c] = grown
-        for c in batch.key_cols:
-            if c in batch.columns and c not in self.cols:
-                self.cols[c] = np.empty(
-                    cap * 2, dtype=batch.columns[c].dtype)
-        new_mask = slots >= prev_next
-        if new_mask.any():
-            idx = new_mask.nonzero()[0]
+        if new_next > self.size or not self.cols:
+            cap = max(new_next, 64)
+            for c in list(self.cols):
+                old = self.cols[c]
+                if len(old) < cap:
+                    grown = np.empty(cap * 2, dtype=old.dtype)
+                    grown[:len(old)] = old
+                    self.cols[c] = grown
             for c in batch.key_cols:
-                if c in batch.columns:
-                    self.cols[c][slots[idx]] = batch.columns[c][idx]
-        self.size = max(self.size, new_next)
+                if c in batch.columns and c not in self.cols:
+                    self.cols[c] = np.empty(
+                        cap * 2, dtype=batch.columns[c].dtype)
+            self.size = max(self.size, new_next)
+        if not len(new_slots) or not self.cols:
+            return
+        if len(self._fresh) < self.size:
+            self._fresh = np.zeros(2 * self.size, dtype=bool)
+        self._fresh[new_slots] = True
+        idx = self._fresh[slots].nonzero()[0]
+        self._fresh[new_slots] = False
+        for c in batch.key_cols:
+            if c in batch.columns:
+                self.cols[c][slots[idx]] = batch.columns[c][idx]
 
     def gather(self, slot_idx: np.ndarray) -> Dict[str, np.ndarray]:
         return {c: v[slot_idx] for c, v in self.cols.items()}
 
-    def snapshot(self) -> Dict[str, np.ndarray]:
-        return {f"kv_{c}": v[:self.size] for c, v in self.cols.items()} | {
-            "kv_size": np.array([self.size])}
+    def snapshot(self, live: Optional[np.ndarray] = None
+                 ) -> Dict[str, np.ndarray]:
+        """The key columns of slots ``0 .. size - 1``, or of the slots of
+        ``live`` in its order (a state that compacts its snapshot)."""
+        if live is None:
+            return {f"kv_{c}": v[:self.size] for c, v in self.cols.items()
+                    } | {"kv_size": np.array([self.size])}
+        return {f"kv_{c}": v[live] for c, v in self.cols.items()} | {
+            "kv_size": np.array([len(live)])}
 
     def restore(self, arrays: Dict[str, np.ndarray]) -> None:
         self.size = int(arrays["kv_size"][0])
@@ -246,7 +262,9 @@ class BinAggOperator(Operator):
             self.state.set_route_shift(route_shift_for(par))
 
         def snap():
-            out = self.state.snapshot() | self.keyvals.snapshot()
+            live = getattr(self.state, "live_slots", None)  # not the mesh's
+            out = self.state.snapshot() | self.keyvals.snapshot(
+                None if live is None else live())
             if self._lat_pending is not None:
                 # pending pane stamp survives checkpoint/restore so a
                 # sampled record held in pane state at barrier time is
@@ -313,9 +331,8 @@ class BinAggOperator(Operator):
             bins = state.assign(batch.timestamp)
             if state.replaces_planes(len(batch), bins):
                 await self._await_update()  # it holds the planes
-        prev = state.next_slot
         slots = state._lookup_or_insert(batch.key_hash)
-        self.keyvals.ensure(batch, slots, prev, state.next_slot)
+        self.keyvals.ensure(batch, slots, state.new_slots, state.next_slot)
         if admit is None:
             await self._run_state(state.update, batch.key_hash,
                                   batch.timestamp, batch.columns)

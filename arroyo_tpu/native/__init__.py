@@ -114,8 +114,11 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.arroyo_dir_load.argtypes = [ctypes.c_void_p, u64p, i64p,
                                     ctypes.c_int64]
     lib.arroyo_dir_insert.argtypes = [ctypes.c_void_p, u64p, ctypes.c_int64,
-                                      ctypes.c_int64, i64p, u64p]
+                                      ctypes.c_int64, i64p, ctypes.c_int64,
+                                      i64p, u64p]
     lib.arroyo_dir_insert.restype = ctypes.c_int64
+    lib.arroyo_dir_erase.argtypes = [ctypes.c_void_p, u64p, ctypes.c_int64]
+    lib.arroyo_dir_erase.restype = ctypes.c_int64
     lib.arroyo_dir_lookup.argtypes = [ctypes.c_void_p, u64p, ctypes.c_int64,
                                       i64p]
     lib.arroyo_agg_cells.argtypes = [
@@ -208,6 +211,9 @@ def assign_bins(ts: np.ndarray, slide: int, ring: int,
         return bins, live.astype(bool), 0, None, None
     return bins, live.astype(bool), int(n_live), lo.value, hi.value
 
+_NO_SLOTS = np.zeros(0, dtype=np.int64)
+
+
 class NativeDir:
     """Persistent open-addressing key directory (key hash -> slot) backed
     by the C++ table; ``None``-like when the native lib is unavailable —
@@ -233,17 +239,26 @@ class NativeDir:
         s = np.ascontiguousarray(slots, dtype=np.int64)
         _lib.arroyo_dir_load(self._h, k, s, len(k))
 
-    def insert(self, kh: np.ndarray, next_slot: int
+    def insert(self, kh: np.ndarray, next_slot: int,
+               free: Optional[np.ndarray] = None
                ) -> Tuple[np.ndarray, np.ndarray]:
         """Lookup-or-insert: returns (slots[n], new_keys) where unknown
-        keys got sequential slots from ``next_slot`` in first-seen order."""
+        keys, in first-seen order, got the slots of ``free`` first and
+        then sequential slots from ``next_slot``."""
         k = np.ascontiguousarray(kh, dtype=np.uint64)
+        f = np.ascontiguousarray(_NO_SLOTS if free is None else free,
+                                 dtype=np.int64)
         n = len(k)
         slots = np.empty(n, dtype=np.int64)
         new_keys = np.empty(n, dtype=np.uint64)
         n_new = _lib.arroyo_dir_insert(self._h, k, n, int(next_slot),
-                                       slots, new_keys)
+                                       f, len(f), slots, new_keys)
         return slots, new_keys[:n_new]
+
+    def erase(self, kh: np.ndarray) -> int:
+        """Remove keys (unknown ones are skipped); returns how many went."""
+        k = np.ascontiguousarray(kh, dtype=np.uint64)
+        return int(_lib.arroyo_dir_erase(self._h, k, len(k)))
 
     def lookup(self, kh: np.ndarray) -> np.ndarray:
         """Slots for known keys, -1 for unknown."""

@@ -8,7 +8,8 @@ watermark advance, adds/retracts bins from an in-memory per-key view.  Here:
 
 * the **key directory** lives on host: a sorted uint64 array of known key
   hashes with a parallel slot array (lookups are one vectorized
-  ``np.searchsorted`` per batch; inserts are a vectorized merge);
+  ``np.searchsorted`` per batch, or one pass of the native hash table;
+  inserts are a vectorized merge);
 * the **bin ring** lives in HBM as flat planes, one device array per
   channel (``values``) and one of row counts (``counts``) — C key slots x
   B time bins of ``slide`` width each, bin-major: the cell of slot ``s`` in
@@ -17,10 +18,13 @@ watermark advance, adds/retracts bins from an in-memory per-key view.  Here:
 * **pane emission** on watermark advance is one device kernel over all
   pending panes at once: a gather of the panes' bin rows (a bin's C slots
   lie together in a plane) and a window reduce;
-* eviction resets the expired bin rows on device, in place.
+* eviction resets the expired bin rows on device, in place, and a key
+  whose every bin is gone leaves the directory one fire later, its slot
+  to a free list that new keys take first, so the directory holds the
+  keys of the retained bins and not every key seen.
 
-Capacity doubles when the key directory fills; shapes are powers of two so
-recompiles are O(log keys).
+Capacity doubles when the live keys outgrow it; shapes are powers of two
+so recompiles are O(log keys).
 """
 
 from __future__ import annotations
@@ -591,7 +595,9 @@ def directory_insert(state, kh: np.ndarray, ensure_capacity) -> np.ndarray:
     (``key_sorted``, ``slot_of_sorted``, ``next_slot``, ``slot_to_key``),
     inserting unknown keys.  ``ensure_capacity(total_slots, new_keys)`` is
     the growth hook (device-array growth for KeyedBinState, shard-count
-    accounting + device growth for the mesh state).
+    accounting + device growth for the mesh state).  A state that keeps a
+    free list (``_free``: KeyedBinState's released slots) hands those to
+    new keys first; the mesh state has none and its slots are sequential.
 
     Fast path: when the state carries a native C++ hash directory
     (``state._ndir``), the per-row lookup is one O(n) linear-probe pass;
@@ -599,7 +605,8 @@ def directory_insert(state, kh: np.ndarray, ensure_capacity) -> np.ndarray:
     set) because checkpointing and emission-time lookups read them."""
     ndir = getattr(state, "_ndir", None)
     if ndir is not None:
-        slots, new_keys = ndir.insert(kh, state.next_slot)
+        slots, new_keys = ndir.insert(kh, state.next_slot,
+                                      getattr(state, "_free", None))
         _append_new_keys(state, new_keys, ensure_capacity)
         return slots
     uniq = np.unique(kh)
@@ -615,18 +622,27 @@ def directory_insert(state, kh: np.ndarray, ensure_capacity) -> np.ndarray:
 
 
 def _append_new_keys(state, new_keys: np.ndarray, ensure_capacity) -> None:
-    """Register new keys: sequential slots from ``next_slot`` (the order
-    the native dir already assigned), slot_to_key update, and sorted-array
-    merge.  Shared by the native and numpy directory paths so the
-    checkpointable arrays stay bit-identical between builds."""
-    if not len(new_keys):
-        return
+    """Register new keys: the slots of the state's free list first, where
+    it keeps one, then sequential slots from ``next_slot`` (the order the
+    native dir already assigned), slot_to_key update, and sorted-array
+    merge; ``new_slots`` is left holding the slots the keys got.  Shared
+    by the native and numpy directory paths so the checkpointable arrays
+    stay bit-identical between builds."""
+    free = getattr(state, "_free", None)
     n_new = len(new_keys)
+    reused = 0 if free is None else min(n_new, len(free))
+    fresh = n_new - reused
+    new_slots = np.arange(state.next_slot, state.next_slot + fresh)
+    if reused:
+        new_slots = np.concatenate([free[:reused], new_slots])
+        state._free = free[reused:]
+    state.new_slots = new_slots
+    if not n_new:
+        return
     perf.count("keys_inserted", n_new)
-    ensure_capacity(state.next_slot + n_new, new_keys)
-    new_slots = np.arange(state.next_slot, state.next_slot + n_new)
+    ensure_capacity(state.next_slot + fresh, new_keys)
     state.slot_to_key[new_slots] = new_keys
-    state.next_slot += n_new
+    state.next_slot += fresh
     merged = np.concatenate([state.key_sorted, new_keys])
     merged_slots = np.concatenate([state.slot_of_sorted, new_slots])
     order = np.argsort(merged, kind="stable")
@@ -649,14 +665,16 @@ class BinsOf(NamedTuple):
 class AdmittedRows(NamedTuple):
     """What the loop half of an update (:meth:`KeyedBinState.admit`)
     hands its executor half (:meth:`KeyedBinState.apply`): every row's
-    slot and ring bin, which rows are live and how many, and the
-    batch's columns."""
+    slot and ring bin, which rows are live and how many, the batch's
+    columns, and the least absolute bin of its live rows (their ring bins
+    lie within B of it, so it makes them absolute again)."""
 
     slots: np.ndarray
     bins: np.ndarray
     live: np.ndarray
     n_live: int
     agg_inputs: Dict[str, np.ndarray]
+    lo: int
 
 
 class FiredPanes(NamedTuple):
@@ -687,9 +705,10 @@ class PendingFire:
     block where channels ride the transfer), ``nnz`` how many of their
     rows are live; the bin-sharded ring route reads back in the head and
     leaves the flattened host ``cells`` instead.  ``slot_to_key`` is the
-    array the head saw (a grow replaces the state's, an insert writes
-    only past the slots that fired).  ``_d2h`` are the spans of the
-    fire's blocking read-backs so far (:func:`_readback`)."""
+    array the head saw (a grow replaces the state's; an insert writes
+    only slots that hold no key of this fire, as
+    :meth:`KeyedBinState.fire_tail` says).  ``_d2h`` are the spans of
+    the fire's blocking read-backs so far (:func:`_readback`)."""
 
     watermark: int
     pane_ends: np.ndarray
@@ -698,6 +717,11 @@ class PendingFire:
     devs: tuple = ()
     cells: Optional[tuple] = None
     _d2h: List[Tuple[float, float]] = dataclasses.field(default_factory=list)
+
+
+# ``KeyedBinState._last_bin`` of a slot on the free list: above every bin,
+# so no release takes it twice
+_FREE_SLOT = np.iinfo(np.int64).max
 
 
 class KeyedBinState:
@@ -742,8 +766,25 @@ class KeyedBinState:
 
         self.key_sorted = np.zeros(0, dtype=np.uint64)  # sorted known hashes
         self.slot_of_sorted = np.zeros(0, dtype=np.int64)
+        # the high-water mark of the slots: those below it are held by a
+        # key or on the free list
         self.next_slot = 0
+        # a slot's key.  A slot released at a fire (``_release_dead``)
+        # keeps its old key here until a new key takes it, and that is
+        # written only by the loop half of a batch's update, which runs
+        # after the fire's ``handle_watermark`` has settled the tail
+        # before it: the one tail that may still read the old key
         self.slot_to_key = np.zeros(self.C, dtype=np.uint64)
+        # the greatest absolute bin each slot has a cell in: -1 where its
+        # key has none yet, _FREE_SLOT where the slot is on the free list
+        self._last_bin = np.full(self.C, -1, dtype=np.int64)
+        # released slots, taken by new keys before ``next_slot`` (in order)
+        self._free = np.zeros(0, dtype=np.int64)
+        # the slots the last insert gave its new keys (_append_new_keys)
+        self.new_slots = np.zeros(0, dtype=np.int64)
+        # ``min_bin`` as the last fire left it: the next fire releases the
+        # slots whose last bin lies below it
+        self._release_below: Optional[int] = None
         from ..native import NativeDir
 
         self._ndir = NativeDir.create(self.C)
@@ -792,12 +833,42 @@ class KeyedBinState:
 
     @_in_phase("dir_insert")
     def _lookup_or_insert(self, kh: np.ndarray) -> np.ndarray:
-        """Vectorized key hash -> slot id, inserting unknown keys."""
+        """Vectorized key hash -> slot id, inserting unknown keys (into
+        released slots first: ``new_slots`` says which slots they got)."""
         def ensure(total, _new_keys):
             if total > self.C:
                 self._grow(total)
 
-        return directory_insert(self, kh, ensure)
+        slots = directory_insert(self, kh, ensure)
+        self._last_bin[self.new_slots] = -1
+        return slots
+
+    def _release_dead(self) -> None:
+        """Free the slots of keys whose every cell was evicted one fire
+        ago: those whose last bin lies below the ``min_bin`` the previous
+        fire left (``_release_below``).  Such a key holds no state (its
+        columns are at the channels' identities, every bin it touched
+        reset by the evict), and one fire late no fire's tail reads it:
+        the previous fire's tail, which may still run, reads keys that
+        died at its own evict, and this fire's panes start at that
+        ``min_bin``.  The keys leave the native directory and the sorted
+        pair, their slots go on the free list; ``slot_to_key`` keeps the
+        old keys until a new key takes the slot.  Counted in
+        ``keys_evicted``."""
+        below, self._release_below = self._release_below, self.min_bin
+        if below is None:
+            return
+        dead = np.flatnonzero(self._last_bin[:self.next_slot] < below)
+        if not len(dead):
+            return
+        if self._ndir is not None:
+            self._ndir.erase(self.slot_to_key[dead])
+        self._last_bin[dead] = _FREE_SLOT
+        keep = self._last_bin[self.slot_of_sorted] != _FREE_SLOT
+        self.key_sorted = self.key_sorted[keep]
+        self.slot_of_sorted = self.slot_of_sorted[keep]
+        self._free = np.concatenate([self._free, dead])
+        perf.count("keys_evicted", len(dead))
 
     def _grow(self, needed: int) -> None:
         perf.count("state_grows")
@@ -819,6 +890,8 @@ class KeyedBinState:
         self.counts = widen(self.counts, 0)
         self.slot_to_key = np.concatenate(
             [self.slot_to_key, np.zeros(pad, dtype=np.uint64)])
+        self._last_bin = np.concatenate(
+            [self._last_bin, np.full(pad, -1, dtype=np.int64)])
         self.C = newC
 
     # -- update ------------------------------------------------------------
@@ -863,7 +936,7 @@ class KeyedBinState:
         planes: a ``_grow`` (the rows bound their new keys) or a
         ``_grow_ring``.  An update in flight holds the planes, so its
         caller waits for it first."""
-        if self.next_slot + n_rows > self.C:
+        if self.next_slot + n_rows - len(self._free) > self.C:
             return True
         if bins.n_live == 0:
             return False
@@ -897,7 +970,7 @@ class KeyedBinState:
         if slots is None:
             slots = self._lookup_or_insert(key_hash)
         return AdmittedRows(slots, admitted[0], admitted[1],
-                            int(admitted[2]), agg_inputs)
+                            int(admitted[2]), agg_inputs, int(admitted[3]))
 
     @_in_phase("preagg")  # its h2d and dispatch nest
     def apply(self, rows: AdmittedRows) -> None:
@@ -909,7 +982,7 @@ class KeyedBinState:
         if self._merge_cols is not None:
             self._apply_merged(rows)
             return
-        slots, bins_mod, live, n_live, agg_inputs = rows
+        slots, bins_mod, live, n_live, agg_inputs, lo = rows
         n = len(slots)
         self._note_mass(n_live)
         # two-phase, local half: reduce rows per (slot, bin) on the host
@@ -938,7 +1011,7 @@ class KeyedBinState:
                     slots[idx], bins_mod[idx], vals[:, idx]
             slots_c, bins_c, rowcnt, vals_c = preaggregate(
                 slots, bins_mod, xfer_kinds, vals)
-        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c)
+        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c, lo)
 
     def _admit_bins(self, timestamps: np.ndarray,
                     bins: Optional[BinsOf] = None
@@ -981,11 +1054,16 @@ class KeyedBinState:
             self.counts = self.counts.astype(jnp.int64)
 
     def _enqueue_cells(self, slots_c: np.ndarray, bins_c: np.ndarray,
-                       rowcnt: np.ndarray, vals_c: np.ndarray) -> None:
-        """Shared update tail: buffer the pre-aggregated cell run (one
+                       rowcnt: np.ndarray, vals_c: np.ndarray,
+                       lo: int) -> None:
+        """Shared update tail: each cell's slot keeps the greatest absolute
+        bin it has a cell in (``_last_bin``: ``lo`` makes the ring bins
+        absolute), then the pre-aggregated cell run is buffered (one
         merged scatter carries many batches; the planes are only read at
-        pane fires / snapshots, and every reader flushes) and flush at
+        pane fires / snapshots, and every reader flushes) and flushed at
         the bound."""
+        abs_c = (bins_c.astype(np.int64) - lo) % self.B + lo
+        np.maximum.at(self._last_bin, slots_c, abs_c)
         self._pending.append((slots_c, bins_c, rowcnt, vals_c))
         self._pending_cells += len(slots_c)
         if self._pending_cells >= UPDATE_FLUSH_CELLS:
@@ -1000,7 +1078,7 @@ class KeyedBinState:
         one the unfactored member would hold after the same raw rows."""
         from ..formats import coerce_float
 
-        slots, bins_mod, live, _n_live, agg_inputs = rows
+        slots, bins_mod, live, _n_live, agg_inputs, lo = rows
         n = len(slots)
         w = coerce_float(agg_inputs[self._rows_col], ACC_DTYPE)
         w = np.where(np.isnan(w), 0.0, w)
@@ -1026,7 +1104,7 @@ class KeyedBinState:
             slots, bins_mod, ext_kinds, np.concatenate([vals, w[None]]))
         rowcnt = red[-1]
         vals_c = red[:-1]
-        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c)
+        self._enqueue_cells(slots_c, bins_c, rowcnt, vals_c, lo)
 
     @_in_phase("preagg")
     def flush_updates(self) -> None:
@@ -1407,6 +1485,7 @@ class KeyedBinState:
                     ek, self.values, self.counts, *_h2d(ring, ev),
                     in_total=False)
             self.min_bin = new_min
+        self._release_dead()
 
         fire = PendingFire(int(watermark), pane_ends, self.slot_to_key,
                            nnz, devs, cells, self._d2h)
@@ -1421,7 +1500,12 @@ class KeyedBinState:
         the blocking read-backs of the pick's outputs, the fire's accounts
         and the flatten.  Nothing here reads the planes, the directory or
         a field an update sets, so it may run while the next batches are
-        inserted and applied."""
+        inserted and applied.  The keys it reads from the handle's
+        ``slot_to_key`` stay there while it runs: an insert writes a fresh
+        slot, or one the next fire's head freed, and the operator lets a
+        batch in after that head only once this tail is over
+        (``_release_dead`` frees a key one fire after it died, so none of
+        this fire's own keys is freed before the head after next)."""
         cells = fire.cells if fire.cells is not None else \
             self._read_picked(fire)
         _fire_done(fire, fire.watermark)
@@ -1598,9 +1682,17 @@ class KeyedBinState:
         self.values = tuple(jnp.asarray(v.reshape(-1)) for v in values)
         self.counts = jnp.asarray(counts.reshape(-1))
 
+    def live_slots(self) -> np.ndarray:
+        """The slots a key holds, ascending: those below ``next_slot``
+        that are not on the free list.  A snapshot writes these alone,
+        compacted to slots ``0 .. n - 1`` in this order, so the operator
+        compacts its key columns by the same array."""
+        return np.flatnonzero(self._last_bin[:self.next_slot] != _FREE_SLOT)
+
     def snapshot(self) -> Dict[str, np.ndarray]:
         self.flush_updates()  # buffered cells belong to this epoch
-        n = self.next_slot
+        live = self.live_slots()
+        n = len(live)
         values, counts = self.host_planes()
         if self.min_bin is not None and self.max_bin is not None:
             lo = self.min_bin
@@ -1608,17 +1700,21 @@ class KeyedBinState:
         else:
             lo = -1
             cols = np.zeros(0, dtype=np.int64)
+        keys = self.slot_to_key[live]
         return {
-            "bin_keys": self.slot_to_key[:n],
+            "bin_keys": keys,
             # canonical form: a row per key, a column per linear bin
             "bin_vals": np.ascontiguousarray(
-                values[:, cols, :n].transpose(0, 2, 1)),
-            "bin_counts": np.ascontiguousarray(counts[cols, :n].T),
+                values[:, cols[:, None], live[None, :]].transpose(0, 2, 1)),
+            "bin_counts": np.ascontiguousarray(
+                counts[cols[:, None], live[None, :]].T),
             "ch_init": channel_inits(self._ch_kinds),
             "mesh_shards": np.array([1], dtype=np.int64),
             "key_sorted": self.key_sorted,
-            "slot_of_sorted": self.slot_of_sorted,
-            "slot_to_key": self.slot_to_key[:n],
+            # the sorted pair holds the live keys already: only their
+            # slots move, to their rank among the live ones
+            "slot_of_sorted": np.searchsorted(live, self.slot_of_sorted),
+            "slot_to_key": keys,
             "meta": np.array([
                 n, lo,  # lo == min_bin: first linear column's absolute bin
                 -1 if self.max_bin is None else self.max_bin,
@@ -1647,6 +1743,9 @@ class KeyedBinState:
         self.slot_to_key = np.zeros(self.C, dtype=np.uint64)
         self.slot_to_key[:self.next_slot] = \
             arrays["slot_to_key"].astype(np.uint64)[:self.next_slot]
+        self._last_bin = np.full(self.C, -1, dtype=np.int64)
+        self._free = np.zeros(0, dtype=np.int64)
+        self._release_below = None
 
         bin_keys = arrays["bin_keys"].astype(np.uint64)
         bin_vals = np.asarray(arrays["bin_vals"], dtype=ACC_DTYPE)
@@ -1665,6 +1764,10 @@ class KeyedBinState:
             cols = (np.arange(lo, lo + span) % self.B)
             values[:, cols[None, :], slots[:, None]] = bin_vals
             counts[cols[None, :], slots[:, None]] = bin_counts
+            # a key's last bin: its last column with rows
+            held = bin_counts != 0
+            last = lo + span - 1 - np.argmax(held[:, ::-1], axis=1)
+            self._last_bin[slots] = np.where(held.any(axis=1), last, -1)
         self._set_planes(values, counts)
 
 

@@ -166,11 +166,13 @@ void arroyo_dir_load(void* h, const uint64_t* keys, const int64_t* slots,
     }
 }
 
-// Lookup-or-insert a batch.  Unknown keys get sequential slots starting at
-// next_slot, in first-appearance order; their hashes are appended to
-// out_new_keys.  Returns the number of new keys.
+// Lookup-or-insert a batch.  Unknown keys get slots in first-appearance
+// order: the n_free slots of free_slots first, then sequential slots from
+// next_slot; their hashes are appended to out_new_keys.  Returns the
+// number of new keys.
 int64_t arroyo_dir_insert(void* h, const uint64_t* kh, int64_t n,
-                          int64_t next_slot, int64_t* out_slots,
+                          int64_t next_slot, const int64_t* free_slots,
+                          int64_t n_free, int64_t* out_slots,
                           uint64_t* out_new_keys) {
     ArroyoDir* d = (ArroyoDir*)h;
     int64_t n_new = 0;
@@ -181,13 +183,46 @@ int64_t arroyo_dir_insert(void* h, const uint64_t* kh, int64_t n,
         while (d->slots[j] >= 0 && d->keys[j] != k) j = (j + 1) & d->mask;
         if (d->slots[j] < 0) {
             d->keys[j] = k;
-            d->slots[j] = next_slot + n_new;
+            d->slots[j] = n_new < n_free ? free_slots[n_new]
+                                         : next_slot + (n_new - n_free);
             d->size++;
             out_new_keys[n_new++] = k;
         }
         out_slots[i] = d->slots[j];
     }
     return n_new;
+}
+
+// Remove keys (absent ones are skipped).  Backward-shift deletion: each
+// entry after the hole in its probe run moves back into the hole unless
+// its home bucket lies cyclically after the hole, so linear probing
+// needs no tombstones and a lookup stops at the first empty bucket.
+// Returns the number of keys removed.
+int64_t arroyo_dir_erase(void* h, const uint64_t* kh, int64_t n) {
+    ArroyoDir* d = (ArroyoDir*)h;
+    int64_t removed = 0;
+    for (int64_t i = 0; i < n; i++) {
+        uint64_t k = kh[i];
+        uint64_t hole = splitmix64(k) & d->mask;
+        while (d->slots[hole] >= 0 && d->keys[hole] != k)
+            hole = (hole + 1) & d->mask;
+        if (d->slots[hole] < 0) continue;
+        uint64_t j = hole;
+        for (;;) {
+            j = (j + 1) & d->mask;
+            if (d->slots[j] < 0) break;
+            uint64_t home = splitmix64(d->keys[j]) & d->mask;
+            // home in (hole, j] cyclically: the entry stays
+            if (((j - home) & d->mask) < ((j - hole) & d->mask)) continue;
+            d->keys[hole] = d->keys[j];
+            d->slots[hole] = d->slots[j];
+            hole = j;
+        }
+        d->slots[hole] = -1;
+        d->size--;
+        removed++;
+    }
+    return removed;
 }
 
 // Lookup only (emission-time key recovery); missing keys -> -1.

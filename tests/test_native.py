@@ -119,6 +119,67 @@ def test_native_dir_matches_sorted_directory(rng):
     np.testing.assert_array_equal(got, want)
 
 
+def test_native_dir_erase_and_free_slots_match_the_numpy_directory(rng):
+    """Keys leave the table (backward-shift deletion: every key still in
+    it is found, every key taken out is not, and the table never fills
+    with tombstones) and new keys take the free slots handed in, first
+    seen first, before sequential ones: slot for slot what the numpy
+    sorted-array directory of ``directory_insert`` gives for the same
+    free list."""
+    from types import SimpleNamespace
+
+    from arroyo_tpu.native import NativeDir
+    from arroyo_tpu.ops.keyed_bins import directory_insert
+
+    def numpy_dir():
+        return SimpleNamespace(
+            key_sorted=np.zeros(0, np.uint64),
+            slot_of_sorted=np.zeros(0, np.int64), next_slot=0,
+            slot_to_key=np.zeros(1 << 16, np.uint64),
+            _free=np.zeros(0, np.int64), _ndir=None)
+
+    d = NativeDir(16)  # 64 buckets: probe runs wrap round the table
+    ref = numpy_dir()
+    live = {}  # key -> slot
+    next_slot = 0
+    free = np.zeros(0, np.int64)
+    for round_ in range(40):
+        # a batch of 150 keys, half of them known; new ones arrive in a
+        # batch of their own first so both directories see them in the
+        # same (sorted) order
+        known = np.array(list(live), np.uint64)
+        fresh = np.unique(rng.integers(1, 2**64, 80, dtype=np.uint64))
+        fresh = fresh[~np.isin(fresh, known)]
+        slots, new_keys = d.insert(fresh, next_slot, free)
+        ref._free = free
+        want = directory_insert(ref, fresh, lambda *_: None)
+        np.testing.assert_array_equal(slots, want)
+        np.testing.assert_array_equal(new_keys, fresh)
+        used = min(len(fresh), len(free))
+        np.testing.assert_array_equal(slots[:used], free[:used])
+        np.testing.assert_array_equal(
+            slots[used:], next_slot + np.arange(len(fresh) - used))
+        next_slot += len(fresh) - used
+        free = free[used:]
+        live.update(zip(fresh.tolist(), slots.tolist()))
+        batch = np.concatenate([known[rng.integers(0, max(len(known), 1),
+                                                   70)] if len(known)
+                                else fresh[:0], fresh])
+        got, none_new = d.insert(batch, next_slot, free)
+        assert len(none_new) == 0
+        assert got.tolist() == [live[k] for k in batch.tolist()]
+        # a random half of the live keys leave; their slots are free
+        keys = np.array(list(live), np.uint64)
+        gone = keys[rng.random(len(keys)) < 0.5]
+        assert d.erase(np.concatenate([gone, gone[:3]])) == len(gone)
+        free = np.concatenate([free, [live.pop(k) for k in gone.tolist()]]
+                              ).astype(np.int64)
+        probe = np.concatenate([keys, np.array([7], np.uint64)])
+        np.testing.assert_array_equal(
+            d.lookup(probe), [live.get(k, -1) for k in probe.tolist()])
+    assert 0 < len(live) < 200 and next_slot < 40 * 80
+
+
 def test_agg_cells_matches_preaggregate(rng):
     """Native (slot,bin)-cell aggregation is a lossless reordering of the
     lexsort+reduceat preaggregate path for every channel kind."""

@@ -12,8 +12,9 @@ Maps the reference's window operator suite onto batched device kernels:
   (windows.rs:160-197): buffer rows, trigger at window end, segment-reduce on
   device; supports tumbling/sliding/instant windows, aggregate or flatten.
 * :class:`SessionWindowOperator` — SessionWindowFunc (windows.rs:200-427):
-  host-managed per-key gap-merged window sets (data-dependent merging stays
-  on host, as the reference keeps it in KeyedState), aggregation on device.
+  per-key gap-merged window sets (the interval union on device where the
+  backend is an accelerator); mergeable aggregates fold into each
+  session's own partials at the merge, others re-read buffered rows.
 * :class:`TumblingTopNOperator` — TumblingTopN (tumbling_top_n_window.rs);
   the fused SlidingAggregatingTopN lives as the ``top_n`` mode of
   :class:`BinAggOperator` (sliding_top_n_aggregating_window.rs).
@@ -33,6 +34,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import asyncio
 import logging
+import numbers
 import time as _time
 
 import numpy as np
@@ -390,7 +392,7 @@ class BinAggOperator(Operator):
                 perf.count("wait_us.update_wait",
                            (_time.perf_counter_ns() - t0) // 1000)
         self._update = None
-        upd.result()
+        upd.result()  # arroyolint: disable=async-blocking -- done: awaited above or found done, so this only re-raises
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
         """A fire in two halves.  The head is what the operator's serial
@@ -799,9 +801,55 @@ class WindowOperator(Operator):
         self.buffer.evict_before(end - self.width + self.slide)
 
 
+# the aggregates a session folds into partial channels of its own at the
+# merge (the accumulator path); any other plan keeps the row buffer
+_SESSION_ACC_KINDS = (AggKind.COUNT, AggKind.SUM, AggKind.MIN, AggKind.MAX,
+                      AggKind.AVG)
+# partial channel -> (fold kind, dtype); key columns ride as "first"
+_ACC_CHANNELS = {"rows": ("add", np.int64), "nn": ("add", np.int64),
+                 "sum": ("add", np.float64), "min": ("min", np.float64),
+                 "max": ("max", np.float64)}
+# what a NULL (or no row) feeds each fold: segment_aggregate's f64
+# extremes for min / max
+_F64_MAX = float(np.finfo(np.float64).max)
+_FOLD_IDENTITY = {"add": 0, "min": _F64_MAX, "max": -_F64_MAX}
+
+
+def session_acc_channels(aggs: Sequence[AggSpec], flatten: bool
+                         ) -> Optional[List[Tuple[str, Optional[str]]]]:
+    """The partial channels ``(name, column)`` a session's aggregates
+    fold from — a row count, and per aggregated column its non-null
+    count plus a sum, a min or a max as the aggregates need — or None
+    where the plan keeps the row buffer (flatten, COUNT DISTINCT,
+    UDAFs).  String MIN / MAX is told by its column's values
+    (:meth:`SessionWindowOperator._acc_verdict`)."""
+    if flatten or not aggs or any(a.kind not in _SESSION_ACC_KINDS
+                                  for a in aggs):
+        return None
+    chans: List[Tuple[str, Optional[str]]] = [("rows", None)]
+    for a in aggs:
+        if a.column is None:  # COUNT(*) reads the row count
+            continue
+        need = [("nn", a.column)]
+        if a.kind in (AggKind.SUM, AggKind.AVG):
+            need.append(("sum", a.column))
+        elif a.kind in (AggKind.MIN, AggKind.MAX):
+            need.append((a.kind.value, a.column))
+        chans.extend(c for c in need if c not in chans)
+    return chans
+
+
 class SessionWindowOperator(Operator):
     """Session windows with gap merging: per-key window sets on host
-    (SessionWindowFunc / WindowGroup, windows.rs:200-427)."""
+    (SessionWindowFunc / WindowGroup, windows.rs:200-427).
+
+    Plans whose aggregates are COUNT / SUM / MIN / MAX / AVG over
+    numeric columns take the accumulator path: every session carries
+    its rows' partials (``session_acc_channels`` plus its key columns'
+    values), folded wherever sessions merge, and a fire emits the
+    closed sessions straight off them — no row buffer.  Flatten,
+    COUNT DISTINCT, UDAF and string MIN / MAX plans buffer the rows and
+    re-read them at the fire."""
 
     def __init__(self, name: str, gap_micros: int, aggs: Tuple[AggSpec, ...],
                  flatten: bool, projection=None):
@@ -811,13 +859,27 @@ class SessionWindowOperator(Operator):
         self.flatten = flatten or not aggs
         self.projection = (CompiledExpr(projection.name, projection.fn)
                            if projection else None)
-        self._pending_fires: List[Tuple[int, int, int]] = []
+        # (keys, starts, ends, channels) of closed sessions, one entry
+        # an expire
+        self._pending_fires: List[Tuple[np.ndarray, np.ndarray,
+                                        np.ndarray, List[np.ndarray]]] = []
         self._min_end: Optional[int] = None  # no-fire fast-path bound
+        self._acc_chans = session_acc_channels(aggs, self.flatten)
+        # the path: None until the first rows that tell (or the restored
+        # state) settle it, where the plan could take the accumulators
+        self._acc_on: Optional[bool] = (None if self._acc_chans is not None
+                                        else False)
+        self._key_cols: Tuple[str, ...] = ()
+        self._firsts: Tuple[str, ...] = ()  # key columns carried
+        self._kinds: Tuple[str, ...] = ()
+        self._dtypes: Tuple[np.dtype, ...] = ()
 
     def tables(self) -> List[TableDescriptor]:
         return [
             TableDescriptor("s", TableType.BATCH_BUFFER, "session data"),
             TableDescriptor("v", TableType.KEYED, "session windows per key"),
+            TableDescriptor("k", TableType.GLOBAL,
+                            "session key columns and their dtypes"),
         ]
 
     async def on_start(self, ctx: Context) -> None:
@@ -829,43 +891,236 @@ class SessionWindowOperator(Operator):
         # interface, so the per-key clamp path below runs unchanged
         self.windows = ctx.state.get_session_state("v")
         self._device_state = isinstance(self.windows, SessionRunState)
+        self._layout = ctx.state.get_global_keyed_state("k")
         self._lat_pending: Optional[Tuple[int, float]] = None
+        if self._acc_on is None:
+            self._resume_path()
 
-    def _merge_key(self, kh: int, times: np.ndarray, ctx: Context) -> None:
-        """handle_event extend/merge/create (windows.rs:232-302)."""
-        sessions: List[Tuple[int, int]] = list(self.windows.get(kh) or [])
-        for t in np.sort(times).tolist():
+    # -- the accumulator path's layout -------------------------------------
+
+    def _acc_verdict(self, columns: Dict[str, np.ndarray]
+                     ) -> Optional[bool]:
+        """Whether an accumulator plan's rows fold.  False where a MIN /
+        MAX column is an object column holding anything but numbers and
+        NULLs (strings, booleans): segment_aggregate's host reduce of
+        object columns keeps the buffer.  None while such a column
+        holds only NULLs, which say nothing of its type: the rows ride
+        the buffer until rows that do arrive.  True otherwise."""
+        verdict: Optional[bool] = True
+        for a in self.aggs:
+            if a.kind not in (AggKind.MIN, AggKind.MAX):
+                continue
+            col = columns[a.column]
+            if col.dtype != object:
+                continue
+            vals = col[~np.equal(col, None)]
+            if not len(vals):
+                verdict = None
+            elif not all(isinstance(v, numbers.Real)
+                         and not isinstance(v, (bool, np.bool_))
+                         for v in vals):
+                return False
+        return verdict
+
+    def _set_layout(self, key_cols: Sequence[str],
+                    firsts: Sequence[str]) -> None:
+        """Channels: the plan's partials, then one "first" channel a
+        carried key column (``firsts``), whose dtype follows its values
+        (``SessionRunState.configure``)."""
+        self._key_cols = tuple(key_cols)
+        self._firsts = tuple(firsts)
+        kd = [_ACC_CHANNELS[name] for name, _ in self._acc_chans]
+        self._kinds = (tuple(k for k, _ in kd)
+                       + ("first",) * len(self._firsts))
+        self._dtypes = (tuple(np.dtype(d) for _, d in kd)
+                        + (np.dtype(object),) * len(self._firsts))
+
+    def _start_acc(self, key_cols: Sequence[str],
+                   firsts: Sequence[str]) -> None:
+        self._set_layout(key_cols, firsts)
+        if self._device_state:
+            self.windows.configure(self._kinds, self._dtypes)
+        # a restore before any row arrives still knows the key columns
+        self._layout.insert("key_cols", (self._key_cols, self._firsts))
+        self._acc_on = True
+
+    def _choose_path(self, batch: Batch) -> None:
+        """Settle the path at the first rows that tell (see
+        :meth:`_acc_verdict`); sessions the buffer held until then are
+        rebuilt into channels once."""
+        verdict = self._acc_verdict(batch.columns)
+        if verdict is None:
+            return
+        if not verdict:
+            self._acc_on = False
+            return
+        self._rebuild_from_rows(self.buffer.all(), batch.key_cols, tuple(
+            c for c in batch.key_cols if c in batch.columns))
+
+    def _resume_path(self) -> None:
+        """Settle the path from restored state.  An epoch of the
+        accumulator path names its key columns in table ``k``.  An
+        epoch of the buffer path (``(start, end)`` sessions and the
+        rows they hold) is rebuilt into channels once, unless its rows
+        hold string MIN / MAX, or tell nothing yet; sessions without
+        rows stay on the buffer.  Nothing restored: the first rows
+        decide."""
+        saved = self._layout.get("key_cols")
+        if saved is not None:
+            self._start_acc(*saved)
+            return
+        rows = self.buffer.all()
+        if rows is None or not len(rows):
+            if next(iter(self.windows.items()), None) is not None:
+                self._acc_on = False
+            return
+        verdict = self._acc_verdict(rows.columns)
+        if verdict is False:
+            self._acc_on = False
+        elif verdict:
+            self._rebuild_from_rows(rows, rows.key_cols, tuple(
+                c for c in rows.key_cols if c in rows.columns))
+
+    def _rebuild_from_rows(self, rows: Optional[Batch],
+                           key_cols: Sequence[str],
+                           firsts: Sequence[str]) -> None:
+        """The one-time rebuild of sessions the buffer holds the rows
+        of (a buffer-path epoch, or the rows before the path was
+        settled): the fire's row-to-session sweep over the rows, each
+        session's partials folded from its rows (identities where it
+        holds none, so it emits nothing, as it would not have), the
+        sessions written back as ``(start, end, *channels)`` and the
+        buffer emptied."""
+        from ..state.session_state import fold_channels
+
+        self._set_layout(key_cols, firsts)
+        keys: List[int] = []
+        bounds: List[Tuple[int, int]] = []
+        times: Dict[int, int] = {}
+        for kh, sessions in self.windows.items():
+            times[int(kh)] = int(self.windows.get_time(kh) or 0)
+            for sess in sessions:
+                keys.append(int(kh))
+                bounds.append((int(sess[0]), int(sess[1])))
+        m = len(keys)
+        fk = np.fromiter(keys, dtype=np.uint64, count=m)
+        fs = np.fromiter((b[0] for b in bounds), dtype=np.int64, count=m)
+        fe = np.fromiter((b[1] for b in bounds), dtype=np.int64, count=m)
+        fo = np.lexsort((fs, fk))
+        fk, fs, fe = fk[fo], fs[fo], fe[fo]
+        vals: Optional[List[np.ndarray]] = None
+        if rows is not None and len(rows):
+            sel, seg = self._assign_rows(fk, fs, fe, rows)
+            if len(sel):
+                so = np.argsort(seg, kind="stable")
+                seg_sorted = seg[so]
+                first = np.flatnonzero(np.concatenate(
+                    [[True], seg_sorted[1:] != seg_sorted[:-1]]))
+                vals = fold_channels(
+                    self._kinds, self._row_channels(rows.columns, sel[so]),
+                    first)
+        ch: List[np.ndarray] = []
+        for j, (kind, d) in enumerate(zip(self._kinds, self._dtypes)):
+            if kind == "first" and vals is not None:
+                d = vals[j].dtype  # a key column keeps its values' type
+            c = np.full(m, _FOLD_IDENTITY.get(kind, 0), dtype=d)
+            if vals is not None:
+                c[seg_sorted[first]] = vals[j]
+            ch.append(c)
+        cut = np.flatnonzero(np.concatenate([[True], fk[1:] != fk[:-1],
+                                             [True]])) if m else []
+        entries = []
+        for lo, hi in zip(cut[:-1], cut[1:]):
+            k = int(fk[lo])
+            entries.append((times[k], k, list(zip(
+                fs[lo:hi].tolist(), fe[lo:hi].tolist(),
+                *(c[lo:hi] for c in ch)))))
+        self.windows.restore(entries)
+        self.buffer.restore_batch(None)
+        self._start_acc(key_cols, firsts)
+
+    def _row_channels(self, columns: Dict[str, np.ndarray],
+                      order: np.ndarray) -> List[np.ndarray]:
+        """Each row's own partials in the layout's channels, rows taken
+        in ``order``.  NaN is a column's NULL, as segment_aggregate
+        reads it: it counts in no non-null count and feeds the sums,
+        minima and maxima their identities."""
+        from ..formats import coerce_float
+
+        n = len(order)
+        raw: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
+        out: List[np.ndarray] = []
+        for name, col in self._acc_chans:
+            if name == "rows":
+                out.append(np.ones(n, dtype=np.int64))
+                continue
+            if col not in raw:
+                v = coerce_float(columns[col][order], np.float64)
+                raw[col] = (v, ~np.isnan(v))
+            v, ok = raw[col]
+            if name == "nn":
+                out.append(ok.astype(np.int64))
+            else:
+                out.append(np.where(ok, v, float(
+                    _FOLD_IDENTITY[_ACC_CHANNELS[name][0]])))
+        out.extend(columns[c][order] for c in self._firsts)
+        return out
+
+    # -- merge ---------------------------------------------------------------
+
+    def _merge_key(self, kh: int, times: np.ndarray, ctx: Context,
+                   rch: Sequence[np.ndarray] = ()) -> None:
+        """handle_event extend/merge/create (windows.rs:232-302); each
+        event brings its own row's partials ``rch`` into the session it
+        joins."""
+        from ..state.session_state import fold_pair
+
+        kinds = self._kinds
+        sessions: List[Tuple[Any, ...]] = list(self.windows.get(kh) or [])
+        o = np.argsort(times, kind="stable")
+        evs = list(zip(*(c[o] for c in rch))) if rch else None
+        for j, t in enumerate(times[o].tolist()):
+            acc = evs[j] if evs is not None else ()
             placed = False
-            for i, (s, e) in enumerate(sessions):
+            for i, sess in enumerate(sessions):
+                s, e = sess[0], sess[1]
                 if s - self.gap <= t < e:
                     ns, ne = min(s, t), max(e, t + self.gap)
                     if ne - ns > MAX_SESSION_SIZE_MICROS:
-                        ne = ns + MAX_SESSION_SIZE_MICROS
-                    sessions[i] = (ns, ne)
+                        # the clamp stops the end from growing but never
+                        # moves it back: an event that extends a
+                        # session's start joins it with every event the
+                        # session already holds
+                        ne = max(ns + MAX_SESSION_SIZE_MICROS, e)
+                    sessions[i] = (ns, ne, *fold_pair(kinds, sess[2:], acc))
                     placed = True
                     break
             if not placed:
-                sessions.append((t, t + self.gap))
+                sessions.append((t, t + self.gap, *acc))
             # merge overlapping sessions
-            sessions.sort()
-            merged: List[Tuple[int, int]] = []
-            for s, e in sessions:
-                if merged and s <= merged[-1][1]:
-                    ps, pe = merged[-1]
-                    merged[-1] = (ps, max(pe, e))
+            sessions.sort(key=lambda r: (r[0], r[1]))
+            merged: List[Tuple[Any, ...]] = []
+            for sess in sessions:
+                if merged and sess[0] <= merged[-1][1]:
+                    prev = merged[-1]
+                    merged[-1] = (prev[0], max(prev[1], sess[1]),
+                                  *fold_pair(kinds, prev[2:], sess[2:]))
                 else:
-                    merged.append((s, e))
+                    merged.append(sess)
             sessions = merged
         self.windows.insert(int(times.max()), kh, sessions)
         if sessions:
-            me = min(e for _, e in sessions)
+            me = min(sess[1] for sess in sessions)
             if self._min_end is not None and me < self._min_end:
                 self._min_end = me
 
     async def process_batch(self, batch: Batch, ctx: Context, side: int = 0) -> None:
         assert batch.key_hash is not None
         self._lat_pending = _lat_track(self._lat_pending, batch)
-        self.buffer.append(batch)
+        if self._acc_on is None:
+            self._choose_path(batch)
+        if not self._acc_on:
+            self.buffer.append(batch)
         # collapse events -> candidate session intervals for the WHOLE
         # batch in three vector ops (events within gap of their
         # predecessor merge, so a burst becomes ONE interval): the
@@ -887,9 +1142,18 @@ class SessionWindowOperator(Operator):
         kb = np.append(kb, len(ikh))
         span_ok = (ien - ist) <= MAX_SESSION_SIZE_MICROS
         key_starts = np.append(newkey.nonzero()[0], n)
+        rch: List[np.ndarray] = []
+        ich: List[np.ndarray] = []
+        if self._acc_on:
+            from ..state.session_state import fold_channels
+
+            # the rows' partials, and each interval's folded over its rows
+            rch = self._row_channels(batch.columns, order)
+            ich = fold_channels(self._kinds, rch, brk.nonzero()[0])
         if self._device_state:
             await self._merge_batch_device(kh, ts, ikh, ist, ien, kb,
-                                           span_ok, key_starts, ctx)
+                                           span_ok, key_starts, rch, ich,
+                                           ctx)
             return
         from ..state.session_state import _count_merge
 
@@ -897,19 +1161,21 @@ class SessionWindowOperator(Operator):
         for i in range(len(kb) - 1):
             k = int(ikh[kb[i]])
             lo, hi = kb[i], kb[i + 1]
+            ivs = list(zip(*(c[lo:hi] for c in ich))) if ich else None
             if not span_ok[lo:hi].all() or not self._merge_key_intervals(
                     k, ist[lo:hi].tolist(), ien[lo:hi].tolist(),
-                    int(ts[key_starts[i + 1] - 1]), ctx):
+                    int(ts[key_starts[i + 1] - 1]), ctx, ivs):
                 # a burst longer than MAX_SESSION_SIZE, or a merge that
                 # would clamp-truncate past an incoming interval's end
                 # (events beyond the clamp must START a new session, and
                 # only the per-event path knows their positions): rare —
                 # the incremental-clamp-splitting path is authoritative
-                self._merge_key(k, ts[key_starts[i]:key_starts[i + 1]],
-                                ctx)
+                a, b = key_starts[i], key_starts[i + 1]
+                self._merge_key(k, ts[a:b], ctx, [c[a:b] for c in rch])
 
     async def _merge_batch_device(self, kh, ts, ikh, ist, ien, kb,
-                                  span_ok, key_starts, ctx: Context) -> None:
+                                  span_ok, key_starts, rch, ich,
+                                  ctx: Context) -> None:
         """Device-state merge: ONE vectorized interval-union dispatch
         covers every in-bounds key; keys the clamp touches (overlong
         bursts, or merged spans crossing MAX_SESSION_SIZE) re-run the
@@ -935,7 +1201,8 @@ class SessionWindowOperator(Operator):
                             "session_merge") if prof is not None else None)
         try:
             flagged = self.windows.merge_intervals(
-                ikh[good_iv], ist[good_iv], ien[good_iv], itm[good_iv])
+                ikh[good_iv], ist[good_iv], ien[good_iv], itm[good_iv],
+                [c[good_iv] for c in ich])
         finally:
             if prof is not None:
                 prof.end(frame)
@@ -948,7 +1215,8 @@ class SessionWindowOperator(Operator):
             for i in sorted(fb):
                 lo, hi = key_starts[i], key_starts[i + 1]
                 host_events += int(hi - lo)
-                self._merge_key(int(keys_arr[i]), ts[lo:hi], ctx)
+                self._merge_key(int(keys_arr[i]), ts[lo:hi], ctx,
+                                [c[lo:hi] for c in rch])
             _count_merge(0, host_events)
         # exact no-fire bound straight off the runs (cheap: P partition
         # minima), replacing the legacy conservative tracking
@@ -956,44 +1224,55 @@ class SessionWindowOperator(Operator):
 
     def _merge_key_intervals(self, kh: int, ists: List[int],
                              iens: List[int], max_t: int,
-                             ctx: Context) -> bool:
-        """Union sorted candidate intervals into the key's sorted session
-        list — linear two-pointer sweep with the same touching-merges and
-        incremental max-size clamp as the per-event path.  Returns False
-        WITHOUT touching state when a clamp would truncate below a
-        contributing interval's end (events past the clamp would be
-        silently swallowed; the caller re-runs the per-event path)."""
-        old: List[Tuple[int, int]] = list(self.windows.get(kh) or [])
-        merged: List[Tuple[int, int]] = []
+                             ctx: Context,
+                             ichs: Optional[List[Tuple[Any, ...]]] = None
+                             ) -> bool:
+        """Union sorted candidate intervals (with their partials
+        ``ichs``) into the key's sorted session list — linear two-pointer
+        sweep with the same touching-merges and incremental max-size
+        clamp as the per-event path.  Returns False WITHOUT touching
+        state when a clamp would truncate below a contributing
+        interval's end (events past the clamp would be silently
+        swallowed; the caller re-runs the per-event path)."""
+        from ..state.session_state import fold_pair
+
+        kinds = self._kinds
+        old: List[Tuple[Any, ...]] = list(self.windows.get(kh) or [])
+        merged: List[Tuple[Any, ...]] = []
         i = j = 0
         no, ni = len(old), len(ists)
         while i < no or j < ni:
             if i < no and (j >= ni or old[i][0] <= ists[j]):
-                s, e = old[i]
+                cur = old[i]
                 i += 1
             else:
-                s, e = ists[j], iens[j]
+                cur = (ists[j], iens[j], *(ichs[j] if ichs else ()))
                 j += 1
+            s, e = cur[0], cur[1]
             if merged and s <= merged[-1][1]:
-                ps, pe = merged[-1]
+                prev = merged[-1]
+                ps, pe = prev[0], prev[1]
                 ne = max(pe, e)
                 if ne - ps > MAX_SESSION_SIZE_MICROS:
-                    if ps + MAX_SESSION_SIZE_MICROS < e:
+                    if pe < e:
                         return False  # clamp would swallow interval tail
-                    ne = ps + MAX_SESSION_SIZE_MICROS
-                merged[-1] = (ps, ne)
+                    # an end the clamp never moves back (_merge_key)
+                    ne = pe
+                merged[-1] = (ps, ne, *fold_pair(kinds, prev[2:], cur[2:]))
             else:
                 if e - s > MAX_SESSION_SIZE_MICROS:
                     return False  # guarded by span_ok; belt-and-braces
-                merged.append((s, e))
-        self.windows.insert(max_t, kh, merged if merged != old else old)
+                merged.append(cur)
+        self.windows.insert(max_t, kh, merged)
         if merged:
             # keep the no-fire fast-path bound conservative: a fresh
             # short session may end before the cached minimum
-            me = min(e for _, e in merged)
+            me = min(sess[1] for sess in merged)
             if self._min_end is not None and me < self._min_end:
                 self._min_end = me
         return True
+
+    # -- fire ----------------------------------------------------------------
 
     def _collect_expired(self, watermark: int, ctx: Context) -> None:
         """Move every session with end <= watermark into the pending-fire
@@ -1009,81 +1288,92 @@ class SessionWindowOperator(Operator):
         if self._device_state:
             # mask-compress every closed session out of the runs in one
             # vector pass per partition — no key iteration
-            fk, fs, fe, removed = self.windows.expire(watermark)
-            self._pending_fires.extend(
-                zip((int(k) for k in fk.tolist()), fs.tolist(),
-                    fe.tolist()))
+            fk, fs, fe, fch, removed = self.windows.expire(watermark)
+            if len(fk):
+                self._pending_fires.append((fk, fs, fe, fch))
             for kh in removed:
                 ctx.state.note_delete("v", kh)
             self._min_end = self.windows.min_end()
             return
+        from ..state.session_state import channel_array
+
         expired_keys = []
         min_end = None
+        fired: List[Tuple[int, Tuple[Any, ...]]] = []
         for kh, sessions in self.windows.items():
-            fire = [(s, e) for (s, e) in sessions if e <= watermark]
+            fire = [sess for sess in sessions if sess[1] <= watermark]
             if not fire:
-                for (_s, e) in sessions:
-                    if min_end is None or e < min_end:
-                        min_end = e
+                for sess in sessions:
+                    if min_end is None or sess[1] < min_end:
+                        min_end = sess[1]
                 continue
-            remain = [(s, e) for (s, e) in sessions if e > watermark]
+            remain = [sess for sess in sessions if sess[1] > watermark]
             if remain:
                 self.windows.insert(watermark, kh, remain)
-                for (_s, e) in remain:
-                    if min_end is None or e < min_end:
-                        min_end = e
+                for sess in remain:
+                    if min_end is None or sess[1] < min_end:
+                        min_end = sess[1]
             else:
                 expired_keys.append(kh)
-            self._pending_fires.extend((int(kh), s, e) for (s, e) in fire)
+            fired.extend((int(kh), sess) for sess in fire)
         for kh in expired_keys:
             self.windows.remove(kh)
             ctx.state.note_delete("v", kh)
         self._min_end = min_end
+        if fired:
+            m = len(fired)
+            self._pending_fires.append((
+                np.fromiter((k for k, _ in fired), dtype=np.uint64, count=m),
+                np.fromiter((r[0] for _, r in fired), dtype=np.int64,
+                            count=m),
+                np.fromiter((r[1] for _, r in fired), dtype=np.int64,
+                            count=m),
+                [channel_array([r[2 + j] for _, r in fired], d)
+                 for j, d in enumerate(self._dtypes)]))
 
     async def _flush_fires(self, ctx: Context) -> None:
-        """The pending fires' rows out of the buffer, one row a session,
-        handed downstream.  Counters ``session_fires`` (flushes that had
-        sessions to fire), ``session_rows_read`` (the rows ``query_range``
-        handed them) and ``session_flush_us`` (wall microseconds of
+        """The pending fires out, one row a session, handed downstream.
+        Counters ``session_fires`` (flushes that had sessions to fire),
+        ``session_acc_sessions`` (sessions emitted off their channels),
+        ``session_rows_read`` (the buffer path: the rows ``query_range``
+        handed the fire) and ``session_flush_us`` (wall microseconds of
         ``_fired_batch``, the collect left out)."""
         from ..obs import perf
 
-        fires = self._pending_fires
-        if not fires:
+        pending = self._pending_fires
+        if not pending:
             return
         self._pending_fires = []
         perf.count("session_fires")
         t0 = _time.perf_counter_ns()
         try:
-            out = self._fired_batch(fires)
+            out = self._fired_batch(
+                np.concatenate([p[0] for p in pending]),
+                np.concatenate([p[1] for p in pending]),
+                np.concatenate([p[2] for p in pending]),
+                [np.concatenate([p[3][j] for p in pending])
+                 for j in range(len(self._kinds))])
         finally:
             perf.count("session_flush_us",
                        (_time.perf_counter_ns() - t0) // 1000)
         if out is not None:
             await ctx.collect(out)
 
-    def _fired_batch(self, fires: List[Tuple[int, int, int]]
-                     ) -> Optional[Batch]:
-        from ..obs import perf
-
-        rows = self.buffer.query_range(min(s for _, s, _ in fires),
-                                       max(e for _, _, e in fires))
-        if rows is None or not len(rows):
-            return None
-        perf.count("session_rows_read", len(rows))
-        # assign every buffered row to its fired session in ONE combined
-        # sweep: sessions (as start events) and rows merge-sort by
-        # (key, time, starts-first); a running count of starts gives each
-        # row the global index of the latest session start at-or-before
-        # it — valid iff that session shares the row's key and the row
-        # precedes its end.  No per-key python, no buffer argsort.
-        m = len(fires)
-        fk = np.array([k for k, _, _ in fires], dtype=np.uint64)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
-        fs = np.array([s for _, s, _ in fires], dtype=np.int64)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
-        fe = np.array([e for _, _, e in fires], dtype=np.int64)  # arroyolint: disable=host-sync -- intentional pane-emission readback: fired panes must materialize on the host to become output batch columns
-        fo = np.lexsort((fs, fk))
-        fk, fs, fe = fk[fo], fs[fo], fe[fo]
-        n = len(rows)
+    @staticmethod
+    def _assign_rows(fk: np.ndarray, fs: np.ndarray, fe: np.ndarray,
+                     rows: Batch) -> Tuple[np.ndarray, np.ndarray]:
+        """Each buffered row's session (``fk, fs, fe`` sorted by (key,
+        start)) in ONE combined sweep: sessions (as start events) and
+        rows merge-sort by (key, time, starts-first); a running count of
+        starts gives each row the global index of the latest session
+        start at-or-before it — valid iff that session shares the row's
+        key and the row precedes its end.  No per-key python, no buffer
+        argsort.  Returns (the rows that hold a session, their session
+        ordinals)."""
+        m, n = len(fk), len(rows)
+        if m == 0:
+            z = np.zeros(0, dtype=np.int64)
+            return z, z.copy()
         all_kh = np.concatenate([fk, rows.key_hash])
         all_t = np.concatenate([fs, rows.timestamp])
         prio = np.concatenate([np.zeros(m, np.int8), np.ones(n, np.int8)])
@@ -1095,41 +1385,103 @@ class SessionWindowOperator(Operator):
         sic = np.clip(si, 0, m - 1)
         ok = ((si >= 0) & (fk[sic] == rows.key_hash)
               & (rows.timestamp < fe[sic]))
-        if not ok.any():
-            return None
         sel = ok.nonzero()[0]
-        segs = sic[sel].astype(np.uint64)
-        sub = rows.select(sel)
-        seg_kh_a, seg_s_a, seg_e_a = fk, fs, fe
+        return sel, sic[sel]
 
-        if self.flatten:
-            si = segs.astype(np.int64)
-            cols = dict(sub.columns)
-            cols["window_start"] = seg_s_a[si]
-            cols["window_end"] = seg_e_a[si]
-            out = Batch(seg_e_a[si] - 1, cols, sub.key_hash, sub.key_cols)
+    def _fired_batch(self, fk: np.ndarray, fs: np.ndarray, fe: np.ndarray,
+                     fch: List[np.ndarray]) -> Optional[Batch]:
+        """One row a fired session, in (key, start) order: off the
+        sessions' channels on the accumulator path, else from the
+        buffered rows each session holds."""
+        fo = np.lexsort((fs, fk))
+        fk, fs, fe = fk[fo], fs[fo], fe[fo]
+        if self._acc_on:
+            out = self._acc_batch(fk, fs, fe, [c[fo] for c in fch])
         else:
-            uniq, agg_cols, _, _cnt, _vc = segment_aggregate(
-                segs, sub.timestamp, sub.columns, self.aggs)
-            ui = uniq.astype(np.int64)
-            # key columns: first row of each emitted segment
-            cols: Dict[str, np.ndarray] = {}
-            if sub.key_cols:
-                so = np.argsort(segs, kind="stable")
-                seg_sorted = segs[so]
-                _, first = np.unique(seg_sorted, return_index=True)
-                first_rows = so[first]  # aligned with sorted uniq
-                cols = {c: sub.columns[c][first_rows] for c in sub.key_cols
-                        if c in sub.columns}
-            cols["window_start"] = seg_s_a[ui]
-            cols["window_end"] = seg_e_a[ui]
-            cols.update(agg_cols)
-            out = Batch(seg_e_a[ui] - 1, cols, seg_kh_a[ui], sub.key_cols)
+            out = self._buffer_batch(fk, fs, fe)
+        if out is None:
+            return None
         out.lat_stamp = _lat_consume(self._lat_pending)
         self._lat_pending = None
         if self.projection is not None:
             out = eval_record_expr(self.projection, out)
         return out
+
+    def _acc_batch(self, fk: np.ndarray, fs: np.ndarray, fe: np.ndarray,
+                   fch: List[np.ndarray]) -> Optional[Batch]:
+        """The accumulator fire: the columns, dtypes and row order
+        segment_aggregate gives the buffer path, read off the channels.
+        A session whose channels hold no rows emits nothing, as a
+        session with no buffered rows does there."""
+        from ..obs import perf
+
+        live = fch[0] > 0
+        if not live.all():
+            fk, fs, fe = fk[live], fs[live], fe[live]
+            fch = [c[live] for c in fch]
+        if not len(fk):
+            return None
+        perf.count("session_acc_sessions", len(fk))
+        nch = len(self._acc_chans)
+        ch = dict(zip(self._acc_chans, fch[:nch]))
+        cols: Dict[str, np.ndarray] = dict(zip(self._firsts, fch[nch:]))
+        cols["window_start"] = fs
+        cols["window_end"] = fe
+        for a in self.aggs:
+            if a.column is None:
+                cols[a.output] = ch[("rows", None)]
+                continue
+            nn = ch[("nn", a.column)]
+            if a.kind == AggKind.COUNT:
+                col = nn
+            elif a.kind == AggKind.AVG:
+                col = np.where(nn > 0, ch[("sum", a.column)]
+                               / np.maximum(nn, 1), np.nan)
+            else:
+                name = "sum" if a.kind == AggKind.SUM else a.kind.value
+                col = np.where(nn > 0, ch[(name, a.column)], np.nan)
+            cols[a.output] = col
+        return Batch(fe - 1, cols, fk, self._key_cols)
+
+    def _buffer_batch(self, fk: np.ndarray, fs: np.ndarray,
+                      fe: np.ndarray) -> Optional[Batch]:
+        """The buffer fire: the rows from the oldest fired start to the
+        newest fired end, each assigned to its session, then flattened
+        or aggregated per session."""
+        from ..obs import perf
+
+        rows = self.buffer.query_range(int(fs.min()), int(fe.max()))
+        if rows is None or not len(rows):
+            return None
+        perf.count("session_rows_read", len(rows))
+        sel, seg = self._assign_rows(fk, fs, fe, rows)
+        if not len(sel):
+            return None
+        segs = seg.astype(np.uint64)
+        sub = rows.select(sel)
+
+        if self.flatten:
+            si = segs.astype(np.int64)
+            cols = dict(sub.columns)
+            cols["window_start"] = fs[si]
+            cols["window_end"] = fe[si]
+            return Batch(fe[si] - 1, cols, sub.key_hash, sub.key_cols)
+        uniq, agg_cols, _, _cnt, _vc = segment_aggregate(
+            segs, sub.timestamp, sub.columns, self.aggs)
+        ui = uniq.astype(np.int64)
+        # key columns: first row of each emitted segment
+        cols: Dict[str, np.ndarray] = {}
+        if sub.key_cols:
+            so = np.argsort(segs, kind="stable")
+            seg_sorted = segs[so]
+            _, first = np.unique(seg_sorted, return_index=True)
+            first_rows = so[first]  # aligned with sorted uniq
+            cols = {c: sub.columns[c][first_rows] for c in sub.key_cols
+                    if c in sub.columns}
+        cols["window_start"] = fs[ui]
+        cols["window_end"] = fe[ui]
+        cols.update(agg_cols)
+        return Batch(fe[ui] - 1, cols, fk[ui], sub.key_cols)
 
     async def handle_watermark(self, watermark: int, ctx: Context) -> None:
         """Span ``session.fire``, entry to return, for a watermark that
@@ -1141,15 +1493,16 @@ class SessionWindowOperator(Operator):
         self._collect_expired(watermark, ctx)
         fired = bool(self._pending_fires)
         await self._flush_fires(ctx)
-        # evict data older than every live session start
-        if self._device_state:
-            ls = self.windows.min_live_start()
-        else:
-            live_starts = [s for _, sessions in self.windows.items()
-                           for (s, _) in sessions]
-            ls = min(live_starts) if live_starts else None
-        self.buffer.evict_before(
-            ls if ls is not None else watermark - MAX_SESSION_SIZE_MICROS)
+        if not self._acc_on:
+            # evict data older than every live session start
+            if self._device_state:
+                ls = self.windows.min_live_start()
+            else:
+                live_starts = [sess[0] for _, sessions in self.windows.items()
+                               for sess in sessions]
+                ls = min(live_starts) if live_starts else None
+            self.buffer.evict_before(
+                ls if ls is not None else watermark - MAX_SESSION_SIZE_MICROS)
         await ctx.broadcast(Message.wm(Watermark.event_time(watermark)))
         if fired:
             tracing.record_span("session.fire", "window", t0,

@@ -4,7 +4,9 @@ a session closing after 10 s without a bid), through the entry a user takes,
 rehearsal's size: the rows on two seeds and at a gap of 1 s, with the union
 scan on the host and as the jitted kernel; a replayed or half-dropped batch
 reads not correct; the session fire's span and counters; and the
-``watermark`` tick rule a session cell stands on (every session that ends at
+``watermark`` tick rule a session cell stands on; the fire reads each
+session's own accumulators, and a median-UDAF session query still
+re-reads the buffered rows (every session that ends at
 or before a watermark reaches the sink before it, and the sink logs and
 forwards every watermark in order)."""
 
@@ -26,6 +28,7 @@ CONFIG = "nexmark_q11_sessions"
 GAP_SQL = "INTERVAL '10' SECOND"
 STREAM_S = 24  # sessions that end by 22 s hold the bids before some 11 s
 COUNTERS = ("session_fires", "session_rows_read", "session_flush_us",
+            "session_acc_sessions",
             "session_union_rows", "session_merge_dispatches",
             "kernel_dispatches.session_union", "sink_rows")
 
@@ -35,10 +38,10 @@ def _cell():
     return spec.load_cell(CONFIG + ".catchup", rehearsal=True)
 
 
-def _run_q11(seed, stream_s=STREAM_S, gap_s=10):
+def _run_q11(seed, stream_s=STREAM_S, gap_s=10, edit=None, provider=None):
     """The cell's own SQL at its rehearsal size (``gap_s`` in place of the
-    query's 10 s), run to the end of its stream; returns (cell, sink
-    batches)."""
+    query's 10 s, ``edit`` applied to the text), run to the end of its
+    stream; returns (cell, sink batches)."""
     from arroyo_tpu.connectors.memory import clear_sink, sink_output
     from arroyo_tpu.engine.engine import LocalRunner
     from arroyo_tpu.sql import plan_sql
@@ -48,11 +51,13 @@ def _run_q11(seed, stream_s=STREAM_S, gap_s=10):
     sql = cell.sql(seed)
     assert sql.count(GAP_SQL) == 1
     sql = sql.replace(GAP_SQL, f"INTERVAL '{gap_s}' SECOND")
+    if edit is not None:
+        sql = edit(sql)
     sink = cell.config["sink"]
     clear_sink(sink)
     outs = sink_output(sink)
     try:
-        LocalRunner(plan_sql(sql)).run()
+        LocalRunner(plan_sql(sql, provider)).run()
         return cell, list(outs)
     finally:
         clear_sink(sink)
@@ -69,8 +74,7 @@ def _compared(cell, batches, seed, t_end, gap_s=10, **faults):
     return compare.compare(got, want), want
 
 
-@pytest.fixture(scope="module")
-def device_run():
+def _counted_run(seed, **kw):
     """One run with the union scan as the jitted kernel (the path a TPU
     takes by itself), the counters and spans kept, and the rows every
     ``query_range`` of the session buffer returned summed."""
@@ -85,18 +89,22 @@ def device_run():
         returned.append(0 if rows is None else len(rows))
         return rows
 
-    seed = 2_147_483_999
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("ARROYO_SESSION_DEVICE", "on")
         mp.setattr(BatchBuffer, "query_range", counted)
         perf.reset()
         tracing.reset()
-        cell, batches = _run_q11(seed)
+        cell, batches = _run_q11(seed, **kw)
     return {"cell": cell, "seed": seed, "batches": batches,
             "rows_returned": sum(returned),
             "counters": {n: perf.counter(n) for n in COUNTERS},
             "fires": [s for s in tracing.spans("window")
                       if s[0] == "session.fire"]}
+
+
+@pytest.fixture(scope="module")
+def device_run():
+    return _counted_run(2_147_483_999)
 
 
 @pytest.mark.parametrize("seed,gap_s", [(7, 10), (2_147_483_777, 10),
@@ -136,7 +144,8 @@ def test_a_faulted_stream_reads_not_correct(device_run, fault):
 
 def test_session_counters_and_spans_are_recorded(device_run):
     c, run = device_run["counters"], device_run
-    assert c["session_fires"] > 10 and c["session_rows_read"] > 0
+    # q11 counts: the fire reads the sessions' accumulators, no row
+    assert c["session_fires"] > 10 and c["session_rows_read"] == 0
     assert c["session_flush_us"] > 0
     dispatches = c["kernel_dispatches.session_union"]
     assert dispatches > 10 and dispatches == c["session_merge_dispatches"]
@@ -147,13 +156,52 @@ def test_session_counters_and_spans_are_recorded(device_run):
     assert all(dur > 0 and args["watermark"] > 0
                for _n, _c, _s, dur, _p, _t, args in run["fires"])
     assert c["sink_rows"] == sum(len(b) for b in run["batches"])
+    assert c["session_acc_sessions"] == c["sink_rows"]
 
 
 def test_session_rows_read_are_the_rows_query_range_returned(device_run):
-    run = device_run
-    assert run["counters"]["session_rows_read"] == run["rows_returned"]
+    """q11 takes the accumulator path: no fire re-reads the buffer, and
+    every session in the sink came off its own partials."""
+    c = device_run["counters"]
+    assert c["session_rows_read"] == device_run["rows_returned"] == 0
+    assert c["session_acc_sessions"] == c["sink_rows"] > 5000
+
+
+def test_median_udaf_sessions_read_the_rows_query_range_returned():
+    """A median UDAF does not fold into partials, so its session query
+    keeps the row buffer: the fire re-reads the rows ``query_range``
+    returns, counted as such, and the counts still equal the
+    reference's."""
+    from arroyo_tpu.sql import SchemaProvider
+    from arroyo_tpu.sql.functions import UDAFS
+
+    provider = SchemaProvider()
+    # registrations are process-wide: a name no other test registers,
+    # taken back after the run
+    provider.register_udaf("q11_median", np.median)
+
+    def with_median(sql):
+        sql = sql.replace("bid.datetime as datetime",
+                          "bid.datetime as datetime, bid.price as price")
+        return sql.replace(" as window\nFROM",
+                           " as window, q11_median(price) as med\nFROM")
+
+    seed = 2_147_483_651
+    try:
+        run = _counted_run(seed, stream_s=16, edit=with_median,
+                           provider=provider)
+    finally:
+        UDAFS.pop("q11_median", None)
+    c = run["counters"]
+    assert c["session_rows_read"] == run["rows_returned"]
     # the re-read: a fire reads far more rows than the sessions it fires
-    assert run["rows_returned"] > 10 * run["counters"]["sink_rows"]
+    assert run["rows_returned"] > 10 * c["sink_rows"] > 0
+    assert c["session_acc_sessions"] == 0
+    assert all("med" in b.columns for b in run["batches"])
+    numbers, want = _compared(run["cell"], run["batches"], seed,
+                              _t_end(run["cell"], 16))
+    assert compare.verdict(numbers), numbers
+    assert len(want) > 1000
 
 
 # -- the ``watermark`` tick rule, as benchmarks/tests/test_watermark_ticks.py
